@@ -10,6 +10,9 @@ re-simulation, trace-compiled and hybrid segmented initial simulation) to
 ``BENCH_core.json`` so future PRs have a machine-readable trajectory to
 compare against.
 
+JAX's persistent compilation cache goes to ``$JAX_COMPILATION_CACHE_DIR``
+if set, else to ``.jax_cache/`` in the checkout.
+
 ``--quick`` runs only the key-producing benchmarks at reduced sizes —
 every required key is still written (tests/test_bench_schema.py validates
 the schema), but the values are not comparable with the full-size
@@ -21,6 +24,8 @@ from __future__ import annotations
 import json
 import os
 import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main(quick: bool = False, out: str = None) -> None:
@@ -61,8 +66,7 @@ def main(quick: bool = False, out: str = None) -> None:
         # the committed trajectory — keep them out of BENCH_core.json unless
         # the caller routes them explicitly with --out
         name = "BENCH_core.quick.json" if quick else "BENCH_core.json"
-        out = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), name)
+        out = os.path.join(REPO, name)
     with open(out, "w") as f:
         json.dump(tables.BENCH_CORE, f, indent=2, sort_keys=True)
         f.write("\n")
@@ -77,4 +81,6 @@ if __name__ == "__main__":
         if i + 1 >= len(argv):
             sys.exit("usage: python -m benchmarks.run [--quick] [--out PATH]")
         out_path = argv[i + 1]
+    from repro.device import configure_compile_cache
+    configure_compile_cache(os.path.join(REPO, ".jax_cache"))
     main(quick="--quick" in argv, out=out_path)

@@ -618,10 +618,10 @@ def table_corpus_scaling() -> List[str]:
 # ----------------------------------------- sparse Pallas max-plus DSE lane
 def table_sparse_maxplus() -> List[str]:
     """Sparse chain-structured Pallas max-plus solver (``backend="jax"``,
-    interpret mode — CI needs no TPU) on a 100-module corpus design:
-    device-lane throughput at K = 1e3 / 1e4 / 1e5 depth configs, plus the
-    ratio against the numpy Gauss-Seidel fixpoint at the largest K.  The
-    dense ``jax_dense`` lowering cannot run this design at all — its
+    compiled on a TPU, interpret mode on the CPU) on a 100-module corpus
+    design: device-lane throughput at K = 1e3 / 1e4 / 1e5 depth configs,
+    plus the ratio against the numpy Gauss-Seidel fixpoint at the largest
+    K.  The dense ``jax_dense`` lowering cannot run this design at all — its
     (K, npad, npad) working set is O(n^2) per config.  ``--quick`` keeps
     every key but solves K/100 configs per point."""
     import numpy as np
@@ -629,14 +629,15 @@ def table_sparse_maxplus() -> List[str]:
     from repro.core.dse import solve_block_status
     from repro.core.incremental import compile_graph
     from repro.corpus import BENCH_SPEC, generate
+    from repro.device import pallas_interpret
 
     rows = []
     print("\n== Sparse max-plus: backend=\"jax\" on a 100-module corpus "
           "design ==")
     # recorded next to the maxplus_sparse_* keys: interpret mode executes
     # the Pallas kernel body through XLA on CPU, so its numbers are not
-    # comparable with a compiled-device trajectory — flip this on real TPUs
-    jax_interpret = True
+    # comparable with a compiled-device trajectory
+    jax_interpret = pallas_interpret()
     for seed in range(8):           # first live seed, deterministically
         c = generate(seed, scale=100, spec=BENCH_SPEC)
         base_run = simulate(c.builder(), trace="auto")

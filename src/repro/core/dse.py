@@ -52,6 +52,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..device import pallas_interpret
 from .engine import OmniSim, simulate
 from .graph import (export_chain_flat, longest_path_chains,
                     longest_path_chains_batched)
@@ -515,7 +516,7 @@ def _solve_block_numpy(ba: _BatchArrays, Db: np.ndarray):
 
 def solve_block_status(cache: CompiledGraph, depth_block,
                        backend: str = "numpy", block: int = 128,
-                       jax_interpret: bool = True):
+                       jax_interpret: Optional[bool] = None):
     """Engine-free solve phase of :func:`resimulate_batch`.
 
     Classifies a block of depth vectors against ``cache`` alone — no
@@ -530,6 +531,10 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     REUSED with its exact cycle count, or DEADLOCK / CYCLE / VIOLATED with
     ``cycles = -1`` (the caller decides whether to pay for the exact
     fallback re-simulation, which *does* need the engine).
+
+    ``jax_interpret`` (jax backends only) defaults to the platform's
+    Pallas mode (:func:`repro.device.pallas_interpret`): compiled on a
+    TPU, interpreted on the CPU.
     """
     ba = _batch_arrays(cache)
     D = np.asarray(depth_block, dtype=np.int64)
@@ -547,6 +552,8 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     total_rounds = 0
 
     if len(alive):
+        if backend in ("jax", "jax_dense"):
+            jax_interpret = pallas_interpret(jax_interpret)
         if backend == "jax_dense":
             blocks = [(np.arange(len(alive)),
                        *_solve_dense_jax(cache, ba, D[alive],
@@ -660,7 +667,7 @@ def materialize_block(result: SimResult, Du: np.ndarray,
 def resimulate_batch(result: SimResult, depth_matrix,
                      fallback: bool = True, backend: str = "numpy",
                      block: int = 128,
-                     jax_interpret: bool = True,
+                     jax_interpret: Optional[bool] = None,
                      dedup: bool = True) -> BatchOutcome:
     """Incrementally re-simulate ``result`` under K depth vectors at once.
 
@@ -759,7 +766,7 @@ def _sparse_arrays(cache: CompiledGraph, ba: _BatchArrays):
 
 
 def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
-                      Db: np.ndarray, interpret: bool = True):
+                      Db: np.ndarray, interpret: Optional[bool] = None):
     """Sparse chain-structured Pallas solve for one block of configs.
 
     Seeds every config at the no-WAR fixpoint contribution (``c_inf``, a
@@ -772,11 +779,11 @@ def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
 
     _int32_saturation_guard(ba, "jax")
     arr = _sparse_arrays(cache, ba)
-    return sp.solve_chains(arr, Db, use_pallas=True, interpret=interpret)
+    return sp.solve_chains(arr, Db, interpret=interpret)
 
 
 def _solve_dense_jax(cache: CompiledGraph, ba: _BatchArrays, Db: np.ndarray,
-                     interpret: bool = True, block: int = 128):
+                     interpret: bool, block: int = 128):
     """Batched node times via ``jax.vmap`` over the dense Pallas max-plus
     kernel (``repro.kernels.maxplus``) — the legacy O(n^2)-per-config
     lowering, kept as ``backend="jax_dense"`` for tiny graphs.

@@ -320,6 +320,9 @@ class ChainFlatArrays(NamedTuple):
     before solving).  The config-dependent half — which read each write
     waits on under depth ``S`` (``tgt = wseq - S - 1``) — is computed
     on-device from these tables plus the depth block.
+
+    RAW edges and WAR rows are sorted by destination column, padding
+    included, so the device scatters may declare sorted indices.
     """
 
     n: int                    # real node count (columns 0..n are live)
@@ -374,6 +377,14 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
         return (np.concatenate(parts).astype(np.int32) if parts
                 else np.zeros(0, np.int32))
 
+    raw_dst, raw_src, raw_w = (np.asarray(a) for a in (raw_dst, raw_src,
+                                                       raw_w))
+    order = np.argsort(raw_dst, kind="stable")
+    raw_dst, raw_src, raw_w = raw_dst[order], raw_src[order], raw_w[order]
+    war_dst_c = cat(wd)
+    worder = np.argsort(war_dst_c, kind="stable")
+    war = [cat(p)[worder] for p in (ws, wf, wnr, wro)]
+
     def pad(a, m, fill):
         """Bucket array lengths to powers of two (floor 16) so solves of
         different designs reuse the device solver's jit cache; padding
@@ -391,20 +402,24 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
         return m
 
     E = bucket(len(raw_dst)) if len(raw_dst) else 0
-    war_dst_c = cat(wd)
     m = bucket(len(war_dst_c)) if len(war_dst_c) else 0
     R = bucket(roff) if roff else 0
+    # padding edges and WAR rows repeat the last destination, keeping the
+    # destinations sorted; what they scatter is masked to -INF
+    raw_last = int(raw_dst[-1]) if len(raw_dst) else 0
+    war_last = int(war_dst_c[worder[-1]]) if len(war_dst_c) else 0
     return ChainFlatArrays(
         n=n, npad=npad, cw=cwp, seg_start=seg, c_seed=cs,
-        # padding edges: weight = -INF (a max-identity), src/dst = 0
-        raw_dst=pad(np.asarray(raw_dst), E, 0),
-        raw_src=pad(np.asarray(raw_src), E, 0),
+        # padding edges: weight = -INF (a max-identity), src = 0
+        raw_dst=pad(raw_dst, E, raw_last),
+        raw_src=pad(raw_src, E, 0),
         raw_w=pad(np.maximum(raw_w, neg), E, neg),
         # padding WAR rows: wseq = 0 makes every target negative (masked);
         # nr = 1 / roff = 0 keep the clipped gather in bounds
-        war_dst=pad(war_dst_c, m, 0), war_wseq=pad(cat(ws), m, 0),
-        war_fid=pad(cat(wf), m, 0), war_nr=pad(cat(wnr), m, 1),
-        war_roff=pad(cat(wro), m, 0), war_rcols=pad(cat(rc), R, 0),
+        war_dst=pad(war_dst_c[worder], m, war_last),
+        war_wseq=pad(war[0], m, 0), war_fid=pad(war[1], m, 0),
+        war_nr=pad(war[2], m, 1), war_roff=pad(war[3], m, 0),
+        war_rcols=pad(cat(rc), R, 0),
         bound=int(bound),
         max_seg=max([hi - lo for (lo, hi) in chain_slices] or [1]))
 

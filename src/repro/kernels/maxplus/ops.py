@@ -9,6 +9,7 @@ pads to the 128 tile size).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,11 +21,11 @@ from .ref import maxplus_sweep_ref
 @functools.partial(jax.jit, static_argnames=("max_iters", "use_pallas",
                                              "interpret"))
 def longest_path(a: jnp.ndarray, base: jnp.ndarray, *, max_iters: int = 0,
-                 use_pallas: bool = True, interpret: bool = True):
+                 use_pallas: bool = True, interpret: bool):
     """Fixpoint t = max(base, A (+) t).  a: [N, N] int32; base: [N] int32.
 
-    ``interpret=True`` (default) executes the Pallas kernel body in Python —
-    the CPU-validation mode; on real TPU pass interpret=False.
+    ``interpret`` is the platform's Pallas mode
+    (:func:`repro.device.pallas_interpret`).
     """
     n = a.shape[0]
     assert n % BLK == 0
@@ -49,11 +50,16 @@ def longest_path(a: jnp.ndarray, base: jnp.ndarray, *, max_iters: int = 0,
     return t
 
 
-def finalize_times(graph, *, use_pallas: bool = True, interpret: bool = True):
-    """Longest-path node times for a SimGraph via the dense-blocked kernel."""
+def finalize_times(graph, *, use_pallas: bool = True,
+                   interpret: Optional[bool] = None):
+    """Longest-path node times for a SimGraph via the dense-blocked kernel
+    (``interpret=None`` derives the Pallas mode from the platform)."""
     import numpy as np
 
     from ...core.graph import to_dense_blocks
+    from ...device import pallas_interpret
+
+    interpret = pallas_interpret(interpret)
     indptr, src, wgt, base = graph.to_csr()
     a, b = to_dense_blocks(indptr, src, wgt, base, pad_to=BLK)
     # clip the int64 -INF sentinel in numpy BEFORE the int32 transfer —

@@ -14,8 +14,11 @@ Per fixpoint round (K configs at once):
   1. **chain pass** — ``t = cw + segcummax(c - cw)``: one *segmented*
      cummax over the (K, npad) contribution matrix, segment boundaries at
      chain starts.  This is the Pallas kernel: a Hillis–Steele doubling
-     scan (log2(npad) shifted-max steps, each a full-tile VPU op) over
-     (ROWS, npad) VMEM tiles, gridded over config rows.  ``max`` is
+     scan (log2 of the tile width or of the longest chain, whichever is
+     smaller, shifted-max steps, each a full-tile VPU op) over (rows,
+     width) VMEM tiles, gridded over config rows and node tiles; a
+     per-row carry continues a chain across node tiles, so the VMEM
+     footprint stays fixed however wide the design.  ``max`` is
      idempotent, so overlapping windows need no flag bookkeeping — a
      column takes its shifted partner iff the partner is at/after its
      own chain start.
@@ -49,13 +52,16 @@ tails hit the jit cache instead of recompiling per shape.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.graph import ChainFlatArrays
+from ...device import pallas_interpret
 
 # int32 -INF sentinel — matches the numpy solver's int32 mode, and leaves
 # headroom: with bound < 2^28 (enforced upstream) no max-plus candidate
@@ -63,7 +69,8 @@ from ...core.graph import ChainFlatArrays
 NEG = -(1 << 29)
 LANES = 128        # node-axis padding unit (TPU lane width)
 ROWS = 8           # minimum configs per kernel row tile (sublane width)
-_TILE_BYTES = 1 << 21   # per-buffer VMEM budget for one (rows, npad) tile
+LANE_TILE = 2048   # node-axis tile width once the padded axis is wider
+_TILE_BYTES = 1 << 20   # per-buffer VMEM budget for one (rows, width) tile
 
 
 def _pow2(x: int, floor: int) -> int:
@@ -73,12 +80,25 @@ def _pow2(x: int, floor: int) -> int:
     return p
 
 
-def _rows_for(K: int, npad: int) -> int:
+def _tile_width(npad: int) -> int:
+    """Node-axis tile: the whole axis up to ``LANE_TILE`` columns, else
+    ``LANE_TILE`` — so a tile's VMEM footprint never grows with the
+    design (the axis is padded to a tile multiple, see
+    :func:`_padded_width`)."""
+    return npad if npad <= LANE_TILE else LANE_TILE
+
+
+def _padded_width(npad: int) -> int:
+    w = _tile_width(npad)
+    return -(-npad // w) * w
+
+
+def _rows_for(K: int, width: int) -> int:
     """Row-tile height: as tall as the VMEM budget allows (fewer grid
     steps — interpret mode executes them sequentially), never taller than
     the (power-of-two) batch.  Both are powers of two, so rows | K."""
     cap = ROWS
-    while cap * 2 * npad * 4 <= _TILE_BYTES and cap < 512:
+    while cap * 2 * width * 4 <= _TILE_BYTES and cap < 512:
         cap *= 2
     return min(K, cap)
 
@@ -103,11 +123,21 @@ def _doubling_scan(x, seg, col, limit):
     return x
 
 
-def _segcummax_kernel(limit, x_ref, seg_ref, o_ref):
-    x = x_ref[...]                              # (rows, npad) int32
-    seg = seg_ref[...]                          # (1, npad) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[1]), 1)
-    o_ref[...] = _doubling_scan(x, seg, col, limit)
+def _segcummax_kernel(limit, x_ref, seg_ref, o_ref, carry_ref):
+    """One (rows, width) tile.  Node tiles of a row block run in order
+    (the grid's last axis), and ``carry_ref`` holds the previous tile's
+    last column: a segment that began before this tile continues that
+    column's running max, so segments may be any length."""
+    width = x_ref.shape[1]
+    seg = seg_ref[...] - pl.program_id(1) * width   # tile-relative starts
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    y = _doubling_scan(x_ref[...], jnp.maximum(seg, 0), col, limit)
+    # seg < 0 never holds in the first tile, so the carry is only read
+    # after a previous tile of the same row block wrote it
+    y = jnp.where(seg < 0, jnp.maximum(y, carry_ref[...]), y)
+    o_ref[...] = y
+    carry_ref[...] = jnp.max(jnp.where(col == width - 1, y, NEG),
+                             axis=1, keepdims=True)
 
 
 def _scan_limit(npad: int, max_seg) -> int:
@@ -124,42 +154,52 @@ def segmented_cummax_ref(x: jnp.ndarray, seg_start: jnp.ndarray,
 
 
 def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
-                     max_seg=None, use_pallas: bool = True,
-                     interpret: bool = True):
+                     max_seg=None, interpret: Optional[bool] = None,
+                     width: Optional[int] = None):
     """Segmented cummax over (K, npad); ``seg_start[j]`` is column j's
     segment start, ``max_seg`` an optional bound on segment length (caps
     the scan's doubling steps).  K must be a ROWS multiple and npad a
-    LANES multiple for the Pallas path (callers bucket-pad; see
-    :func:`solve_chains`)."""
-    if not use_pallas:
-        return segmented_cummax_ref(x, seg_start, max_seg)
+    multiple of the node tile ``width`` (default :func:`_tile_width`),
+    itself a LANES multiple — callers bucket-pad, see
+    :func:`_fixpoint_args`.  ``interpret=None`` derives the mode from the
+    platform (:func:`repro.device.pallas_interpret`).
+    """
+    if interpret is None:
+        interpret = pallas_interpret()
     K, npad = x.shape
-    rows = _rows_for(K, npad)
-    assert K % rows == 0 and npad % LANES == 0, (K, npad)
-    seg2 = seg_start.reshape(1, npad).astype(jnp.int32)
+    width = _tile_width(npad) if width is None else width
+    rows = _rows_for(K, width)
+    assert K % rows == 0 and npad % width == 0 and width % LANES == 0, \
+        (K, npad, width)
     return pl.pallas_call(
-        functools.partial(_segcummax_kernel, _scan_limit(npad, max_seg)),
-        grid=(K // rows,),
+        functools.partial(_segcummax_kernel,
+                          _scan_limit(width, max_seg)),
+        grid=(K // rows, npad // width),
         in_specs=[
-            pl.BlockSpec((rows, npad), lambda i: (i, 0)),
-            pl.BlockSpec((1, npad), lambda i: (0, 0)),
+            pl.BlockSpec((rows, width), lambda i, j: (i, j)),
+            pl.BlockSpec((1, width), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((rows, npad), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, width), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, npad), x.dtype),
+        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, seg2)
+    )(x, seg_start.reshape(1, npad).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
 # the batched fixpoint
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit,
-                   static_argnames=("max_seg", "use_pallas", "interpret"))
-def _fixpoint(c0, cw, seg_start, raw_dst, raw_src, raw_w,
+                   static_argnames=("max_seg", "width", "interpret"))
+def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
               war_dst, war_wseq, war_fid, war_nr, war_roff, war_rcols,
-              Db, bound, iters, *, max_seg: int, use_pallas: bool,
+              Db, bound, iters, *, max_seg: int, width: int,
               interpret: bool):
-    K, npad = c0.shape
+    K = Db.shape[0]
+    npad = c_seed.shape[0]
+    c0 = jnp.broadcast_to(c_seed[None, :], (K, npad))
     cw_row = cw[None, :]
 
     # depth-dependent WAR targets, computed on-device once per solve:
@@ -174,10 +214,12 @@ def _fixpoint(c0, cw, seg_start, raw_dst, raw_src, raw_w,
 
     def chain_pass(c):
         seg = segmented_cummax(c - cw_row, seg_start, max_seg=max_seg,
-                               use_pallas=use_pallas, interpret=interpret)
+                               interpret=interpret, width=width)
         return seg + cw_row
 
     def cross_pass(c, t):
+        # export_chain_flat sorts both scatters' destinations (padding
+        # included); an unsorted scatter compiles to a sort on the TPU
         c2 = c
         if raw_dst.shape[0]:
             # w == NEG marks bucket-padding edges; real weights are >= 0.
@@ -185,11 +227,11 @@ def _fixpoint(c0, cw, seg_start, raw_dst, raw_src, raw_w,
             # NEG + t[src] and perturb unreached-node sentinel times.
             cand = jnp.where(raw_w[None, :] > jnp.int32(NEG),
                              t[:, raw_src] + raw_w[None, :], jnp.int32(NEG))
-            c2 = c2.at[:, raw_dst].max(cand)
+            c2 = c2.at[:, raw_dst].max(cand, indices_are_sorted=True)
         if have_war:
             cand = jnp.take_along_axis(t, war_src, axis=1) + 1
             cand = jnp.where(war_valid, cand, jnp.int32(NEG))
-            c2 = c2.at[:, war_dst].max(cand)
+            c2 = c2.at[:, war_dst].max(cand, indices_are_sorted=True)
         return c2
 
     def body(state):
@@ -212,36 +254,49 @@ def _fixpoint(c0, cw, seg_start, raw_dst, raw_src, raw_w,
     return t, ~(diverged | pending), rounds
 
 
+def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
+    """Host operands and static arguments of :func:`_fixpoint` for one
+    block: the batch is bucketed to a power of two (slab tails reuse the
+    compiled solver; padding rows replicate row 0 and converge exactly
+    when it does) and the node axis to a tile multiple (inert one-column
+    segments at the -INF sentinel)."""
+    K = len(Db)
+    Kp = _pow2(K, max(ROWS, 1))
+    Dp = np.minimum(np.asarray(Db, np.int64), 1 << 30).astype(np.int32)
+    if Kp != K:
+        Dp = np.concatenate([Dp, np.broadcast_to(Dp[:1], (Kp - K,
+                                                          Dp.shape[1]))])
+    full = _padded_width(arr.npad)
+    extra = full - arr.npad
+    c_seed, cw, seg = arr.c_seed, arr.cw, arr.seg_start
+    if extra:
+        c_seed = np.concatenate([c_seed, np.full(extra, NEG, np.int32)])
+        cw = np.concatenate([cw, np.zeros(extra, np.int32)])
+        seg = np.concatenate([seg, np.arange(arr.npad, full,
+                                             dtype=np.int32)])
+    args = (c_seed, cw, seg, arr.raw_dst, arr.raw_src, arr.raw_w,
+            arr.war_dst, arr.war_wseq, arr.war_fid, arr.war_nr,
+            arr.war_roff, arr.war_rcols, Dp, np.int32(arr.bound),
+            np.int32(arr.n + 2))
+    return args, dict(max_seg=arr.max_seg, width=_tile_width(full))
+
+
 def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
-                 use_pallas: bool = True, interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Solve K depth configs over one chain-flat graph.
 
     ``Db``: (K, n_fifos) depth block.  Returns ``(times, converged,
     rounds)`` — ``times`` (n, K) int32 in chain-major node order (the
     layout ``core.dse.solve_block_status`` consumes), ``converged[k]``
     False where config k's regenerated WAR edges form a cycle.
+    ``interpret=None`` derives the Pallas mode from the platform.
     """
+    interpret = pallas_interpret(interpret)
     K = len(Db)
     if K == 0 or arr.n == 0:
         return (np.zeros((arr.n, K), np.int32), np.ones(K, bool), 0)
-    # bucket the batch axis so slab tails reuse the compiled solver; the
-    # padding rows replicate row 0 and converge exactly when it does
-    Kp = _pow2(K, max(ROWS, 1))
-    Dp = np.minimum(np.asarray(Db, np.int64), 1 << 30).astype(np.int32)
-    if Kp != K:
-        Dp = np.concatenate([Dp, np.broadcast_to(Dp[:1], (Kp - K,
-                                                          Dp.shape[1]))])
-    c0 = jnp.asarray(np.broadcast_to(arr.c_seed, (Kp, arr.npad)))
-    t, conv, rounds = _fixpoint(
-        c0, jnp.asarray(arr.cw), jnp.asarray(arr.seg_start),
-        jnp.asarray(arr.raw_dst), jnp.asarray(arr.raw_src),
-        jnp.asarray(arr.raw_w),
-        jnp.asarray(arr.war_dst), jnp.asarray(arr.war_wseq),
-        jnp.asarray(arr.war_fid), jnp.asarray(arr.war_nr),
-        jnp.asarray(arr.war_roff), jnp.asarray(arr.war_rcols),
-        jnp.asarray(Dp), jnp.int32(arr.bound),
-        jnp.int32(arr.n + 2),
-        max_seg=arr.max_seg,
-        use_pallas=use_pallas, interpret=interpret)
+    args, static = _fixpoint_args(arr, Db)
+    t, conv, rounds = _fixpoint(*map(jnp.asarray, args), **static,
+                                interpret=interpret)
     times = np.ascontiguousarray(np.asarray(t)[:K, :arr.n].T)
     return times, np.asarray(conv)[:K], int(rounds)

@@ -8,6 +8,7 @@ smoke tests must see the single real CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,6 +19,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh():
-    """Degenerate 1x1 mesh over the real local device(s) for smoke runs."""
+    """Degenerate 1x1 mesh over the real local device(s) for smoke runs.
+
+    Auto axes: the model code places activations with
+    ``with_sharding_constraint``, which JAX refuses on the Explicit axes
+    ``make_mesh`` defaults to since JAX 0.9."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

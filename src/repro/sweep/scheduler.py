@@ -39,7 +39,9 @@ pickled graphs per design key, every (re)spawned worker unpickles them
 once at startup, and a task ships only the design key — a worker that has
 never seen the key answers with a need-blob sentinel and the host resends
 that one chunk with the blob attached, so steady state, retries and
-respawns never re-pay graph serialization per task.
+respawns never re-pay graph serialization per task.  The jax lanes
+refuse ``mode="process"``: a chip belongs to one process, so their
+shards are threads on the one device.
 
 Exactness: a block's verdicts and cycle counts are exactly
 ``resimulate_batch``'s — REUSED rows from the shared fixpoint, failed rows
@@ -84,12 +86,14 @@ import numpy as np
 from ..core.dse import (CANCELLED, FAULTED, REUSED, TIMED_OUT,
                         materialize_block, solve_block_status)
 from ..core.program import SimResult
+from ..device import pallas_interpret
 from .cache import CacheEntry
 from .faults import (POOL_BROKEN, SHARD_CORRUPT, SHARD_FAULT, SHARD_HANG,
                      DesignQuarantine, FaultInjector, InjectedFault,
                      RetryPolicy, _PoolBrokenFault)
 
 INTERACTIVE, BULK = "interactive", "bulk"
+JAX_BACKENDS = ("jax", "jax_dense")     # solve lanes that hold the device
 
 _DONE = object()                     # per-request stream terminator
 
@@ -203,7 +207,8 @@ def _apply_shard_faults(out, hang_s: float, boom: bool, corrupt: bool):
 
 def _shard_task(graph, Db: np.ndarray, backend: str, block: int,
                 hang_s: float = 0.0, boom: bool = False,
-                corrupt: bool = False, jax_interpret: bool = True):
+                corrupt: bool = False,
+                jax_interpret: Optional[bool] = None):
     """Thread/serial shard unit: solve one chunk (plus injected faults —
     the injector draws on the scheduler thread, deterministically, and
     ships only the outcome flags here)."""
@@ -219,7 +224,7 @@ def _shard_task(graph, Db: np.ndarray, backend: str, block: int,
 def _process_shard_solve(key: str, blob: Optional[bytes], Db: np.ndarray,
                          backend: str, block: int, hang_s: float = 0.0,
                          boom: bool = False, corrupt: bool = False,
-                         jax_interpret: bool = True):
+                         jax_interpret: Optional[bool] = None):
     graph = _WORKER_GRAPHS.get(key)
     if graph is None:
         if blob is None:
@@ -250,9 +255,19 @@ class BlockScheduler:
                  shard_timeout_s: Optional[float] = 30.0,
                  quarantine: Optional[DesignQuarantine] = None,
                  max_pool_respawns: int = 2,
-                 jax_interpret: bool = True,
+                 jax_interpret: Optional[bool] = None,
                  memo_capacity: int = 4096):
         assert mode in ("serial", "thread", "process"), mode
+        if backend in JAX_BACKENDS:
+            if mode == "process":
+                raise ValueError(
+                    f"mode='process' cannot serve backend={backend!r}: a "
+                    f"chip belongs to one process, so worker processes "
+                    f"cannot reach the device the parent holds; use "
+                    f"mode='thread' (shards share the one device)")
+            # resolved here, not in a shard: a refused mode must fail the
+            # constructor, never surface later as FAULTED rows
+            jax_interpret = pallas_interpret(jax_interpret)
         self.block = max(int(block), 1)
         self.shards = max(int(shards), 1)
         self.mode = mode if self.shards > 1 else "serial"

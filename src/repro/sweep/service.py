@@ -191,7 +191,7 @@ class SweepService:
                  max_inflight_rows_per_tenant: Optional[int] = None,
                  max_queued_rows: Optional[int] = None,
                  default_deadline_s: Optional[float] = None,
-                 jax_interpret: bool = True,
+                 jax_interpret: Optional[bool] = None,
                  memo_capacity: int = 4096):
         self.cache = GraphCache(capacity=cache_capacity)
         quarantine = DesignQuarantine(threshold=quarantine_after,

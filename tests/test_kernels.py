@@ -284,3 +284,50 @@ def test_solve_chains_matches_numpy_seeded_solver():
     # converged configs: full (n, K) node-time agreement, not just cycles
     cols = np.flatnonzero(conv_np)
     assert (np.asarray(t_np)[:, cols] == t_jx[:, cols]).all()
+
+
+@pytest.mark.parametrize("npad,width,max_len", [(512, 128, 300),
+                                                (768, 256, 40),
+                                                (1024, 128, 1024)])
+def test_segmented_cummax_node_tiles(npad, width, max_len):
+    """Node-axis tiling: chains crossing one or many tile boundaries
+    continue through the per-row carry."""
+    rng = np.random.default_rng(npad + width)
+    seg = np.zeros(npad, np.int32)
+    lo = 0
+    while lo < npad:
+        ln = int(rng.integers(1, max_len + 1))
+        seg[lo:min(lo + ln, npad)] = lo
+        lo += ln
+    x = rng.integers(-50, 50, size=(16, npad)).astype(np.int32)
+    want = _segcummax_oracle(x, seg)
+    got = np.asarray(segmented_cummax(jnp.asarray(x), jnp.asarray(seg),
+                                      max_seg=max_len, width=width))
+    assert (got == want).all()
+
+
+def test_solve_chains_node_tiles_match_numpy(monkeypatch):
+    """The whole fixpoint with the node axis split into many tiles (a
+    narrow LANE_TILE stands in for a design wider than one tile)."""
+    from repro.core import simulate
+    from repro.core.dse import (_batch_arrays, _solve_block_numpy,
+                                _sparse_arrays)
+    from repro.core.incremental import compile_graph
+    from repro.designs.typea import skynet_like
+    from repro.kernels.maxplus import sparse as sp
+
+    base = simulate(skynet_like(items=64, depth=4))
+    g = compile_graph(base.graph)
+    ba = _batch_arrays(g)
+    arr = _sparse_arrays(g, ba)
+    monkeypatch.setattr(sp, "LANE_TILE", 128)
+    assert sp._padded_width(arr.npad) // sp._tile_width(arr.npad) > 2
+    assert arr.max_seg > 128                  # chains span several tiles
+    rng = np.random.default_rng(3)
+    Db = rng.integers(1, 9, size=(8, len(base.depths))).astype(np.int64)
+    t_np, conv_np, _ = _solve_block_numpy(ba, Db)
+    t_jx, conv_jx, _ = sp.solve_chains(arr, Db)
+    assert (conv_np == conv_jx).all()
+    cols = np.flatnonzero(conv_np)
+    assert len(cols)
+    assert (np.asarray(t_np)[:, cols] == t_jx[:, cols]).all()

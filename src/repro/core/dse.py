@@ -52,7 +52,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from ..device import pallas_interpret
+from ..device import pallas_interpret, span
 from .engine import OmniSim, simulate
 from .graph import (export_chain_flat, longest_path_chains,
                     longest_path_chains_batched)
@@ -575,20 +575,22 @@ def solve_block_status(cache: CompiledGraph, depth_block,
                 blocks.append((sl, t_nm, conv))
         else:
             raise ValueError(f"unknown backend {backend!r}")
-        for sl, t_nm, conv in blocks:
-            rows = alive[sl]
-            status[rows[~conv]] = CYCLE                       # ② event order
-            if conv.any():
-                # ③ constraint re-check, all configs at once
-                viol = _check_constraints_stacked(cache, ba, t_nm,
-                                                  D[rows])
-                violated[rows[conv]] = viol[conv]
-                status[rows[conv & (viol > 0)]] = VIOLATED
-                good = conv & (viol == 0)
-                if good.any():
-                    cyc = (t_nm.max(axis=0) if t_nm.shape[0]
-                           else np.zeros(len(rows), np.int64))
-                    cycles[rows[good]] = cyc[good]
+        with span("solve.recheck", rounds=total_rounds) as sp:
+            for sl, t_nm, conv in blocks:
+                rows = alive[sl]
+                status[rows[~conv]] = CYCLE                   # ② event order
+                if conv.any():
+                    # ③ constraint re-check, all configs at once
+                    viol = _check_constraints_stacked(cache, ba, t_nm,
+                                                      D[rows])
+                    violated[rows[conv]] = viol[conv]
+                    status[rows[conv & (viol > 0)]] = VIOLATED
+                    good = conv & (viol == 0)
+                    if good.any():
+                        cyc = (t_nm.max(axis=0) if t_nm.shape[0]
+                               else np.zeros(len(rows), np.int64))
+                        cycles[rows[good]] = cyc[good]
+            sp.set_metadata(violated_rows=int((violated > 0).sum()))
     return status, cycles, violated, total_rounds
 
 

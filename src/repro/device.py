@@ -6,6 +6,8 @@
 * :func:`configure_compile_cache` — where JAX keeps its persistent
   compilation cache.  Entry points (``chip_smoke.py``,
   ``benchmarks/run.py``) call it; importing the library never does.
+* :func:`span` — a named host span in the JAX profiler's trace, the one
+  tracing hook of the sweep service and the solve phase.
 """
 from __future__ import annotations
 
@@ -53,3 +55,19 @@ def configure_compile_cache(default_dir: str) -> str:
 
     jax.config.update("jax_compilation_cache_dir", str(default_dir))
     return str(default_dir)
+
+
+def span(name: str, **attrs):
+    """Context manager timing ``name`` as a host span of the JAX profiler's
+    trace: ``jax.profiler.TraceAnnotation(name, **attrs)``.  The keyword
+    attributes come back as the event's stats; ``set_metadata(**attrs)``
+    on the entered span adds those known only at its end.
+
+    Spans are always on: with no profiler session one costs about a
+    microsecond, so they are opened per block, phase and request, never
+    per row.  jax is imported on first use, so a module that opens spans
+    stays importable without it.
+    """
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **attrs)

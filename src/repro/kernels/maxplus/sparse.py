@@ -61,7 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.graph import ChainFlatArrays
-from ...device import pallas_interpret
+from ...device import pallas_interpret, span
 
 # int32 -INF sentinel — matches the numpy solver's int32 mode, and leaves
 # headroom: with bound < 2^28 (enforced upstream) no max-plus candidate
@@ -185,6 +185,7 @@ def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="segcummax",
     )(x, seg_start.reshape(1, npad).astype(jnp.int32))
 
 
@@ -212,26 +213,32 @@ def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
         war_src = war_rcols[war_roff[None, :]
                             + jnp.clip(tgt, 0, war_nr[None, :] - 1)]
 
+    # the scopes name the passes' ops in a device trace (op_name metadata)
     def chain_pass(c):
-        seg = segmented_cummax(c - cw_row, seg_start, max_seg=max_seg,
-                               interpret=interpret, width=width)
-        return seg + cw_row
+        with jax.named_scope("chain_pass"):
+            seg = segmented_cummax(c - cw_row, seg_start, max_seg=max_seg,
+                                   interpret=interpret, width=width)
+            return seg + cw_row
 
     def cross_pass(c, t):
         # export_chain_flat sorts both scatters' destinations (padding
         # included); an unsorted scatter compiles to a sort on the TPU
         c2 = c
         if raw_dst.shape[0]:
-            # w == NEG marks bucket-padding edges; real weights are >= 0.
-            # An unmasked padding edge would lift a NEG contribution to
-            # NEG + t[src] and perturb unreached-node sentinel times.
-            cand = jnp.where(raw_w[None, :] > jnp.int32(NEG),
-                             t[:, raw_src] + raw_w[None, :], jnp.int32(NEG))
-            c2 = c2.at[:, raw_dst].max(cand, indices_are_sorted=True)
+            with jax.named_scope("cross_pass_raw"):
+                # w == NEG marks bucket-padding edges; real weights are
+                # >= 0.  An unmasked padding edge would lift a NEG
+                # contribution to NEG + t[src] and perturb unreached-node
+                # sentinel times.
+                cand = jnp.where(raw_w[None, :] > jnp.int32(NEG),
+                                 t[:, raw_src] + raw_w[None, :],
+                                 jnp.int32(NEG))
+                c2 = c2.at[:, raw_dst].max(cand, indices_are_sorted=True)
         if have_war:
-            cand = jnp.take_along_axis(t, war_src, axis=1) + 1
-            cand = jnp.where(war_valid, cand, jnp.int32(NEG))
-            c2 = c2.at[:, war_dst].max(cand, indices_are_sorted=True)
+            with jax.named_scope("cross_pass_war"):
+                cand = jnp.take_along_axis(t, war_src, axis=1) + 1
+                cand = jnp.where(war_valid, cand, jnp.int32(NEG))
+                c2 = c2.at[:, war_dst].max(cand, indices_are_sorted=True)
         return c2
 
     def body(state):
@@ -290,13 +297,25 @@ def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
     layout ``core.dse.solve_block_status`` consumes), ``converged[k]``
     False where config k's regenerated WAR edges form a cycle.
     ``interpret=None`` derives the Pallas mode from the platform.
+
+    Each phase is a host span: ``solve.upload`` (operands to the device,
+    dispatch), ``solve.fixpoint`` (waiting for the device),
+    ``solve.copy_back`` (results to the host) and ``solve.transpose``.
     """
     interpret = pallas_interpret(interpret)
     K = len(Db)
     if K == 0 or arr.n == 0:
         return (np.zeros((arr.n, K), np.int32), np.ones(K, bool), 0)
-    args, static = _fixpoint_args(arr, Db)
-    t, conv, rounds = _fixpoint(*map(jnp.asarray, args), **static,
-                                interpret=interpret)
-    times = np.ascontiguousarray(np.asarray(t)[:K, :arr.n].T)
-    return times, np.asarray(conv)[:K], int(rounds)
+    with span("solve.upload") as sp:
+        args, static = _fixpoint_args(arr, Db)
+        out = _fixpoint(*map(jnp.asarray, args), **static,
+                        interpret=interpret)
+        sp.set_metadata(K=int(out[0].shape[0]))       # padded batch
+    with span("solve.fixpoint"):
+        t, conv, rounds = jax.block_until_ready(out)
+    with span("solve.copy_back",
+              bytes=t.nbytes + conv.nbytes + rounds.nbytes):
+        t, conv, rounds = np.asarray(t), np.asarray(conv), int(rounds)
+    with span("solve.transpose"):
+        times = np.ascontiguousarray(t[:K, :arr.n].T)
+    return times, conv[:K], rounds

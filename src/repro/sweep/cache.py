@@ -37,9 +37,8 @@ from __future__ import annotations
 import dataclasses
 import pickle
 import threading
-import time as _time
 from collections import OrderedDict
-from typing import Callable, Dict, NamedTuple, Optional, Union
+from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 from ..core.dse import _batch_arrays, program_mutation_lock
 from ..core.engine import simulate
@@ -48,6 +47,7 @@ from ..core.program import Program, SimResult
 from ..core.trace import HybridCache, program_fingerprint
 from ..delta.fingerprint import DesignDelta, DesignFingerprint, diff
 from ..delta.patch import DeltaState, apply_patch, cold_build
+from ..device import span
 
 
 class CacheEntry:
@@ -57,19 +57,20 @@ class CacheEntry:
     ``_FullRun`` entry (PR 9's hybrid replay artifact) alongside the
     graph: a cache hit reinstalls it into the shared
     :class:`~repro.core.trace.HybridCache`, so one tenant's completed
-    dynamic run warms every other tenant's fallback re-simulations."""
+    dynamic run warms every other tenant's fallback re-simulations.
+    ``patched`` marks an entry an edit session's delta patch built."""
 
-    __slots__ = ("key", "result", "graph", "_batch", "hits", "build_s",
+    __slots__ = ("key", "result", "graph", "_batch", "hits", "patched",
                  "lock", "_graph_blob", "full_run")
 
     def __init__(self, key: str, result: SimResult, graph: CompiledGraph,
-                 batch=None, build_s: float = 0.0):
+                 batch=None):
         self.key = key
         self.result = result
         self.graph = graph
         self._batch = batch
         self.hits = 0
-        self.build_s = build_s
+        self.patched = False
         # serializes engine-touching work (fallback re-simulation mutates
         # Program FIFO depths in place and restores them)
         self.lock = threading.Lock()
@@ -203,6 +204,15 @@ class GraphCache:
         overrides the content fingerprint for callers that already know
         their design identity.
         """
+        return self.resolve(design, key, simulate_fn)[0]
+
+    def resolve(self, design: Union[Program, SimResult],
+                key: Optional[str] = None,
+                simulate_fn: Callable = simulate
+                ) -> Tuple[CacheEntry, str]:
+        """:meth:`get_or_build`, also saying how the entry was found:
+        ``"hit"``, ``"patched"`` (a hit on an entry a delta patch built)
+        or ``"miss"`` (built here, in a ``sweep.cache_build`` span)."""
         base: Optional[SimResult] = None
         if isinstance(design, SimResult):
             base = design
@@ -219,27 +229,25 @@ class GraphCache:
                 key = program_fingerprint(program)
             entry = self.lookup(key)
             if entry is not None:
-                return entry
-            t0 = _time.perf_counter()
-            if base is None:
-                if simulate_fn is simulate:
-                    # default path: thread the shared HybridCache so a
-                    # dynamic design's verified _FullRun lands in it
-                    base = simulate(program, hybrid_cache=self.hybrid)
-                else:
-                    base = simulate_fn(program)
-            entry = self._entry_from(key, base, t0)
-            return self.insert(entry)
+                return entry, "patched" if entry.patched else "hit"
+            with span("sweep.cache_build", key=key[:16]):
+                if base is None:
+                    if simulate_fn is simulate:
+                        # default path: thread the shared HybridCache so a
+                        # dynamic design's verified _FullRun lands in it
+                        base = simulate(program, hybrid_cache=self.hybrid)
+                    else:
+                        base = simulate_fn(program)
+                entry = self._entry_from(key, base)
+            return self.insert(entry), "miss"
 
-    def _entry_from(self, key: str, base: SimResult,
-                    t0: float) -> CacheEntry:
+    def _entry_from(self, key: str, base: SimResult) -> CacheEntry:
         """Hoist the compiled graph from a base run and spill the hybrid
         whole-run entry (if the build produced one) onto the entry.  The
         batch view is deliberately *not* built here — see
         :attr:`CacheEntry.batch`."""
         graph = compile_graph(base.graph)
-        entry = CacheEntry(key, base, graph,
-                           build_s=_time.perf_counter() - t0)
+        entry = CacheEntry(key, base, graph)
         entry.full_run = self.hybrid.peek_full(key)
         return entry
 
@@ -266,29 +274,31 @@ class GraphCache:
             entry = self.lookup(fps.key)
             if entry is not None:
                 return DeltaLookup(entry, "exact", "", None, total, total)
-            t0 = _time.perf_counter()
-            reason = ""
-            if state is not None:
-                if delta is None:
-                    delta = diff(state.fps, fps)
-                if delta.patchable:
-                    out = apply_patch(state, program, delta=delta,
-                                      new_fps=fps)
-                    if out.ok:
-                        entry = self.insert(
-                            self._entry_from(fps.key, out.result, t0))
-                        with self._lock:
-                            self.delta_hits += 1
-                        return DeltaLookup(entry, "patched", "", out.state,
-                                           out.reused_modules, total)
-                    reason = out.reason
-                else:
-                    reason = delta.reason
-                with self._lock:
-                    self.delta_rejects += 1
-            base, new_state = cold_build(program, hybrid_cache=self.hybrid,
-                                         fps=fps)
-            entry = self.insert(self._entry_from(fps.key, base, t0))
+            with span("sweep.cache_build", key=fps.key[:16]):
+                reason = ""
+                if state is not None:
+                    if delta is None:
+                        delta = diff(state.fps, fps)
+                    if delta.patchable:
+                        out = apply_patch(state, program, delta=delta,
+                                          new_fps=fps)
+                        if out.ok:
+                            entry = self._entry_from(fps.key, out.result)
+                            entry.patched = True
+                            self.insert(entry)
+                            with self._lock:
+                                self.delta_hits += 1
+                            return DeltaLookup(entry, "patched", "",
+                                               out.state,
+                                               out.reused_modules, total)
+                        reason = out.reason
+                    else:
+                        reason = delta.reason
+                    with self._lock:
+                        self.delta_rejects += 1
+                base, new_state = cold_build(
+                    program, hybrid_cache=self.hybrid, fps=fps)
+                entry = self.insert(self._entry_from(fps.key, base))
             return DeltaLookup(entry, "cold", reason, new_state, 0, total)
 
     def stats(self) -> Dict[str, float]:

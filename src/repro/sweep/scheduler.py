@@ -86,7 +86,7 @@ import numpy as np
 from ..core.dse import (CANCELLED, FAULTED, REUSED, TIMED_OUT,
                         materialize_block, solve_block_status)
 from ..core.program import SimResult
-from ..device import pallas_interpret
+from ..device import pallas_interpret, span
 from .cache import CacheEntry
 from .faults import (POOL_BROKEN, SHARD_CORRUPT, SHARD_FAULT, SHARD_HANG,
                      DesignQuarantine, FaultInjector, InjectedFault,
@@ -159,6 +159,7 @@ class _Block(NamedTuple):
     entry: CacheEntry
     items: List[Tuple[_Request, int]]    # (request, row index) per row
     lane: str
+    number: int                          # running block number, from 1
 
 
 class _Attempt(NamedTuple):
@@ -317,6 +318,10 @@ class BlockScheduler:
         self.stats_pool_respawns = 0
         self.stats_blob_reships = 0      # need-blob round trips (process)
         self.stats_memo_hits = 0         # rows answered without a solve
+        self.stats_solve_calls = 0       # shard solves that returned
+        self.stats_fixpoint_rounds = 0   # their fixpoint rounds, summed
+        self.stats_forced_bulk = 0       # bulk blocks forced past waiting
+        #                                  interactive rows
 
     # --------------------------------------------------------------- pool
     def _make_pool(self):
@@ -387,6 +392,9 @@ class BlockScheduler:
         if req.finalized:
             return
         req.finalized = True
+        with span("sweep.request_done", rid=req.rid, lane=req.priority,
+                  latency_us=(_time.perf_counter() - req.t_submit) * 1e6):
+            pass
         req.out_q.put(_DONE)
         if req.on_finalize is not None:
             try:
@@ -500,14 +508,23 @@ class BlockScheduler:
 
     def _assemble(self) -> Optional[_Block]:
         """Build the next block: anchor on the chosen lane's oldest live
-        request, fill with same-design rows from every queued request."""
-        with self._cv:
+        request, fill with same-design rows from every queued request.
+
+        Traced as ``sweep.assemble`` (block number, lane, ``forced``, rows),
+        with a zero-length ``sweep.dequeue`` marker for each request whose
+        first rows the block takes."""
+        with span("sweep.assemble") as sp, self._cv:
             lane_name = self._pick_lane()
             if lane_name is None:
+                sp.set_metadata(rows=0)
                 return None
+            # _pick_lane only passes over waiting interactive rows when
+            # starvation_limit forces a bulk block
+            forced = lane_name == BULK and bool(self._lanes[INTERACTIVE])
             lane = self._lanes[lane_name]
             anchor = lane[0]
             items: List[Tuple[_Request, int]] = []
+            first: List[_Request] = []
             for scan in (lane_name, BULK if lane_name == INTERACTIVE
                          else INTERACTIVE):
                 q = self._lanes[scan]
@@ -519,6 +536,8 @@ class BlockScheduler:
                     if req.entry is not anchor.entry:
                         continue
                     take = min(self.block - len(items), req.K - req.cursor)
+                    if req.cursor == 0:
+                        first.append(req)
                     items.extend((req, i) for i in
                                  range(req.cursor, req.cursor + take))
                     req.cursor += take
@@ -532,9 +551,22 @@ class BlockScheduler:
             else:
                 self._consec_interactive = 0
                 self.stats_blocks_bulk += 1
+            self.stats_forced_bulk += forced
             self.stats_blocks += 1
             self.stats_rows += len(items)
-            return _Block(anchor.entry, items, lane_name)
+            n = self.stats_blocks
+            now = _time.perf_counter()
+            for req in first:
+                with span("sweep.dequeue", rid=req.rid, lane=req.priority,
+                          rows=req.K, block=n,
+                          wait_us=(now - req.t_submit) * 1e6):
+                    pass
+            sp.set_metadata(block=n, lane=lane_name, forced=int(forced),
+                            rows=len(items),
+                            interactive_rows=sum(
+                                1 for req, _i in items
+                                if req.priority == INTERACTIVE))
+            return _Block(anchor.entry, items, lane_name, n)
 
     # -------------------------------------------------------------- solve
     def _launch(self, entry: CacheEntry, Db: np.ndarray,
@@ -623,12 +655,15 @@ class BlockScheduler:
                         self.jax_interpret)
                     attempt = _Attempt(fut, None, self._pool_gen)
                     continue
-                status, cycles, violated, _rounds = out
+                status, cycles, violated, rounds = out
                 if (len(status) != K or len(cycles) != K
                         or len(violated) != K):
                     raise ShardCorruption(
                         f"shard returned {len(status)} rows for a "
                         f"{K}-row chunk")
+                with self._cv:
+                    self.stats_solve_calls += 1
+                    self.stats_fixpoint_rounds += int(rounds)
                 return (np.asarray(status, np.int8),
                         np.asarray(cycles, np.int64),
                         np.asarray(violated, np.int64), "")
@@ -704,55 +739,112 @@ class BlockScheduler:
 
     # ------------------------------------------------------------ deliver
     def _deliver(self, blk: _Block) -> None:
+        """Dedup, solve, materialize and deliver one block.  Each phase on
+        this thread is a flat span carrying the block number
+        (``sweep.dedup``, ``sweep.materialize``, ``sweep.deliver``; the
+        solve phase opens its own ``solve.*`` spans) — flat, so a trace
+        names an idle device gap by the phase that covers it."""
         entry = blk.entry
-        rows = np.stack([req.D[i] for (req, i) in blk.items])
-        Du, inverse = np.unique(rows, axis=0, return_inverse=True)
-        inverse = inverse.reshape(-1)
-        with self._cv:
-            self.stats_rows_unique += len(Du)
-        deadlines = [req.t_deadline for (req, _i) in blk.items
-                     if req.t_deadline is not None]
-        t_deadline = min(deadlines) if deadlines else None
-        # cross-block memo: identical (design, depth-row) pairs seen in any
-        # earlier block are answered without a solver call — only the
-        # residual rows reach _solve_unique
-        U = len(Du)
-        status_u = np.empty(U, dtype=np.int8)
-        cycles_u = np.full(U, -1, dtype=np.int64)
-        violated_u = np.zeros(U, dtype=np.int64)
-        notes: Dict[int, str] = {}
-        memo_hit = np.zeros(U, dtype=bool)
-        if self.memo_capacity:
+        n = blk.number
+        with span("sweep.dedup", block=n) as sp:
+            rows = np.stack([req.D[i] for (req, i) in blk.items])
+            Du, inverse = np.unique(rows, axis=0, return_inverse=True)
+            inverse = inverse.reshape(-1)
             with self._cv:
-                for u in range(U):
-                    mk = (entry.key, Du[u].tobytes())
-                    got = self._memo.get(mk)
-                    if got is not None:
-                        self._memo.move_to_end(mk)
-                        status_u[u], cycles_u[u], violated_u[u] = got
-                        memo_hit[u] = True
-                        self.stats_memo_hits += 1
-        solve_idx = np.flatnonzero(~memo_hit)
+                self.stats_rows_unique += len(Du)
+            deadlines = [req.t_deadline for (req, _i) in blk.items
+                         if req.t_deadline is not None]
+            t_deadline = min(deadlines) if deadlines else None
+            # cross-block memo: identical (design, depth-row) pairs seen in
+            # any earlier block are answered without a solver call — only
+            # the residual rows reach _solve_unique
+            U = len(Du)
+            status_u = np.empty(U, dtype=np.int8)
+            cycles_u = np.full(U, -1, dtype=np.int64)
+            violated_u = np.zeros(U, dtype=np.int64)
+            notes: Dict[int, str] = {}
+            memo_hit = np.zeros(U, dtype=bool)
+            if self.memo_capacity:
+                with self._cv:
+                    for u in range(U):
+                        mk = (entry.key, Du[u].tobytes())
+                        got = self._memo.get(mk)
+                        if got is not None:
+                            self._memo.move_to_end(mk)
+                            status_u[u], cycles_u[u], violated_u[u] = got
+                            memo_hit[u] = True
+                            self.stats_memo_hits += 1
+            solve_idx = np.flatnonzero(~memo_hit)
+            hits = U - len(solve_idx)
+            sp.set_metadata(rows_unique=U, memo_hits=hits)
         if len(solve_idx):
             st, cy, vi, sub_notes = self._solve_unique(
                 entry, Du[solve_idx], t_deadline)
-            status_u[solve_idx] = st
-            cycles_u[solve_idx] = cy
-            violated_u[solve_idx] = vi
-            for su, note in sub_notes.items():
-                notes[int(solve_idx[su])] = note
-            if self.memo_capacity:
-                with self._cv:
-                    for su in range(len(solve_idx)):
-                        s = int(st[su])
-                        if s == FAULTED or s == TIMED_OUT:
-                            continue
-                        self._memo[(entry.key,
-                                    Du[solve_idx[su]].tobytes())] = (
-                            s, int(cy[su]), int(vi[su]))
-                    while len(self._memo) > self.memo_capacity:
-                        self._memo.popitem(last=False)
+            with span("sweep.dedup", block=n, rows_unique=U,
+                      memo_hits=hits, memo_inserts=len(solve_idx)):
+                status_u[solve_idx] = st
+                cycles_u[solve_idx] = cy
+                violated_u[solve_idx] = vi
+                for su, note in sub_notes.items():
+                    notes[int(solve_idx[su])] = note
+                if self.memo_capacity:
+                    with self._cv:
+                        for su in range(len(solve_idx)):
+                            s = int(st[su])
+                            if s == FAULTED or s == TIMED_OUT:
+                                continue
+                            self._memo[(entry.key,
+                                        Du[solve_idx[su]].tobytes())] = (
+                                s, int(cy[su]), int(vi[su]))
+                        while len(self._memo) > self.memo_capacity:
+                            self._memo.popitem(last=False)
 
+        with span("sweep.materialize", block=n) as sp:
+            results_u, reasons_u, n_fb = self._materialize(
+                blk, Du, inverse, status_u, cycles_u, violated_u)
+            for u, note in notes.items():
+                reasons_u[u] = note
+            sp.set_metadata(fallbacks=n_fb)
+
+        with span("sweep.deliver", block=n, rows=len(blk.items)):
+            now = _time.perf_counter()
+            for pos, (req, i) in enumerate(blk.items):
+                if req.cancelled.is_set():
+                    continue
+                if req.expired(now):
+                    # end-to-end deadline: a result that arrives late is a
+                    # timeout, not a delivery
+                    req.out_q.put(ConfigResult(
+                        request_id=req.rid, index=i,
+                        depths=tuple(int(d) for d in req.D[i]),
+                        ok=False, status=TIMED_OUT, cycles=-1, violated=0,
+                        reason="deadline exceeded before this config was "
+                               "delivered", result=None))
+                    with self._cv:
+                        self.stats_timed_out_rows += 1
+                else:
+                    u = int(inverse[pos])
+                    use_fb = req.fallback or status_u[u] == REUSED
+                    req.out_q.put(ConfigResult(
+                        request_id=req.rid, index=i,
+                        depths=tuple(int(d) for d in req.D[i]),
+                        ok=bool(status_u[u] == REUSED),
+                        status=int(status_u[u]),
+                        cycles=int(cycles_u[u]) if use_fb else -1,
+                        violated=int(violated_u[u]), reason=reasons_u[u],
+                        result=results_u[u] if use_fb else None))
+                req.delivered += 1
+                if req.delivered >= req.K:
+                    self._finish(req)
+            for req, _i in blk.items:
+                if req.cancelled.is_set():
+                    self._finalize(req)
+
+    def _materialize(self, blk: _Block, Du, inverse, status_u, cycles_u,
+                     violated_u):
+        """Reasons, result shells and exact fallbacks of a block's unique
+        rows; returns ``(results_u, reasons_u, fallbacks run)``."""
+        entry = blk.entry
         # a failed unique row pays for its exact fallback only if a LIVE
         # request owning it asked for fallback (a cancelled or expired
         # tenant's rows must not cost engine re-simulations nobody will
@@ -786,45 +878,11 @@ class BlockScheduler:
                 if fb_mask[u] and status_u[u] != REUSED:
                     reasons_u[u] += f" [{note}]"
             fb_mask[:] = False
-        for u, note in notes.items():
-            reasons_u[u] = note
         n_fb = int((fb_mask & (status_u != REUSED)).sum())
         if n_fb:
             with self._cv:
                 self.stats_fallbacks += n_fb
-
-        now = _time.perf_counter()
-        for pos, (req, i) in enumerate(blk.items):
-            if req.cancelled.is_set():
-                continue
-            if req.expired(now):
-                # end-to-end deadline: a result that arrives late is a
-                # timeout, not a delivery
-                req.out_q.put(ConfigResult(
-                    request_id=req.rid, index=i,
-                    depths=tuple(int(d) for d in req.D[i]),
-                    ok=False, status=TIMED_OUT, cycles=-1, violated=0,
-                    reason="deadline exceeded before this config was "
-                           "delivered", result=None))
-                with self._cv:
-                    self.stats_timed_out_rows += 1
-            else:
-                u = int(inverse[pos])
-                use_fb = req.fallback or status_u[u] == REUSED
-                req.out_q.put(ConfigResult(
-                    request_id=req.rid, index=i,
-                    depths=tuple(int(d) for d in req.D[i]),
-                    ok=bool(status_u[u] == REUSED),
-                    status=int(status_u[u]),
-                    cycles=int(cycles_u[u]) if use_fb else -1,
-                    violated=int(violated_u[u]), reason=reasons_u[u],
-                    result=results_u[u] if use_fb else None))
-            req.delivered += 1
-            if req.delivered >= req.K:
-                self._finish(req)
-        for req, _i in blk.items:
-            if req.cancelled.is_set():
-                self._finalize(req)
+        return results_u, reasons_u, n_fb
 
     # --------------------------------------------------------------- step
     def step(self) -> bool:
@@ -885,6 +943,9 @@ class BlockScheduler:
                 "blob_reships": self.stats_blob_reships,
                 "memo_hits": self.stats_memo_hits,
                 "memo_size": len(self._memo),
+                "solve_calls": self.stats_solve_calls,
+                "fixpoint_rounds": self.stats_fixpoint_rounds,
+                "forced_bulk_blocks": self.stats_forced_bulk,
                 "shards": self.shards,
                 "mode": self.mode,
             }

@@ -49,6 +49,7 @@ from ..core.dse import (CANCELLED, REJECTED, BatchOutcome,
                         program_mutation_lock)
 from ..core.program import Program, SimResult
 from ..core.trace import program_fingerprint
+from ..device import span
 from .admission import DEFAULT_TENANT, AdmissionController
 from .cache import GraphCache
 from .faults import DesignQuarantine, FaultInjector, RetryPolicy
@@ -339,59 +340,68 @@ class SweepService:
         delivered in time terminate ``TIMED_OUT``.  A request refused by
         quarantine or admission control returns a handle whose rows are
         all ``REJECTED`` (see :attr:`SweepHandle.rejected`).
+
+        Traced as a ``sweep.submit`` span on the caller's thread (request
+        id, lane, rows, and ``cache``: ``hit``, ``miss`` or ``patched``).
         """
-        if self._stop.is_set():
-            raise RuntimeError("sweep service is closed")
-        if deadline_s is None:
-            deadline_s = self.default_deadline_s
-        D = np.asarray(depths, dtype=np.int64)
-        if D.ndim == 1:
-            D = D[None, :]
-        program = (design.graph.program if isinstance(design, SimResult)
-                   else design)
-        if key is None:
-            with program_mutation_lock(program):
-                key = program_fingerprint(program)
-        # refuse before building: a quarantined design must not cost a
-        # cache build, and a shed request must not evict a warm entry
-        if self.quarantine.is_quarantined(key):
-            why = self.quarantine.reason(key)
-            return self._rejected_handle(
-                D, "design quarantined after repeated solve faults"
-                   f"{': ' + why if why else ''}", tenant, fallback)
-        shed = self.admission.try_admit(tenant, len(D))
-        if shed is not None:
-            return self._rejected_handle(D, shed, tenant, fallback)
-        try:
-            entry = self.cache.get_or_build(design, key=key)
-            if D.ndim != 2 or D.shape[1] != entry.n_fifos:
-                raise ValueError(f"depth matrix {D.shape} does not match "
-                                 f"{entry.n_fifos} FIFOs")
-        except Exception as exc:
-            self.admission.release(tenant, len(D))
-            if not isinstance(exc, ValueError):
-                self.quarantine.strike(key, f"cache build faulted: {exc!r}")
-            raise
-        if priority is None:
-            priority = INTERACTIVE if len(D) <= self.interactive_max else BULK
-        assert priority in (INTERACTIVE, BULK), priority
-        with self._rid_lock:
-            self._rid += 1
-            rid = self._rid
-        req = _Request(rid, entry, D, priority, fallback, queue.Queue(),
-                       tenant=tenant, deadline_s=deadline_s,
-                       on_finalize=lambda r:
-                           self.admission.release(r.tenant, r.K))
-        handle = SweepHandle(req, self.scheduler)
-        if req.K == 0:
-            # an empty sweep completes immediately — it must never reach
-            # the scheduler (a zero-row block would fault the loop)
-            req.finalized = True
-            req.out_q.put(_DONE)
+        with span("sweep.submit") as sp:
+            if self._stop.is_set():
+                raise RuntimeError("sweep service is closed")
+            if deadline_s is None:
+                deadline_s = self.default_deadline_s
+            D = np.asarray(depths, dtype=np.int64)
+            if D.ndim == 1:
+                D = D[None, :]
+            program = (design.graph.program
+                       if isinstance(design, SimResult) else design)
+            if key is None:
+                with program_mutation_lock(program):
+                    key = program_fingerprint(program)
+            # refuse before building: a quarantined design must not cost
+            # a cache build, and a shed request must not evict a warm
+            # entry
+            if self.quarantine.is_quarantined(key):
+                why = self.quarantine.reason(key)
+                return self._rejected_handle(
+                    D, "design quarantined after repeated solve faults"
+                       f"{': ' + why if why else ''}", tenant, fallback)
+            shed = self.admission.try_admit(tenant, len(D))
+            if shed is not None:
+                return self._rejected_handle(D, shed, tenant, fallback)
+            try:
+                entry, how = self.cache.resolve(design, key=key)
+                if D.ndim != 2 or D.shape[1] != entry.n_fifos:
+                    raise ValueError(f"depth matrix {D.shape} does not "
+                                     f"match {entry.n_fifos} FIFOs")
+            except Exception as exc:
+                self.admission.release(tenant, len(D))
+                if not isinstance(exc, ValueError):
+                    self.quarantine.strike(
+                        key, f"cache build faulted: {exc!r}")
+                raise
+            if priority is None:
+                priority = (INTERACTIVE if len(D) <= self.interactive_max
+                            else BULK)
+            assert priority in (INTERACTIVE, BULK), priority
+            with self._rid_lock:
+                self._rid += 1
+                rid = self._rid
+            req = _Request(rid, entry, D, priority, fallback, queue.Queue(),
+                           tenant=tenant, deadline_s=deadline_s,
+                           on_finalize=lambda r:
+                               self.admission.release(r.tenant, r.K))
+            sp.set_metadata(rid=rid, lane=priority, rows=req.K, cache=how)
+            handle = SweepHandle(req, self.scheduler)
+            if req.K == 0:
+                # an empty sweep completes immediately — it must never
+                # reach the scheduler (a zero-row block would fault the
+                # loop)
+                req.finalized = True
+                req.out_q.put(_DONE)
+                return handle
+            self.scheduler.submit(req)
+            self._ensure_thread()
             return handle
-        self.scheduler.submit(req)
-        self._ensure_thread()
-        return handle
 
     def stream(self, design: Union[Program, SimResult], depths,
                **kw) -> Iterator[ConfigResult]:

@@ -318,8 +318,24 @@ class ChainFlatArrays(NamedTuple):
     one row per blocking write of every FIFO that has at least one read
     (a blocking overflow with no reads is a structural deadlock, masked
     before solving).  The config-dependent half — which read each write
-    waits on under depth ``S`` (``tgt = wseq - S - 1``) — is computed
-    on-device from these tables plus the depth block.
+    waits on under depth ``S`` (``tgt = wseq - S - 1``, valid iff
+    ``0 <= tgt < nr``) — is computed on-device from these tables plus the
+    depth block, without a gather whose indices differ by config:
+
+    * the *WAR lane* gives each such FIFO one contiguous segment of
+      ``max(nr, max wseq)`` slots; its first ``nr`` slots name the FIFO's
+      read columns in order (``war_lane_src``), the rest are empty (-1);
+      ``war_lane_fid`` names every slot's FIFO;
+    * write ``wseq`` reads slot ``war_pos = segment start + wseq - 1``
+      after every slot of its segment moved right by ``S``: that brings
+      in the slot of read ``tgt`` exactly when ``tgt`` is valid (a source
+      before the segment start is ``tgt < 0``, an empty slot
+      ``tgt >= nr``, both masked);
+    * ``war_seg``, the longest segment, bounds every valid shift
+      (``S <= wseq - 1 < war_seg``), so a barrel shifter of
+      ``(war_seg - 1).bit_length()`` power-of-two steps suffices.  It is
+      exact: a valid target's source lies inside its segment, and so does
+      every slot on its way there, all of them moving by the same ``S``.
 
     RAW edges and WAR rows are sorted by destination column, padding
     included, so the device scatters may declare sorted indices.
@@ -337,10 +353,12 @@ class ChainFlatArrays(NamedTuple):
     war_wseq: np.ndarray      # (m,) 1-based write sequence numbers
     war_fid: np.ndarray       # (m,) owning FIFO (column of the depth row)
     war_nr: np.ndarray        # (m,) reads of that FIFO
-    war_roff: np.ndarray      # (m,) offset of that FIFO's reads in war_rcols
-    war_rcols: np.ndarray     # (R,) concatenated read columns, FIFO-major
+    war_pos: np.ndarray       # (m,) WAR lane slot the write reads (shifted)
+    war_lane_src: np.ndarray  # (L,) WAR lane: read column, -1 where empty
+    war_lane_fid: np.ndarray  # (L,) WAR lane: FIFO of the slot's segment
     bound: int                # upper bound on any acyclic path length
     max_seg: int = 1          # longest chain (caps the scan's doubling steps)
+    war_seg: int = 0          # longest WAR lane segment (caps the shifts)
 
 
 def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
@@ -357,8 +375,8 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
     cwp[:n] = np.minimum(cw, np.iinfo(np.int32).max)
     cs = np.full(npad, neg, np.int32)
     cs[:n] = np.maximum(c_seed, neg)
-    wd, ws, wf, wnr, wro, rc = [], [], [], [], [], []
-    roff = 0
+    wd, ws, wf, wnr, wpos, lsrc, lfid = [], [], [], [], [], [], []
+    lane = war_seg = 0
     for fid, wcols in enumerate(fifo_w_cols):
         rcols = fifo_r_cols[fid]
         blk = fifo_blocking[fid]
@@ -369,9 +387,14 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
         ws.append(keep + 1)                    # 1-based write sequence
         wf.append(np.full(len(keep), fid, np.int64))
         wnr.append(np.full(len(keep), len(rcols), np.int64))
-        wro.append(np.full(len(keep), roff, np.int64))
-        rc.append(rcols)
-        roff += len(rcols)
+        wpos.append(lane + keep)               # slot wseq - 1 of the segment
+        seg_len = max(len(rcols), int(keep[-1]) + 1)
+        src = np.full(seg_len, -1, np.int64)
+        src[:len(rcols)] = rcols
+        lsrc.append(src)
+        lfid.append(np.full(seg_len, fid, np.int64))
+        lane += seg_len
+        war_seg = max(war_seg, seg_len)
 
     def cat(parts):
         return (np.concatenate(parts).astype(np.int32) if parts
@@ -383,7 +406,7 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
     raw_dst, raw_src, raw_w = raw_dst[order], raw_src[order], raw_w[order]
     war_dst_c = cat(wd)
     worder = np.argsort(war_dst_c, kind="stable")
-    war = [cat(p)[worder] for p in (ws, wf, wnr, wro)]
+    war = [cat(p)[worder] for p in (ws, wf, wnr, wpos)]
 
     def pad(a, m, fill):
         """Bucket array lengths to powers of two (floor 16) so solves of
@@ -403,7 +426,7 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
 
     E = bucket(len(raw_dst)) if len(raw_dst) else 0
     m = bucket(len(war_dst_c)) if len(war_dst_c) else 0
-    R = bucket(roff) if roff else 0
+    L = bucket(lane) if lane else 0
     # padding edges and WAR rows repeat the last destination, keeping the
     # destinations sorted; what they scatter is masked to -INF
     raw_last = int(raw_dst[-1]) if len(raw_dst) else 0
@@ -415,13 +438,16 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
         raw_src=pad(raw_src, E, 0),
         raw_w=pad(np.maximum(raw_w, neg), E, neg),
         # padding WAR rows: wseq = 0 makes every target negative (masked);
-        # nr = 1 / roff = 0 keep the clipped gather in bounds
+        # pos = 0 keeps their lane read in bounds.  Padding lane slots are
+        # empty, and no real write's shifted source reaches them
         war_dst=pad(war_dst_c[worder], m, war_last),
         war_wseq=pad(war[0], m, 0), war_fid=pad(war[1], m, 0),
-        war_nr=pad(war[2], m, 1), war_roff=pad(war[3], m, 0),
-        war_rcols=pad(cat(rc), R, 0),
+        war_nr=pad(war[2], m, 1), war_pos=pad(war[3], m, 0),
+        war_lane_src=pad(cat(lsrc), L, -1),
+        war_lane_fid=pad(cat(lfid), L, 0),
         bound=int(bound),
-        max_seg=max([hi - lo for (lo, hi) in chain_slices] or [1]))
+        max_seg=max([hi - lo for (lo, hi) in chain_slices] or [1]),
+        war_seg=war_seg)
 
 
 def to_dense_blocks(indptr: np.ndarray, src: np.ndarray, wgt: np.ndarray,

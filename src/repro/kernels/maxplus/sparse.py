@@ -32,8 +32,20 @@ Per fixpoint round (K configs at once):
 WAR targets are computed **on-device** from the flat FIFO tables and the
 depth block: write ``wseq`` of FIFO ``f`` under depth ``S = Db[k, f]``
 waits on read ``wseq - S - 1`` (weight 1), masked out where the target
-does not exist.  Regeneration is therefore one gather per solve, not a
-host round-trip per config.
+does not exist.  For one FIFO and one config, that is read ``wseq - 1``
+moved back by ``S``: the FIFO's read times moved right by ``S`` line each
+target up with its write, so the WAR half never gathers with indices
+that differ by config.  Each round it gathers the read times into the
+static *WAR lane* (one segment per FIFO, see
+:class:`~repro.core.graph.ChainFlatArrays`) with one column vector shared
+by every row, like the RAW half; moves each row's segments right by
+their own ``S`` with a barrel shifter (``(war_seg - 1).bit_length()``
+steps, each a select between the lane and its power-of-two shift); and
+reads every write's slot at a static column.  The shift is exact: a
+valid target's source, and every slot on its way there, lie inside one
+segment and move by the same amount; a source before the segment or in
+one of its empty slots is a masked target.  Only the shift amounts and
+the validity mask depend on the depth block, computed once per solve.
 
 Rows diverge independently: a config whose regenerated WAR edges form a
 cycle grows its times past the acyclic ``bound`` and is frozen (reported
@@ -106,6 +118,12 @@ def _rows_for(K: int, width: int) -> int:
 # ---------------------------------------------------------------------------
 # segmented cummax: the chain pass
 # ---------------------------------------------------------------------------
+def _shift_right(x, s: int):
+    """``x`` (rows, n) moved ``s`` columns right along axis 1, NEG in."""
+    return jnp.concatenate(
+        [jnp.full((x.shape[0], s), NEG, x.dtype), x[:, :-s]], axis=1)
+
+
 def _doubling_scan(x, seg, col, limit):
     """Hillis–Steele segmented max-scan body shared by the Pallas kernel
     and the jnp reference: log2(limit) shifted-max steps; a column accepts
@@ -115,8 +133,7 @@ def _doubling_scan(x, seg, col, limit):
     are usually far shorter than the padded node axis."""
     s = 1
     while s < limit:
-        shifted = jnp.concatenate(
-            [jnp.full((x.shape[0], s), NEG, x.dtype), x[:, :-s]], axis=1)
+        shifted = _shift_right(x, s)
         take = (col - s) >= seg
         x = jnp.where(take, jnp.maximum(x, shifted), x)
         s *= 2
@@ -192,26 +209,56 @@ def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
 # ---------------------------------------------------------------------------
 # the batched fixpoint
 # ---------------------------------------------------------------------------
+def war_steps(war_seg: int) -> int:
+    """Barrel-shift steps for WAR lane segments of at most ``war_seg``
+    slots: a shift ``S`` that leaves a valid target is below ``war_seg``."""
+    return max(int(war_seg) - 1, 0).bit_length()
+
+
+def _war_candidates(t, war_lane_src, war_pos, war_shift, war_valid,
+                    steps: int):
+    """(K, m) WAR candidates ``t[read wseq - S - 1] + 1``, NEG where that
+    read does not exist, through the WAR lane: a static column gather;
+    a barrel shift, where at step ``b`` a slot takes its partner ``2**b``
+    to the left iff bit ``b`` of its ``war_shift`` is set (exact, as the
+    module docstring says); a static read of each write's slot."""
+    x = jnp.where(war_lane_src[None, :] >= 0, t[:, war_lane_src],
+                  jnp.int32(NEG))
+    for b in range(steps):
+        x = jnp.where(((war_shift >> b) & 1) == 1, _shift_right(x, 1 << b),
+                      x)
+    return jnp.where(war_valid, x[:, war_pos] + 1, jnp.int32(NEG))
+
+
+def _war_operands(Db, war_wseq, war_fid, war_nr, war_lane_fid, war_seg):
+    """Per-solve WAR operands: each lane slot's shift ``S`` (clamped to
+    ``war_seg - 1``: any larger shift leaves only masked targets) and the
+    (K, m) mask of writes whose target read exists."""
+    S = Db[:, war_fid]                                        # (K, m)
+    tgt = war_wseq[None, :] - S - 1
+    war_valid = (tgt >= 0) & (tgt < war_nr[None, :])
+    war_shift = jnp.minimum(Db[:, war_lane_fid], war_seg - 1)
+    return war_shift, war_valid
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("max_seg", "width", "interpret"))
+                   static_argnames=("max_seg", "war_seg", "width",
+                                    "interpret"))
 def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
-              war_dst, war_wseq, war_fid, war_nr, war_roff, war_rcols,
-              Db, bound, iters, *, max_seg: int, width: int,
-              interpret: bool):
+              war_dst, war_wseq, war_fid, war_nr, war_pos, war_lane_src,
+              war_lane_fid, Db, bound, iters, *, max_seg: int, war_seg: int,
+              width: int, interpret: bool):
     K = Db.shape[0]
     npad = c_seed.shape[0]
     c0 = jnp.broadcast_to(c_seed[None, :], (K, npad))
     cw_row = cw[None, :]
 
-    # depth-dependent WAR targets, computed on-device once per solve:
-    # write wseq of FIFO fid waits on read (wseq - S - 1) under depth S
+    # depth-dependent WAR shifts and mask, computed on-device once per
+    # solve: write wseq of FIFO fid waits on read (wseq - S - 1)
     have_war = war_dst.shape[0] > 0
     if have_war:
-        S = Db[:, war_fid]                                    # (K, m)
-        tgt = war_wseq[None, :] - S - 1
-        war_valid = (tgt >= 0) & (tgt < war_nr[None, :])
-        war_src = war_rcols[war_roff[None, :]
-                            + jnp.clip(tgt, 0, war_nr[None, :] - 1)]
+        war_shift, war_valid = _war_operands(Db, war_wseq, war_fid, war_nr,
+                                             war_lane_fid, war_seg)
 
     # the scopes name the passes' ops in a device trace (op_name metadata)
     def chain_pass(c):
@@ -236,8 +283,8 @@ def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
                 c2 = c2.at[:, raw_dst].max(cand, indices_are_sorted=True)
         if have_war:
             with jax.named_scope("cross_pass_war"):
-                cand = jnp.take_along_axis(t, war_src, axis=1) + 1
-                cand = jnp.where(war_valid, cand, jnp.int32(NEG))
+                cand = _war_candidates(t, war_lane_src, war_pos, war_shift,
+                                       war_valid, war_steps(war_seg))
                 c2 = c2.at[:, war_dst].max(cand, indices_are_sorted=True)
         return c2
 
@@ -283,9 +330,10 @@ def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
                                              dtype=np.int32)])
     args = (c_seed, cw, seg, arr.raw_dst, arr.raw_src, arr.raw_w,
             arr.war_dst, arr.war_wseq, arr.war_fid, arr.war_nr,
-            arr.war_roff, arr.war_rcols, Dp, np.int32(arr.bound),
-            np.int32(arr.n + 2))
-    return args, dict(max_seg=arr.max_seg, width=_tile_width(full))
+            arr.war_pos, arr.war_lane_src, arr.war_lane_fid, Dp,
+            np.int32(arr.bound), np.int32(arr.n + 2))
+    return args, dict(max_seg=arr.max_seg, war_seg=arr.war_seg,
+                      width=_tile_width(full))
 
 
 def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
@@ -299,8 +347,10 @@ def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
     ``interpret=None`` derives the Pallas mode from the platform.
 
     Each phase is a host span: ``solve.upload`` (operands to the device,
-    dispatch), ``solve.fixpoint`` (waiting for the device),
-    ``solve.copy_back`` (results to the host) and ``solve.transpose``.
+    dispatch; metadata: the padded batch ``K``, the WAR lane's length
+    ``war_lane`` and its shift steps ``war_steps``), ``solve.fixpoint``
+    (waiting for the device), ``solve.copy_back`` (results to the host)
+    and ``solve.transpose``.
     """
     interpret = pallas_interpret(interpret)
     K = len(Db)
@@ -310,7 +360,9 @@ def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
         args, static = _fixpoint_args(arr, Db)
         out = _fixpoint(*map(jnp.asarray, args), **static,
                         interpret=interpret)
-        sp.set_metadata(K=int(out[0].shape[0]))       # padded batch
+        sp.set_metadata(K=int(out[0].shape[0]),       # padded batch
+                        war_lane=len(arr.war_lane_src),
+                        war_steps=war_steps(arr.war_seg))
     with span("solve.fixpoint"):
         t, conv, rounds = jax.block_until_ready(out)
     with span("solve.copy_back",

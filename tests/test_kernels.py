@@ -214,6 +214,7 @@ def test_mlstm_chunk_matches_model_scan():
 
 
 # ----------------------------------------------------------- sparse maxplus
+from repro.kernels.maxplus.sparse import NEG as SPARSE_NEG
 from repro.kernels.maxplus.sparse import (segmented_cummax,
                                           segmented_cummax_ref)
 
@@ -331,3 +332,145 @@ def test_solve_chains_node_tiles_match_numpy(monkeypatch):
     cols = np.flatnonzero(conv_np)
     assert len(cols)
     assert (np.asarray(t_np)[:, cols] == t_jx[:, cols]).all()
+
+
+def _war_candidates_per_row_gather(t, wseq, fid, nr, roff, rcols, Db):
+    """The WAR candidates as a gather with one index per config row: write
+    ``wseq`` of FIFO ``fid`` under depth ``S`` waits on read
+    ``wseq - S - 1`` of the FIFO's reads ``rcols[roff:roff + nr]``."""
+    S = Db[:, fid]
+    tgt = wseq[None, :] - S - 1
+    valid = (tgt >= 0) & (tgt < nr[None, :])
+    src = rcols[roff[None, :] + jnp.clip(tgt, 0, nr[None, :] - 1)]
+    cand = jnp.take_along_axis(t, src, axis=1) + 1
+    return jnp.where(valid, cand, jnp.int32(SPARSE_NEG))
+
+
+@pytest.mark.parametrize("n_fifos,nw,nr,depths", [
+    (1, 40, 10, "random"),          # more blocking writes than reads
+    (1, 10, 40, "random"),          # more reads than writes
+    (3, 20, 20, "zero"),
+    (3, 20, 25, "deep"),            # depth >= the segment length
+    (2, 30, 30, "huge"),            # the 1 << 30 clip of _fixpoint_args
+    (24, (1, 60), (0, 60), "mixed"),
+], ids=["writes_gt_reads", "reads_gt_writes", "depth0", "deep", "huge",
+        "many"])
+def test_war_lane_matches_per_row_gather(n_fifos, nw, nr, depths):
+    """The WAR lane (static gather, per-row barrel shift, static read)
+    gives the per-row gather's candidates exactly, bucket-padding WAR
+    rows (wseq = 0) and FIFOs without reads or blocking writes included."""
+    from repro.core.graph import export_chain_flat
+    from repro.kernels.maxplus import sparse as sp
+
+    rng = np.random.default_rng(n_fifos * 1000 + sum(map(ord, depths)))
+
+    def draw(v):
+        return v if isinstance(v, int) else int(rng.integers(*v))
+
+    sizes = [(draw(nw), draw(nr)) for _ in range(n_fifos)]
+    n = sum(a + b for a, b in sizes)
+    cols = rng.permutation(n)
+    w_cols, r_cols, blocking, lo = [], [], [], 0
+    for a, b in sizes:
+        w_cols.append(cols[lo:lo + a])
+        r_cols.append(cols[lo + a:lo + a + b])
+        blk = rng.random(a) < 0.8
+        blk[-1] = True
+        blocking.append(blk)
+        lo += a + b
+    if depths == "mixed":
+        blocking[0][:] = False              # a FIFO with no blocking write
+    arr = export_chain_flat(
+        [(0, n)], np.zeros(n, np.int64), np.zeros(n, np.int64),
+        np.zeros(0, np.int64), np.zeros(0, np.int64), np.zeros(0, np.int64),
+        w_cols, r_cols, blocking, bound=1 << 20, neg=SPARSE_NEG)
+    assert (arr.war_wseq == 0).any()        # bucket-padding WAR rows
+
+    K = 8
+    seg = arr.war_seg
+    Db = {"random": lambda: rng.integers(0, 2 * seg, (K, n_fifos)),
+          "zero": lambda: np.zeros((K, n_fifos)),
+          "deep": lambda: rng.integers(seg, 3 * seg, (K, n_fifos)),
+          "huge": lambda: np.full((K, n_fifos), 1 << 30),
+          "mixed": lambda: rng.choice([0, 1, 3, seg - 1, seg, 1 << 30],
+                                      (K, n_fifos))}[depths]()
+    Db = jnp.asarray(Db, jnp.int32)
+    t = jnp.asarray(rng.integers(-1000, 1000, (K, arr.npad)), jnp.int32)
+
+    shift, valid = sp._war_operands(
+        Db, *map(jnp.asarray, (arr.war_wseq, arr.war_fid, arr.war_nr,
+                               arr.war_lane_fid)), seg)
+    got = sp._war_candidates(t, jnp.asarray(arr.war_lane_src),
+                             jnp.asarray(arr.war_pos), shift, valid,
+                             sp.war_steps(seg))
+    roff = np.cumsum([0] + [len(r) for r in r_cols])[arr.war_fid]
+    roff = np.where(arr.war_wseq > 0, roff, 0)
+    want = _war_candidates_per_row_gather(
+        t, *map(jnp.asarray, (arr.war_wseq, arr.war_fid, arr.war_nr, roff,
+                              np.concatenate(r_cols).astype(np.int32))), Db)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    live = np.asarray(want) > SPARSE_NEG     # no depth >= seg leaves a read
+    assert live.any() == (depths not in ("deep", "huge"))
+
+
+def test_solve_chains_multicore_matches_numpy():
+    """The whole fixpoint on a Type C design whose redirect FIFOs are
+    non-blocking (their writes make no WAR rows) beside blocking ones."""
+    from repro.core import simulate
+    from repro.core.dse import (_batch_arrays, _solve_block_numpy,
+                                _sparse_arrays)
+    from repro.core.incremental import compile_graph
+    from repro.designs.paper import multicore
+    from repro.kernels.maxplus import sparse as sp
+
+    base = simulate(multicore(cores=2, prog_len=16, stride=4), trace="auto")
+    g = compile_graph(base.graph)
+    ba = _batch_arrays(g)
+    arr = _sparse_arrays(g, ba)
+    assert not all(b.all() for b in ba.fifo_blocking)   # NB FIFOs present
+    assert sp.war_steps(arr.war_seg) > 1
+    rng = np.random.default_rng(4)
+    Db = rng.integers(0, 12, size=(16, len(base.depths))).astype(np.int64)
+    t_np, conv_np, _ = _solve_block_numpy(ba, Db)
+    t_jx, conv_jx, _ = sp.solve_chains(arr, Db, interpret=True)
+    assert (conv_np == conv_jx).all()
+    cols = np.flatnonzero(conv_np)
+    assert len(cols)
+    assert (np.asarray(t_np)[:, cols] == t_jx[:, cols]).all()
+
+
+def _gathers(jaxpr, in_loop=False):
+    """(in a while loop?, eqn) of every gather in ``jaxpr``, nested
+    jaxprs included."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "gather":
+            yield in_loop, e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", None)
+                if inner is not None:
+                    yield from _gathers(getattr(inner, "jaxpr", inner),
+                                        in_loop or e.primitive.name == "while")
+
+
+def test_fixpoint_loop_gathers_share_their_indices():
+    """Every gather in the fixpoint's loop body reads whole columns (one
+    index for all K rows): the WAR half has no per-row gather."""
+    from repro.core import simulate
+    from repro.core.dse import _batch_arrays, _sparse_arrays
+    from repro.core.incremental import compile_graph
+    from repro.designs.paper import multicore
+    from repro.kernels.maxplus import sparse as sp
+
+    base = simulate(multicore(cores=2, prog_len=16, stride=4), trace="auto")
+    g = compile_graph(base.graph)
+    arr = _sparse_arrays(g, _batch_arrays(g))
+    K = 16
+    args, static = sp._fixpoint_args(
+        arr, np.ones((K, len(base.depths)), np.int64))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        sp._fixpoint, **static, interpret=True))(*args)
+    loop = [e for in_loop, e in _gathers(jaxpr.jaxpr) if in_loop]
+    assert len(loop) == 3                   # RAW source, WAR lane, WAR slot
+    for e in loop:
+        assert e.params["slice_sizes"][0] == K, e

@@ -26,7 +26,7 @@ SPANS = {
                        "interactive_rows"},
     "sweep.dequeue": {"rid", "lane", "rows", "block", "wait_us"},
     "sweep.dedup": {"block", "rows_unique", "memo_hits"},
-    "solve.upload": {"K"},
+    "solve.upload": {"K", "war_lane", "war_steps"},
     "solve.fixpoint": set(),
     "solve.copy_back": {"bytes"},
     "solve.transpose": set(),

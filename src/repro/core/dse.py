@@ -54,8 +54,9 @@ import numpy as np
 
 from ..device import pallas_interpret, span
 from .engine import OmniSim, simulate
-from .graph import (export_chain_flat, longest_path_chains,
-                    longest_path_chains_batched)
+from .graph import (CHECK_FLOOR, cycle_check_due, export_chain_flat,
+                    longest_path_chains, longest_path_chains_batched,
+                    pointer_cycle_rows)
 from .incremental import NEGI, CompiledGraph, compile_graph
 from .program import SimResult
 
@@ -389,8 +390,11 @@ def _solve_block_numpy(ba: _BatchArrays, Db: np.ndarray):
     sweeps.  int32 when the path-length bound allows (halves the traffic).
 
     Returns (times (n, K) in solve dtype, converged (K,), sweeps).
-    Non-converged configs (WAR cycle: times grow past the acyclic bound,
-    or the sweep cap is hit) report False and undefined times.
+    Non-converged configs (a WAR cycle) report False and undefined times:
+    retired when :func:`~repro.core.graph.pointer_cycle_rows` finds the
+    cycle among their predecessor pointers (run when
+    :func:`~repro.core.graph.cycle_check_due` says so), or by the
+    backstops — times past the acyclic bound, the sweep cap.
     """
     K = len(Db)
     n = len(ba.perm)
@@ -443,13 +447,19 @@ def _solve_block_numpy(ba: _BatchArrays, Db: np.ndarray):
     act = np.arange(K)
     sweeps = 0
     max_sweeps = n + 2
+    mark = CHECK_FLOOR
     while True:
-        # ---- retire configs with no pending chains (or diverged) ----
+        # ---- retire configs with no pending chains, or cyclic ones ----
         pend = dirty.any(axis=0)
         if sweeps >= 8 or not pend.any():
             over = (t > ba.bound).any(axis=0)
         else:
             over = np.zeros(len(act), dtype=bool)
+        if (~pend & ~over).any():
+            mark = max(mark, sweeps)
+        elif cycle_check_due(sweeps, mark):
+            mark = sweeps
+            over |= _pointer_cycles(ba, c, cw, war_entries, sweeps)
         done = ~pend | over
         if done.any():
             if done.all() and len(act) == K:
@@ -514,6 +524,23 @@ def _solve_block_numpy(ba: _BatchArrays, Db: np.ndarray):
     return times_out, conv_out, sweeps
 
 
+def _pointer_cycles(ba: _BatchArrays, c: np.ndarray, cw: np.ndarray,
+                    war_entries, steps: int) -> np.ndarray:
+    """:func:`~repro.core.graph.pointer_cycle_rows` over the node-major
+    working contributions of :func:`_solve_block_numpy` (its WAR edges
+    as its per-FIFO entries hold them, for the configs still active)."""
+    dst = [e[2] for e in war_entries]
+    src = [e[3][:, e[5]].T for e in war_entries]
+    val = [e[4][:, e[5]].T for e in war_entries]
+    K = c.shape[1]
+    return pointer_cycle_rows(
+        np.ascontiguousarray(c.T), cw, ba.slices, ba.raw_dst, ba.raw_src,
+        np.concatenate(dst) if dst else np.zeros(0, np.int64),
+        np.concatenate(src, axis=1) if src else np.zeros((K, 0), np.int64),
+        np.concatenate(val, axis=1) if val else np.zeros((K, 0), bool),
+        steps)
+
+
 def solve_block_status(cache: CompiledGraph, depth_block,
                        backend: str = "numpy", block: int = 128,
                        jax_interpret: Optional[bool] = None):
@@ -566,7 +593,7 @@ def solve_block_status(cache: CompiledGraph, depth_block,
                 solve = _solve_block_reference
             else:           # sparse chain-structured Pallas max-plus lane
                 solve = (lambda ba_, Db_: _solve_sparse_jax(
-                    cache, ba_, Db_, interpret=jax_interpret))
+                    cache, ba_, Db_, interpret=jax_interpret, block=block))
             blocks = []
             for lo in range(0, len(alive), max(block, 1)):
                 sl = np.arange(lo, min(lo + max(block, 1), len(alive)))
@@ -768,7 +795,8 @@ def _sparse_arrays(cache: CompiledGraph, ba: _BatchArrays):
 
 
 def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
-                      Db: np.ndarray, interpret: Optional[bool] = None):
+                      Db: np.ndarray, interpret: Optional[bool] = None,
+                      block: Optional[int] = None):
     """Sparse chain-structured Pallas solve for one block of configs.
 
     Seeds every config at the no-WAR fixpoint contribution (``c_inf``, a
@@ -776,12 +804,92 @@ def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
     chain-pass/cross-pass to the same unique least fixpoint the numpy
     Gauss-Seidel reaches — times, and hence statuses/cycles/violations,
     are bit-identical for converged rows.  O(K·n + K·edges) memory.
+    Rows the device leaves undecided (at its straggler cap, or where a
+    block shorter than ``block`` stops in place of a cycle check) are
+    solved exactly on the host by :func:`_solve_rows_exact`.
     """
     from ..kernels.maxplus import sparse as sp
 
     _int32_saturation_guard(ba, "jax")
     arr = _sparse_arrays(cache, ba)
-    return sp.solve_chains(arr, Db, interpret=interpret)
+    times, conv, rounds, unsettled = sp.solve_chains(
+        arr, Db, interpret=interpret, block=block)
+    if unsettled.any():
+        with span("solve.exact", rows=int(unsettled.sum())):
+            times, conv = np.array(times), np.array(conv)
+            t_ex, conv[unsettled] = _solve_rows_exact(ba, Db[unsettled],
+                                                      int(sp.NEG))
+            times[:, unsettled] = t_ex
+    return times, conv, rounds
+
+
+def _solve_rows_exact(ba: _BatchArrays, Db: np.ndarray, neg: int):
+    """Each depth row's least fixpoint by one topological pass over its
+    own graph: ``(times (n, k) int64 chain-major, converged (k,))``.
+
+    Each chain's pointer advances over the nodes whose one cross in-edge
+    source (RAW, or WAR under the row's depth) is valued already, and
+    values each as ``max`` of its seed contribution (``c_inf``, floored
+    at ``neg`` as the device lane seeds it), its chain predecessor's time
+    plus the SEQ weight and the source's time plus the edge weight; the
+    chains are swept in turn until none advances.  On an acyclic graph
+    that is the least fixpoint the chain iteration converges to, bit for
+    bit.  A row whose graph holds a cycle leaves chains that never reach
+    their end: it is not converged (CYCLE).  The pass costs O(n) a row
+    whatever the rounds the iteration would take, so it finishes the rows
+    the device lane leaves at its straggler cap.  The iterating lanes
+    cannot: on a lockstep row of ``optical_flow(4, 1024)`` the
+    Gauss-Seidel lane (:func:`_solve_block_numpy`) takes 2,044 sweeps and
+    11.9 s on a CPU host, the Jacobi lane 4,093 rounds, where this pass
+    takes 0.09 s.
+    """
+    n, k = len(ba.perm), len(Db)
+    times = np.zeros((n, k), np.int64)
+    conv = np.zeros(k, bool)
+    los = [lo for (lo, hi) in ba.slices]
+    his = [hi for (lo, hi) in ba.slices]
+    owner = np.zeros(n, np.int64)
+    for i, (lo, hi) in enumerate(ba.slices):
+        owner[lo:hi] = i
+    seq_w = np.diff(ba.cw, prepend=0).tolist()
+    c0 = np.maximum(ba.c_inf, neg).tolist()
+    raw_src = np.full(n, -1, np.int64)
+    raw_src[ba.raw_dst] = ba.raw_src
+    raw_w = np.zeros(n, np.int64)
+    raw_w[ba.raw_dst] = ba.raw_w
+    dd, ds, dv = _regen_war_stacked(ba, Db)
+    for r in range(k):
+        src, w = raw_src.copy(), raw_w.copy()
+        src[dd[dv[r]]] = ds[r, dv[r]]
+        w[dd[dv[r]]] = 1
+        src_chain = owner[np.maximum(src, 0)].tolist()
+        src, w = src.tolist(), w.tolist()
+        t = [0] * n
+        ptr = list(los)
+        moved = True
+        while moved:
+            moved = False
+            for i, hi in enumerate(his):
+                j, lo = ptr[i], los[i]
+                while j < hi:
+                    s = src[j]
+                    # a source is valued once its chain's pointer passed it
+                    if s >= 0 and s >= (j if src_chain[j] == i
+                                        else ptr[src_chain[j]]):
+                        break
+                    v = c0[j]
+                    if j > lo:
+                        v = max(v, t[j - 1] + seq_w[j])
+                    if s >= 0:
+                        v = max(v, t[s] + w[j])
+                    t[j] = v
+                    j += 1
+                if j > ptr[i]:
+                    ptr[i] = j
+                    moved = True
+        conv[r] = ptr == his
+        times[:, r] = t
+    return times, conv
 
 
 def _solve_dense_jax(cache: CompiledGraph, ba: _BatchArrays, Db: np.ndarray,

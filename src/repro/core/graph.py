@@ -212,6 +212,94 @@ def longest_path_chains(chains, seq_w, base, cross_dst, cross_src, cross_w,
     return t
 
 
+CHECK_FLOOR = 2      # no cycle check before round 2 * CHECK_FLOOR + 1
+
+
+def cycle_check_due(rounds: int, mark: int) -> bool:
+    """Whether a batched fixpoint runs its cycle check this round (callers
+    ask only in a round in which no row converged).
+
+    ``mark`` is the last round at which a row of the block converged or
+    the check ran, and never below :data:`CHECK_FLOOR`: the check runs
+    once some row has stayed pending for twice as many rounds, so a block
+    whose rows converge together never runs it, and each check (at most
+    ``rounds`` label steps) at least doubles the rounds before the next,
+    keeping the checks' work within a constant factor of the rounds.
+    The device lane calls it with traced scalars."""
+    return rounds > 2 * mark
+
+
+def pointer_cycle_rows(c, cw, chain_slices, cross_dst, cross_src, dyn_dst,
+                       dyn_src, dyn_valid, steps: int) -> np.ndarray:
+    """(K,) bool: the rows of a batched chain fixpoint whose predecessor
+    pointers close a cycle — rows that can never converge.
+
+    ``c`` is the (K, n) chain-major contribution matrix of the rows still
+    iterating, ``cw`` the cumulative SEQ weights; the edges are those of
+    :func:`longest_path_chains_batched`.  Every node gets one pointer, an
+    edge of its row's graph (the longest-path form of the Bellman–Ford
+    predecessor lemma):
+
+    * a node whose own contribution attains its time under ``c`` (a
+      *root*: ``c - cw`` reaches its chain's running max there, ties
+      included) points to the source of its cross in-edge — its one RAW
+      edge, or its WAR edge where the row's depth makes one — and to
+      nothing if it has none;
+    * any other node points to its chain's latest root at or before it,
+      through the SEQ edges between them.
+
+    Max-id labels then flood along the pointers for up to ``steps``
+    rounds: a node's label is the largest node id on its pointer path
+    (chain pointers are followed a whole run at a time, so a step crosses
+    one cross edge).  A node that finds its own id in the label it pulls
+    lies on a cycle of pointers.
+
+    Exactness.  Every pointer follows edges of the row's own graph, so a
+    pointer cycle is a cycle of that graph, whatever the values, ties or
+    Jacobi/Gauss–Seidel staleness that chose the pointers; every cycle
+    holds a WAR edge (SEQ + RAW alone are the base run's DAG), a WAR edge
+    weighs 1 and no edge less than 0, so every cycle is positive and
+    the row has no fixpoint: it is CYCLE, the verdict the ``bound`` and
+    iteration-cap exits reach, only sooner.  A row without a cycle is never
+    flagged.  The converse needs no proof for exactness: a cyclic row the
+    check misses stays pending and is caught by a later check or a
+    backstop.  In practice the pointers close along the cycle once its
+    weight has gone round it about twice (values on it then dominate their
+    other in-edges), and the label needs one step per cross edge of the
+    cycle to find it, so detection takes a number of rounds set by the
+    cycle's cross edges, not by the length of the design.
+    """
+    K, n = c.shape
+    col = np.arange(n, dtype=np.int64)
+    rp = np.empty((K, n), np.int64)          # latest root at or before
+    for (lo, hi) in chain_slices:
+        x = c[:, lo:hi] - cw[lo:hi]
+        top = np.maximum.accumulate(x, axis=1)
+        rp[:, lo:hi] = np.maximum.accumulate(
+            np.where(x == top, col[lo:hi], -1), axis=1)
+    par = np.full((K, n), -1, np.int64)      # a root's cross source
+    if len(cross_dst):
+        par[:, cross_dst] = cross_src
+    if len(dyn_dst):
+        par[:, dyn_dst] = np.where(dyn_valid, dyn_src, -1)
+    par = np.where(rp == col, par, -1)
+    has = par >= 0
+    src = np.maximum(par, 0)
+
+    def pull(ids):                           # at roots: max id up the path
+        return np.where(has, np.take_along_axis(ids, src, axis=1), -1)
+
+    label = pull(np.broadcast_to(col, (K, n)))
+    found = np.zeros(K, dtype=bool)
+    for _ in range(max(int(steps), 1)):
+        fill = np.take_along_axis(label, rp, axis=1)
+        found |= (fill == col).any(axis=1)
+        if found.all():
+            break
+        label = pull(np.maximum(fill, col))
+    return found
+
+
 def longest_path_chains_batched(chain_slices, cw, base, cross_dst, cross_src,
                                 cross_w, dyn_dst, dyn_src_idx, dyn_valid,
                                 bound: int, max_iters: int = 0):
@@ -237,10 +325,18 @@ def longest_path_chains_batched(chain_slices, cw, base, cross_dst, cross_src,
 
     ``base`` is the (K, n) initial contribution matrix (consumed in place).
     Rows converge independently: converged rows are retired from the working
-    set each round, so one pathological config (a WAR cycle grows its times
-    past ``bound``) does not tax the others.  Returns ``(times, converged,
-    rounds)`` — times (K, n); ``converged[k]`` False means config k's
-    regenerated edges formed a cycle (times for that row are meaningless).
+    set each round, so one pathological config does not tax the others.  A
+    config whose regenerated edges close a cycle is retired as soon as
+    :func:`pointer_cycle_rows` finds the cycle among its predecessor
+    pointers — a check that runs only when :func:`cycle_check_due` says
+    some row has stayed pending well past the rows that converged, and is
+    exact (it flags only rows that have a cycle).  A cycle gains only its
+    own weight per trip round it, so the older exits — times past the
+    acyclic ``bound``, or the iteration cap — take rounds that grow with
+    the design's length; they stay as backstops.  Returns ``(times,
+    converged, rounds)`` — times (K, n); ``converged[k]`` False means
+    config k's regenerated edges formed a cycle (times for that row are
+    meaningless).
     """
     K, n = base.shape
     times = np.empty_like(base)
@@ -256,6 +352,7 @@ def longest_path_chains_batched(chain_slices, cw, base, cross_dst, cross_src,
     dyn_src_act = dyn_src_idx if have_dyn else None
     dyn_valid_act = dyn_valid if have_dyn else None
     rounds = 0
+    mark = CHECK_FLOOR
     while len(act):
         rounds += 1
         # ---- chain pass: t = cw + cummax(c - cw) per contiguous chain ----
@@ -284,8 +381,17 @@ def longest_path_chains_batched(chain_slices, cw, base, cross_dst, cross_src,
             np.maximum(cand, old, out=cand)
             changed |= (cand != old).any(axis=1)
             c[:, dyn_dst] = cand
-        # ---- retire rows: fixpoint reached or blown past the DAG bound ----
-        over = (t > bound).any(axis=1)      # positive cycle: early exit
+        # ---- retire rows: fixpoint reached, or a cycle: pointers close
+        # one, or times blew past the DAG bound ----
+        over = (t > bound).any(axis=1)
+        if (~changed & ~over).any():
+            mark = max(mark, rounds)
+        elif len(act) and cycle_check_due(rounds, mark):
+            mark = rounds
+            over |= pointer_cycle_rows(
+                c, cw, chain_slices, cross_dst, cross_src,
+                dyn_dst if have_dyn else (),
+                dyn_src_act, dyn_valid_act, steps=rounds)
         done = ~changed | over
         if done.any():
             rows = act[done]

@@ -48,8 +48,52 @@ one of its empty slots is a masked target.  Only the shift amounts and
 the validity mask depend on the depth block, computed once per solve.
 
 Rows diverge independently: a config whose regenerated WAR edges form a
-cycle grows its times past the acyclic ``bound`` and is frozen (reported
-non-converged = CYCLE upstream) without taxing the other rows.
+cycle is frozen (reported non-converged = CYCLE upstream) without taxing
+the other rows.  The cycle exit is the device form of
+:func:`repro.core.graph.pointer_cycle_rows`, run under a ``lax.cond`` in
+the ``cycle_check`` scope once some row has stayed pending for twice the
+rounds since a row of the block last converged (a block whose rows
+converge together never runs it):
+
+* every node gets a predecessor pointer, an edge of its row's graph: a
+  *root* — a node whose own contribution attains its time, ``c == t``,
+  ties included — points to the source of its one cross in-edge (RAW, or
+  WAR where the row's depth makes one; none otherwise), any other node
+  to its chain's latest root at or before it.  One segmented cummax of
+  the root columns gives each node that root;
+* max-id labels flood along the pointers, one cross edge per step: the
+  same segmented-cummax kernel, fed that root column as *per-row*
+  segment starts, hands each node its root's label, and the cross pass's
+  own gathers (RAW source columns, the WAR lane and its barrel shift)
+  pull a root's label from its source.  A node that meets its own id
+  lies on a pointer cycle.  Steps stop when every pending row has met
+  one, or after as many steps as rounds so far.
+
+It is exact.  A pointer cycle is a cycle of the row's graph whatever the
+values, ties or the Jacobi round's simultaneous updates that chose the
+pointers: each pointer is an edge (or a run of SEQ edges) of that graph.
+Every cycle holds a WAR edge, a WAR edge weighs 1 and no edge less than
+0, so the cycle is positive and the row has no fixpoint: it is
+CYCLE, the verdict the older exits reach, only sooner.  A cyclic row the
+check misses stays pending; its times passing the acyclic ``bound`` and
+the ``n + 2`` round cap remain as backstops.  A cycle gains only its own
+weight per trip round it, so those backstops take rounds that grow with
+the design's length; the pointers close once the cycle's weight has gone
+round about twice, so the check finds it in rounds set by the cycle's
+cross edges.  ``_fixpoint`` returns each row's freeze round (0 if the
+exit did not freeze it).
+
+A row that converges, but slowly, is not held either: once half the
+block's rows have settled, the loop stops at :func:`straggler_cap`
+(running a cycle check by then only if its label steps end before the
+cap) and reports the rows still pending as undecided (``unsettled``);
+the caller solves them exactly on the host.  Such rows are lockstep
+pairs of processes coupled by a FIFO just deep enough, which advance one
+item a round.  The check is compiled only into the program for the
+caller's full block height (``solve_chains(block=...)``): a shorter
+block stops where the check would first run, while fewer than half its
+rows have settled, and leaves the rows still pending to the same exact
+pass.
 
 Everything is int32 on device — callers must clip against :data:`NEG`
 and refuse graphs whose path-length bound nears int32 range (see
@@ -72,7 +116,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.graph import ChainFlatArrays
+from ...core.graph import CHECK_FLOOR, ChainFlatArrays, cycle_check_due
 from ...device import pallas_interpret, span
 
 # int32 -INF sentinel — matches the numpy solver's int32 mode, and leaves
@@ -172,14 +216,16 @@ def segmented_cummax_ref(x: jnp.ndarray, seg_start: jnp.ndarray,
 
 def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
                      max_seg=None, interpret: Optional[bool] = None,
-                     width: Optional[int] = None):
+                     width: Optional[int] = None, name: str = "segcummax"):
     """Segmented cummax over (K, npad); ``seg_start[j]`` is column j's
-    segment start, ``max_seg`` an optional bound on segment length (caps
-    the scan's doubling steps).  K must be a ROWS multiple and npad a
-    multiple of the node tile ``width`` (default :func:`_tile_width`),
-    itself a LANES multiple — callers bucket-pad, see
-    :func:`_fixpoint_args`.  ``interpret=None`` derives the mode from the
-    platform (:func:`repro.device.pallas_interpret`).
+    segment start — one (npad,) vector shared by every row, or a (K, npad)
+    matrix giving each row segments of its own — and ``max_seg`` an
+    optional bound on segment length (caps the scan's doubling steps).  K
+    must be a ROWS multiple and npad a multiple of the node tile ``width``
+    (default :func:`_tile_width`), itself a LANES multiple — callers
+    bucket-pad, see :func:`_fixpoint_args`.  ``interpret=None`` derives
+    the mode from the platform (:func:`repro.device.pallas_interpret`).
+    ``name`` names the kernel in a device trace.
     """
     if interpret is None:
         interpret = pallas_interpret()
@@ -188,13 +234,18 @@ def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
     rows = _rows_for(K, width)
     assert K % rows == 0 and npad % width == 0 and width % LANES == 0, \
         (K, npad, width)
+    if seg_start.ndim == 2:                 # per-row segments
+        seg_spec = pl.BlockSpec((rows, width), lambda i, j: (i, j))
+    else:
+        seg_spec = pl.BlockSpec((1, width), lambda i, j: (0, j))
+        seg_start = seg_start.reshape(1, npad)
     return pl.pallas_call(
         functools.partial(_segcummax_kernel,
                           _scan_limit(width, max_seg)),
         grid=(K // rows, npad // width),
         in_specs=[
             pl.BlockSpec((rows, width), lambda i, j: (i, j)),
-            pl.BlockSpec((1, width), lambda i, j: (0, j)),
+            seg_spec,
         ],
         out_specs=pl.BlockSpec((rows, width), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, npad), x.dtype),
@@ -202,8 +253,8 @@ def segmented_cummax(x: jnp.ndarray, seg_start: jnp.ndarray, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="segcummax",
-    )(x, seg_start.reshape(1, npad).astype(jnp.int32))
+        name=name,
+    )(x, seg_start.astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -241,17 +292,34 @@ def _war_operands(Db, war_wseq, war_fid, war_nr, war_lane_fid, war_seg):
     return war_shift, war_valid
 
 
+def straggler_cap(half_at):
+    """The round at which the fixpoint stops waiting for its last rows:
+    twice the round by which half the block's rows had settled, plus 8.
+    A row still pending then (a lockstep pair of processes coupled by a
+    shallow FIFO takes about one round per item it streams) leaves the
+    device undecided, and the caller solves it exactly on the host
+    (``core.dse._solve_rows_exact``), so one row never holds a block for
+    thousands of rounds.  Rows of one block that settle together never
+    meet the cap: ``multicore``'s settle by round 8 (the cap is at least
+    10) and ``skynet_like``'s by round 3; of uniformly drawn
+    ``optical_flow`` depth rows at 8 x 1,024, 2 of 6,144 are lockstep
+    rows that do.
+    """
+    return 2 * half_at + 8
+
+
 @functools.partial(jax.jit,
                    static_argnames=("max_seg", "war_seg", "width",
-                                    "interpret"))
+                                    "interpret", "check"))
 def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
               war_dst, war_wseq, war_fid, war_nr, war_pos, war_lane_src,
               war_lane_fid, Db, bound, iters, *, max_seg: int, war_seg: int,
-              width: int, interpret: bool):
+              width: int, interpret: bool, check: bool = True):
     K = Db.shape[0]
     npad = c_seed.shape[0]
     c0 = jnp.broadcast_to(c_seed[None, :], (K, npad))
     cw_row = cw[None, :]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, npad), 1)
 
     # depth-dependent WAR shifts and mask, computed on-device once per
     # solve: write wseq of FIFO fid waits on read (wseq - S - 1)
@@ -260,12 +328,65 @@ def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
         war_shift, war_valid = _war_operands(Db, war_wseq, war_fid, war_nr,
                                              war_lane_fid, war_seg)
 
+    def scan(x, seg, name="segcummax"):
+        return segmented_cummax(x, seg, max_seg=max_seg, interpret=interpret,
+                                width=width, name=name)
+
     # the scopes name the passes' ops in a device trace (op_name metadata)
     def chain_pass(c):
         with jax.named_scope("chain_pass"):
-            seg = segmented_cummax(c - cw_row, seg_start, max_seg=max_seg,
-                                   interpret=interpret, width=width)
-            return seg + cw_row
+            return scan(c - cw_row, seg_start) + cw_row
+
+    def cycle_rows(c, t, live, steps):
+        """Rows of ``live`` whose predecessor pointers close a cycle: the
+        device form of :func:`repro.core.graph.pointer_cycle_rows` (the
+        module docstring gives the argument).  ``t`` is ``chain(c)``."""
+        with jax.named_scope("cycle_check"):
+            def pull(ids, root):
+                """(K, npad): at each root, ``ids`` of its cross source
+                (NEG where it has none)."""
+                lab = jnp.full((K, npad), NEG, jnp.int32)
+                if raw_dst.shape[0]:
+                    lab = lab.at[:, raw_dst].max(
+                        jnp.where(raw_w[None, :] > jnp.int32(NEG),
+                                  ids[:, raw_src], jnp.int32(NEG)),
+                        indices_are_sorted=True)
+                if have_war:
+                    cand = _war_candidates(ids, war_lane_src, war_pos,
+                                           war_shift, war_valid,
+                                           war_steps(war_seg))
+                    lab = lab.at[:, war_dst].max(
+                        jnp.where(cand > jnp.int32(NEG), cand - 1,
+                                  jnp.int32(NEG)),
+                        indices_are_sorted=True)
+                return jnp.where(root, lab, jnp.int32(NEG))
+
+            def step(state):
+                # step 0 scans the root columns over the chains: each
+                # column's latest root, a column its own contribution
+                # attains (a non-root points to it, a root to its cross
+                # source); each later step pulls every root's label from
+                # its source and scans it over those per-row segments: a
+                # column takes its root's label, the largest id on the
+                # root's pointer path.  One scan serves both.
+                ids, root_at, found, j = state
+                first = j == 0
+                x = jnp.where(first, jnp.where(c == t, col, -1),
+                              pull(ids, root_at == col))
+                fill = scan(x, jnp.where(first, seg_start[None, :], root_at),
+                            "cycle_fill")
+                found = found | (~first & (fill == col).any(axis=1))
+                return (jnp.where(first, ids, jnp.maximum(fill, col)),
+                        jnp.where(first, fill, root_at), found, j + 1)
+
+            def more(state):
+                _, _, found, j = state
+                return jnp.logical_and(j <= steps, (live & ~found).any())
+
+            ids0 = jnp.broadcast_to(col, (K, npad))
+            state0 = (ids0, ids0, jnp.zeros(K, bool), jnp.int32(0))
+            _, _, found, _ = jax.lax.while_loop(more, step, state0)
+            return found & live
 
     def cross_pass(c, t):
         # export_chain_flat sorts both scatters' destinations (padding
@@ -289,23 +410,57 @@ def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
         return c2
 
     def body(state):
-        c, _, diverged, _, rounds = state
+        (c, _, diverged, was_pending, rounds, frozen_at, mark, half_at,
+         halted) = state
+        rounds = rounds + 1
         t = chain_pass(c)
         diverged = diverged | (t > bound).any(axis=1)
         c2 = cross_pass(c, t)
         c2 = jnp.where(diverged[:, None], c, c2)   # freeze cyclic rows
         pending = (c2 != c).any(axis=1) & ~diverged
-        return c2, t, diverged, pending, rounds + 1
+        # as the numpy lanes: a round in which no row settled, and some
+        # row has stayed pending for twice the rounds since one last did;
+        # once half the rows have settled, only a check whose label steps
+        # (at most ``rounds``) end before the straggler cap: past that the
+        # host's exact pass decides what is left
+        settled = (was_pending & ~pending & ~diverged).any()
+        room = (half_at == 0) | (2 * rounds <= straggler_cap(half_at))
+        due = (~settled & pending.any() & room
+               & cycle_check_due(rounds, mark))
+        mark = jnp.where(settled, jnp.maximum(mark, rounds),
+                         jnp.where(due, rounds, mark))
+        if check:
+            cyc = jax.lax.cond(due, cycle_rows,
+                               lambda *_: jnp.zeros(K, bool),
+                               c, t, pending, rounds)
+        else:
+            # no check compiled: a block still short of half its rows
+            # stops where it would run one; the host decides what is left
+            cyc = jnp.zeros(K, bool)
+            halted = halted | (due & (half_at == 0))
+        frozen_at = jnp.where(cyc, rounds, frozen_at)
+        pending = pending & ~cyc
+        half_at = jnp.where((half_at == 0) & (2 * (~pending).sum() >= K),
+                            rounds, half_at)
+        return (c2, t, diverged | cyc, pending, rounds, frozen_at, mark,
+                half_at, halted)
 
     def cond(state):
-        _, _, _, pending, rounds = state
-        return jnp.logical_and(pending.any(), rounds < iters)
+        pending, rounds, half_at, halted = (state[3], state[4], state[7],
+                                            state[8])
+        late = (half_at > 0) & (rounds >= straggler_cap(half_at))
+        return pending.any() & (rounds < iters) & ~late & ~halted
 
-    state0 = (c0, c0, jnp.zeros(K, bool), jnp.ones(K, bool), jnp.int32(0))
-    _, t, diverged, pending, rounds = jax.lax.while_loop(cond, body, state0)
-    # pending rows at the cap never reached a fixpoint (cycle), same as
-    # longest_path_chains_batched's iteration-cap leftover rows
-    return t, ~(diverged | pending), rounds
+    state0 = (c0, c0, jnp.zeros(K, bool), jnp.ones(K, bool), jnp.int32(0),
+              jnp.zeros(K, jnp.int32), jnp.int32(CHECK_FLOOR), jnp.int32(0),
+              jnp.bool_(False))
+    _, t, diverged, pending, rounds, frozen_at, _, _, _ = jax.lax.while_loop(
+        cond, body, state0)
+    # pending rows at the n + 2 cap never reached a fixpoint (cycle), same
+    # as longest_path_chains_batched's iteration-cap leftover rows; rows
+    # the straggler cap (or a halt) left pending are undecided
+    unsettled = pending & (rounds < iters)
+    return t, ~(diverged | pending), rounds, frozen_at, unsettled
 
 
 def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
@@ -337,37 +492,58 @@ def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
 
 
 def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 block: Optional[int] = None):
     """Solve K depth configs over one chain-flat graph.
 
     ``Db``: (K, n_fifos) depth block.  Returns ``(times, converged,
-    rounds)`` — ``times`` (n, K) int32 in chain-major node order (the
-    layout ``core.dse.solve_block_status`` consumes), ``converged[k]``
-    False where config k's regenerated WAR edges form a cycle.
+    rounds, unsettled)`` — ``times`` (n, K) int32 in chain-major node
+    order (the layout ``core.dse.solve_block_status`` consumes),
+    ``converged[k]`` False where config k's regenerated WAR edges form a
+    cycle or where ``unsettled[k]``: the row was still pending at
+    :func:`straggler_cap`, undecided, its times not final.
     ``interpret=None`` derives the Pallas mode from the platform.
+
+    ``block`` is the caller's full block height.  A block that pads to
+    fewer rows runs a program without the cycle check, which costs
+    compile time at every height: where the check would first run, while
+    fewer than half its rows have settled, it stops and leaves the rows
+    still pending undecided, for the host's exact pass.  So a sweep
+    compiles the check once, for its full blocks.
 
     Each phase is a host span: ``solve.upload`` (operands to the device,
     dispatch; metadata: the padded batch ``K``, the WAR lane's length
     ``war_lane`` and its shift steps ``war_steps``), ``solve.fixpoint``
-    (waiting for the device), ``solve.copy_back`` (results to the host)
-    and ``solve.transpose``.
+    (waiting for the device; metadata: the loop's ``rounds``, the rows
+    the cycle exit froze, ``cyclic_rows``, ``freeze_round``, the round
+    the last of them froze, 0 for none, and ``unsettled_rows``),
+    ``solve.copy_back``
+    (results to the host) and ``solve.transpose``.
     """
     interpret = pallas_interpret(interpret)
     K = len(Db)
     if K == 0 or arr.n == 0:
-        return (np.zeros((arr.n, K), np.int32), np.ones(K, bool), 0)
+        return (np.zeros((arr.n, K), np.int32), np.ones(K, bool), 0,
+                np.zeros(K, bool))
     with span("solve.upload") as sp:
         args, static = _fixpoint_args(arr, Db)
+        floor = max(ROWS, 1)
+        check = block is None or _pow2(K, floor) >= _pow2(block, floor)
         out = _fixpoint(*map(jnp.asarray, args), **static,
-                        interpret=interpret)
+                        interpret=interpret, check=check)
         sp.set_metadata(K=int(out[0].shape[0]),       # padded batch
                         war_lane=len(arr.war_lane_src),
                         war_steps=war_steps(arr.war_seg))
-    with span("solve.fixpoint"):
-        t, conv, rounds = jax.block_until_ready(out)
-    with span("solve.copy_back",
-              bytes=t.nbytes + conv.nbytes + rounds.nbytes):
-        t, conv, rounds = np.asarray(t), np.asarray(conv), int(rounds)
+    with span("solve.fixpoint") as sp:
+        t, conv, rounds, frozen_at, unsettled = jax.block_until_ready(out)
+        rounds, frozen_at = int(rounds), np.asarray(frozen_at)[:K]
+        unsettled = np.asarray(unsettled)[:K]
+        sp.set_metadata(rounds=rounds,
+                        cyclic_rows=int((frozen_at > 0).sum()),
+                        freeze_round=int(frozen_at.max()),
+                        unsettled_rows=int(unsettled.sum()))
+    with span("solve.copy_back", bytes=t.nbytes + conv.nbytes):
+        t, conv = np.asarray(t), np.asarray(conv)
     with span("solve.transpose"):
         times = np.ascontiguousarray(t[:K, :arr.n].T)
-    return times, conv[:K], rounds
+    return times, conv[:K], rounds, unsettled

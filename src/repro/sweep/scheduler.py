@@ -83,7 +83,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from ..core.dse import (CANCELLED, FAULTED, REUSED, TIMED_OUT,
+from ..core.dse import (CANCELLED, CYCLE, FAULTED, REUSED, TIMED_OUT,
                         materialize_block, solve_block_status)
 from ..core.program import SimResult
 from ..device import pallas_interpret, span
@@ -320,6 +320,7 @@ class BlockScheduler:
         self.stats_memo_hits = 0         # rows answered without a solve
         self.stats_solve_calls = 0       # shard solves that returned
         self.stats_fixpoint_rounds = 0   # their fixpoint rounds, summed
+        self.stats_cyclic_rows = 0       # rows they froze as cyclic
         self.stats_forced_bulk = 0       # bulk blocks forced past waiting
         #                                  interactive rows
 
@@ -664,6 +665,8 @@ class BlockScheduler:
                 with self._cv:
                     self.stats_solve_calls += 1
                     self.stats_fixpoint_rounds += int(rounds)
+                    self.stats_cyclic_rows += int(
+                        (np.asarray(status) == CYCLE).sum())
                 return (np.asarray(status, np.int8),
                         np.asarray(cycles, np.int64),
                         np.asarray(violated, np.int64), "")
@@ -945,6 +948,7 @@ class BlockScheduler:
                 "memo_size": len(self._memo),
                 "solve_calls": self.stats_solve_calls,
                 "fixpoint_rounds": self.stats_fixpoint_rounds,
+                "cyclic_rows": self.stats_cyclic_rows,
                 "forced_bulk_blocks": self.stats_forced_bulk,
                 "shards": self.shards,
                 "mode": self.mode,

@@ -8,7 +8,9 @@ import pytest
 
 from repro.core import LightningSim, UnsupportedDesignError, classify, csim, \
     simulate, simulate_rtl
-from repro.designs import PAPER_DESIGNS, TYPEA_DESIGNS
+from repro.core.trace import simulate_hybrid
+from repro.designs import PAPER_DESIGNS, ROSETTA_DESIGNS, TYPEA_DESIGNS
+from repro.designs.rosetta import optical_flow
 
 SMALL_N = 257        # keep unit tests fast; benchmarks use the full N=2025
 
@@ -101,3 +103,46 @@ def test_typea_classified_a(name):
     prog = builder()
     c = classify(prog, simulate(builder()))
     assert c.dtype == "A", f"{name}: {c}"
+
+
+def test_optical_flow_inventory():
+    """Rosetta's optical flow: 9 processes, 14 streams, gradient_z sized
+    at the source's max_width * 4."""
+    prog = ROSETTA_DESIGNS["optical_flow"](height=4, width=64)
+    assert len(prog.modules) == 9
+    assert len(prog.fifos) == 14
+    depths = {f.name: f.depth for f in prog.fifos}
+    assert depths.pop("gradient_z") == 4096
+    assert set(depths.values()) == {1024}
+
+
+def test_optical_flow_all_engines_agree():
+    """Generator engine, trace replay, hybrid replay, the decoupled
+    baseline and the RTL oracle: identical cycles and outputs."""
+    def build():
+        return optical_flow(height=4, width=64)
+    gen = simulate(build(), trace="never")
+    traced = simulate(build(), trace="auto")
+    assert traced.engine == "omnisim-trace"
+    runs = [traced, simulate_hybrid(build()), LightningSim(build()).run(),
+            simulate_rtl(build())]
+    assert not gen.deadlock
+    assert len(gen.outputs) == 4 * 64            # one velocity a pixel
+    for r in runs:
+        assert r.cycles == gen.cycles
+        assert r.outputs == gen.outputs
+
+
+def test_optical_flow_starved_z_path_deadlocks():
+    """The z path has to hold about 2 * width + 2 pixels while the x/y
+    path's line buffers fill: gradient_z plus a frame stream with less
+    room than that deadlocks; either one roomy is enough."""
+    W = 64
+    names = [f.name for f in optical_flow(height=4, width=W).fifos]
+
+    def run(**over):
+        depths = [over.get(n, 1024) for n in names]
+        return simulate(optical_flow(height=4, width=W), depths=depths)
+    assert run(gradient_z=8, frame1_a=8).deadlock
+    assert not run(gradient_z=8).deadlock
+    assert not run(gradient_z=2 * W + 8, frame1_a=8).deadlock

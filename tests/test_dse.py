@@ -362,3 +362,195 @@ def test_reused_shells_do_not_alias_mutable_state():
     assert base.stats.queries != -123
     assert "sentinel" not in r1.constraints
     assert "sentinel" not in base.constraints
+
+
+# ------------------------------------------------------------ cycle exit
+def _bench_reference():
+    """``bench/reference.py``: the benchmark's plain reference simulator,
+    which imports nothing of ``repro``."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "reference.py")
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _optical_flow_rows(height, width, seed, k=12):
+    """optical_flow's compiled graph and ``k`` depth rows uniform in
+    [1, 4 * width]^14: the scale at which about a third deadlock."""
+    from repro.core.incremental import compile_graph
+    from repro.designs.rosetta import optical_flow
+    base = simulate(optical_flow(height, width), trace="auto")
+    D = np.random.default_rng(seed).integers(1, 4 * width + 1,
+                                             (k, len(base.depths)))
+    return base, compile_graph(base.graph), D
+
+
+@pytest.mark.parametrize("height,width,seed", [(2, 64, 0), (3, 128, 1),
+                                               (4, 256, 2)])
+def test_optical_flow_lanes_agree_with_the_bench_reference(height, width,
+                                                           seed):
+    """The jax, numpy and Jacobi lanes give the statuses and cycles of a
+    from-scratch run of the benchmark's reference, CYCLE rows included."""
+    from repro.core.dse import CYCLE, REUSED, VIOLATED, solve_block_status
+    from repro.designs.rosetta import optical_flow
+    ref = _bench_reference()
+    base, g, D = _optical_flow_rows(height, width, seed)
+    bodies = [m.fn for m in optical_flow(height, width).modules]
+    rb = ref.simulate(list(base.depths), bodies)
+    want_s, want_c = [], []
+    for row in D:
+        row = [int(d) for d in row]
+        run = ref.simulate(row, bodies)
+        if not run.deadlock and run.outcomes == rb.outcomes:
+            want_s.append(REUSED)
+            want_c.append(run.cycles)
+        else:
+            want_s.append(CYCLE if ref.retime(rb, row) is None
+                          else VIOLATED)
+            want_c.append(-1)
+    want_s, want_c = np.array(want_s), np.array(want_c)
+    assert 0 < (want_s == CYCLE).sum() < len(D)
+    for backend in ("jax", "numpy", "reference"):
+        st, cyc, _, _ = solve_block_status(g, D, backend=backend, block=16)
+        np.testing.assert_array_equal(st, want_s, err_msg=backend)
+        np.testing.assert_array_equal(cyc, want_c, err_msg=backend)
+
+
+def test_a_block_below_the_full_height_leaves_cycles_to_the_host():
+    """A block that pads to fewer rows than the caller's block runs the
+    fixpoint without the cycle check: it stops where the check would
+    first run and leaves the rows still pending to the host's exact
+    pass, which gives the numpy lane's statuses and cycles."""
+    import functools
+
+    import jax
+
+    from repro.core.dse import (CYCLE, _batch_arrays, _sparse_arrays,
+                                solve_block_status)
+    from repro.kernels.maxplus import sparse as sp
+    _, g, D = _optical_flow_rows(2, 64, 3, k=8)
+    want = solve_block_status(g, D, backend="numpy", block=64)
+    got = solve_block_status(g, D, backend="jax", block=64)
+    for i in range(3):
+        np.testing.assert_array_equal(got[i], want[i])
+    cyclic = want[0] == CYCLE
+    assert cyclic.any()
+    arr = _sparse_arrays(g, _batch_arrays(g))
+    assert sp.solve_chains(arr, D, block=64)[3][cyclic].all()
+    args, static = sp._fixpoint_args(arr, D)
+    text = {check: str(jax.make_jaxpr(functools.partial(
+        sp._fixpoint, **static, interpret=True, check=check))(*args))
+        for check in (True, False)}
+    assert "cycle_fill" in text[True] and "cycle_fill" not in text[False]
+
+
+@pytest.mark.parametrize("backend", ["jax", "numpy", "reference"])
+def test_cycle_exit_rounds_do_not_grow_with_the_frame(backend):
+    """A block holding deadlocking rows finishes in rounds set by the
+    deadlock's cycle, not by the design's length: the old exit (times past
+    the acyclic bound) took about 43 rounds a frame row of width 1,024."""
+    from repro.core.dse import CYCLE, solve_block_status
+    rounds = []
+    for height in (2, 4, 8):
+        _, g, D = _optical_flow_rows(height, 256, 0, k=16)
+        st, _, _, r = solve_block_status(g, D, backend=backend, block=16)
+        assert (st == CYCLE).sum() >= 3
+        rounds.append(r)
+    assert max(rounds) <= 1.25 * min(rounds) + 8, rounds
+
+
+def test_pointer_cycle_rows_flags_only_cyclic_rows():
+    """Forced at every round of a Jacobi solve, the pointer check flags
+    exactly the rows whose from-scratch run deadlocks, and no row of a
+    design whose depth rows never deadlock."""
+    from repro.core.dse import _batch_arrays, _regen_war_stacked
+    from repro.core.graph import pointer_cycle_rows
+    from repro.core.incremental import NEGI, compile_graph
+    from repro.designs.paper import multicore
+    from repro.designs.rosetta import optical_flow
+    ref = _bench_reference()
+    base, g, D = _optical_flow_rows(2, 64, 3, k=16)
+    bodies = [m.fn for m in optical_flow(2, 64).modules]
+    cases = [(g, D, [ref.simulate([int(d) for d in row], bodies).deadlock
+                     for row in D])]
+    mc = simulate(multicore(cores=4, prog_len=32, stride=4), trace="auto")
+    gm = compile_graph(mc.graph)
+    Dm = np.random.default_rng(4).integers(1, 17, (16, len(mc.depths)))
+    cases.append((gm, Dm, [False] * len(Dm)))
+    for g, D, want in cases:
+        ba = _batch_arrays(g)
+        K, n = len(D), len(ba.perm)
+        c = np.broadcast_to(np.where(ba.c_inf == NEGI, -(1 << 40),
+                                     ba.c_inf), (K, n)).copy()
+        dd, ds, dv = _regen_war_stacked(ba, D)
+        flagged = np.zeros(K, bool)
+        for _ in range(40):
+            t = np.empty_like(c)
+            for (lo, hi) in ba.slices:
+                t[:, lo:hi] = ba.cw[lo:hi] + np.maximum.accumulate(
+                    c[:, lo:hi] - ba.cw[lo:hi], axis=1)
+            flagged |= pointer_cycle_rows(c, ba.cw, ba.slices, ba.raw_dst,
+                                          ba.raw_src, dd, ds, dv, steps=64)
+            c[:, ba.raw_dst] = np.maximum(c[:, ba.raw_dst],
+                                          t[:, ba.raw_src] + ba.raw_w)
+            cand = np.where(dv, np.take_along_axis(t, ds, axis=1) + 1,
+                            c[:, dd])
+            c[:, dd] = np.maximum(c[:, dd], cand)
+        np.testing.assert_array_equal(flagged, want)
+    assert 0 < sum(cases[0][2]) < len(cases[0][2])
+
+
+def _lockstep_block():
+    """optical_flow at 4 x 64 and 16 depth rows, the last a lockstep row:
+    the z path (``frame4_a`` plus ``gradient_z``) holds barely the x/y
+    path's lag, so the two paths advance one pixel a round for the rest
+    of the frame and the Jacobi lane needs about 500 rounds."""
+    from repro.core.incremental import compile_graph
+    from repro.designs.rosetta import optical_flow
+    base = simulate(optical_flow(4, 64), trace="auto")
+    names = [f.name for f in optical_flow(4, 64).fifos]
+    lock = np.full(len(names), 256)
+    lock[names.index("frame4_a")] = 32
+    lock[names.index("gradient_z")] = 97
+    D = np.vstack([np.random.default_rng(1).integers(1, 257,
+                                                     (15, len(names))),
+                   lock])
+    return compile_graph(base.graph), D
+
+
+def test_straggler_rows_leave_the_device_exactly():
+    """The jax lane stops at its straggler cap and solves the lockstep
+    row on the host: the same statuses, cycles and violations as the
+    numpy and Jacobi lanes, in a fraction of their rounds."""
+    from repro.core.dse import REUSED, solve_block_status
+    g, D = _lockstep_block()
+    out = {be: solve_block_status(g, D, backend=be, block=16)
+           for be in ("numpy", "reference", "jax")}
+    for be in ("reference", "jax"):
+        for i in range(3):
+            np.testing.assert_array_equal(out[be][i], out["numpy"][i],
+                                          err_msg=be)
+    assert out["numpy"][0][-1] == REUSED
+    assert out["reference"][3] > 400
+    assert out["jax"][3] < 64
+
+
+@pytest.mark.parametrize("height,width,seed", [(2, 64, 5), (4, 64, 6)])
+def test_exact_row_solve_matches_the_numpy_lane(height, width, seed):
+    """One topological pass a row gives the numpy lane's converged flags
+    and, on converged rows, its times bit for bit."""
+    from repro.core.dse import (_batch_arrays, _solve_block_numpy,
+                                _solve_rows_exact)
+    from repro.kernels.maxplus.sparse import NEG
+    _, g, D = _optical_flow_rows(height, width, seed, k=16)
+    ba = _batch_arrays(g)
+    D = D[~(D < ba.fifo_need[None, :]).any(axis=1)]
+    t_np, conv_np, _ = _solve_block_numpy(ba, D)
+    t_ex, conv_ex = _solve_rows_exact(ba, D, int(NEG))
+    np.testing.assert_array_equal(conv_ex, conv_np)
+    assert 0 < conv_np.sum() < len(D)
+    np.testing.assert_array_equal(t_ex[:, conv_np], t_np[:, conv_np])

@@ -307,6 +307,28 @@ def test_segmented_cummax_node_tiles(npad, width, max_len):
     assert (got == want).all()
 
 
+@pytest.mark.parametrize("npad,width,max_len", [(256, 128, 16),
+                                                (512, 128, 300)])
+def test_segmented_cummax_per_row_segments(npad, width, max_len):
+    """A (K, npad) ``seg_start`` gives each row segments of its own (the
+    cycle exit's label fill), across node tiles."""
+    rng = np.random.default_rng(npad + max_len)
+    K = 16
+    seg = np.zeros((K, npad), np.int32)
+    for k in range(K):
+        lo = 0
+        while lo < npad:
+            ln = int(rng.integers(1, max_len + 1))
+            seg[k, lo:min(lo + ln, npad)] = lo
+            lo += ln
+    x = rng.integers(-50, 50, size=(K, npad)).astype(np.int32)
+    want = np.stack([_segcummax_oracle(x[k:k + 1], seg[k])[0]
+                     for k in range(K)])
+    got = np.asarray(segmented_cummax(jnp.asarray(x), jnp.asarray(seg),
+                                      max_seg=max_len, width=width))
+    assert (got == want).all()
+
+
 def test_solve_chains_node_tiles_match_numpy(monkeypatch):
     """The whole fixpoint with the node axis split into many tiles (a
     narrow LANE_TILE stands in for a design wider than one tile)."""
@@ -327,7 +349,7 @@ def test_solve_chains_node_tiles_match_numpy(monkeypatch):
     rng = np.random.default_rng(3)
     Db = rng.integers(1, 9, size=(8, len(base.depths))).astype(np.int64)
     t_np, conv_np, _ = _solve_block_numpy(ba, Db)
-    t_jx, conv_jx, _ = sp.solve_chains(arr, Db)
+    t_jx, conv_jx, _, _ = sp.solve_chains(arr, Db)
     assert (conv_np == conv_jx).all()
     cols = np.flatnonzero(conv_np)
     assert len(cols)
@@ -432,7 +454,7 @@ def test_solve_chains_multicore_matches_numpy():
     rng = np.random.default_rng(4)
     Db = rng.integers(0, 12, size=(16, len(base.depths))).astype(np.int64)
     t_np, conv_np, _ = _solve_block_numpy(ba, Db)
-    t_jx, conv_jx, _ = sp.solve_chains(arr, Db, interpret=True)
+    t_jx, conv_jx, _, _ = sp.solve_chains(arr, Db, interpret=True)
     assert (conv_np == conv_jx).all()
     cols = np.flatnonzero(conv_np)
     assert len(cols)
@@ -471,6 +493,8 @@ def test_fixpoint_loop_gathers_share_their_indices():
     jaxpr = jax.make_jaxpr(functools.partial(
         sp._fixpoint, **static, interpret=True))(*args)
     loop = [e for in_loop, e in _gathers(jaxpr.jaxpr) if in_loop]
-    assert len(loop) == 3                   # RAW source, WAR lane, WAR slot
+    # the cross pass's RAW source, WAR lane and WAR slot; the cycle check
+    # pulls its labels through the same three inside its own loop
+    assert len(loop) == 6
     for e in loop:
         assert e.params["slice_sizes"][0] == K, e

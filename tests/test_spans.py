@@ -27,7 +27,8 @@ SPANS = {
     "sweep.dequeue": {"rid", "lane", "rows", "block", "wait_us"},
     "sweep.dedup": {"block", "rows_unique", "memo_hits"},
     "solve.upload": {"K", "war_lane", "war_steps"},
-    "solve.fixpoint": set(),
+    "solve.fixpoint": {"rounds", "cyclic_rows", "freeze_round",
+                       "unsettled_rows"},
     "solve.copy_back": {"bytes"},
     "solve.transpose": set(),
     "solve.recheck": {"rounds", "violated_rows"},
@@ -189,3 +190,37 @@ def test_cache_resolve_names_how_the_entry_was_found():
     fps = fingerprint_design(p.edited())
     assert cache.get_or_patch(p.edited(), fps, look.state).mode == "patched"
     assert cache.resolve(p.edited())[1] == "patched"
+
+
+def test_cycle_exit_counts_in_spans_and_stats(tmp_path):
+    """A jax-lane sweep of Rosetta's optical flow, a third of whose depth
+    rows deadlock: each ``solve.fixpoint`` span says how many rows the
+    cycle exit froze and in which round the last one froze, and the
+    scheduler counts the CYCLE verdicts."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.dse import CYCLE
+    from repro.designs.rosetta import optical_flow
+
+    def build():
+        return optical_flow(height=2, width=64)
+    D = np.random.default_rng(0).integers(1, 257, (32, 14))
+    svc = SweepService(block=16, backend="jax")
+    with jax.profiler.trace(str(tmp_path)):
+        out = svc.submit(build(), D, priority=BULK).result(timeout=300)
+    stats = svc.stats()["scheduler"]
+    svc.close()
+    n_cycle = int((out.status == CYCLE).sum())
+    assert n_cycle > 0
+    assert stats["cyclic_rows"] == n_cycle
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    fx = [dict(e.stats) for p in ProfileData.from_file(path).planes
+          if p.name.startswith("/host:") for ln in p.lines
+          for e in ln.events if e.name == "solve.fixpoint"]
+    assert len(fx) == stats["solve_calls"]
+    frozen = [st for st in fx if st["cyclic_rows"] > 0]
+    assert frozen and sum(st["cyclic_rows"] for st in fx) <= n_cycle
+    for st in frozen:
+        assert 0 < st["freeze_round"] <= st["rounds"]

@@ -59,6 +59,16 @@ def corpus1000():
     raise AssertionError("no live 1000-module corpus seed")
 
 
+@pytest.fixture(scope="module")
+def optical_flow():
+    """Rosetta's optical flow at 32 x 1,024 frames, four times the
+    benchmark cell's height: the largest graph the device lane is given."""
+    from repro.core import simulate
+    from repro.designs.rosetta import optical_flow
+    return _chain_flat(simulate(optical_flow(height=32, width=1024),
+                                trace="auto"))
+
+
 def _doubled(arr):
     """skynet's graph twice side by side: twice its padded node axis."""
     return arr._replace(
@@ -81,13 +91,15 @@ def _compile(arr, n_fifos, K, sharding):
 
 @pytest.mark.parametrize("design,K", [("skynet", 128), ("skynet", 1024),
                                       ("corpus1000", 1024),
-                                      ("skynet_x2", 1024)])
+                                      ("skynet_x2", 1024),
+                                      ("optical_flow", 128)])
 def test_fixpoint_compiles_for_v5e(design, K, one_chip, request):
     """skynet_like (n=102,452, chains of 4,098 nodes) at the service's
-    default block and at K=1024, the 1000-module corpus design, and twice
-    skynet's width for headroom: the kernel compiles within the chip's
-    VMEM limit, stays a Mosaic kernel, and the whole program fits the
-    chip's 16 GB of HBM."""
+    default block and at K=1024, the 1000-module corpus design, twice
+    skynet's width for headroom, and optical flow at 32 frame rows
+    (n=917,522, 449 node tiles, a 15-step WAR lane) at the cell's block: the
+    kernel compiles within the chip's VMEM limit, stays a Mosaic kernel,
+    and the whole program fits the chip's 16 GB of HBM."""
     arr, n_fifos = request.getfixturevalue(
         "skynet" if design == "skynet_x2" else design)
     if design == "skynet_x2":

@@ -543,7 +543,8 @@ def _pointer_cycles(ba: _BatchArrays, c: np.ndarray, cw: np.ndarray,
 
 def solve_block_status(cache: CompiledGraph, depth_block,
                        backend: str = "numpy", block: int = 128,
-                       jax_interpret: Optional[bool] = None):
+                       jax_interpret: Optional[bool] = None,
+                       counts: Optional[dict] = None):
     """Engine-free solve phase of :func:`resimulate_batch`.
 
     Classifies a block of depth vectors against ``cache`` alone — no
@@ -558,6 +559,13 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     REUSED with its exact cycle count, or DEADLOCK / CYCLE / VIOLATED with
     ``cycles = -1`` (the caller decides whether to pay for the exact
     fallback re-simulation, which *does* need the engine).
+
+    The ``"jax"`` lane decides each row's constraint re-check and cycle
+    count on the device and copies back only those; the host re-checks
+    the rows it solved exactly itself (the device left them undecided).
+    The other lanes re-check every row on the host.  ``counts``, where
+    given, receives ``device_verdict_rows`` and ``host_recheck_rows``,
+    the rows each side decided.
 
     ``jax_interpret`` (jax backends only) defaults to the platform's
     Pallas mode (:func:`repro.device.pallas_interpret`): compiled on a
@@ -577,48 +585,66 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     status[dead] = DEADLOCK
     alive = np.flatnonzero(~dead)
     total_rounds = 0
+    # per solved block: (rows of ``alive``, converged, violated, cycles,
+    # positions the host re-checks, their node times (n, len(positions)))
+    blocks = []
 
     if len(alive):
         if backend in ("jax", "jax_dense"):
             jax_interpret = pallas_interpret(jax_interpret)
         if backend == "jax_dense":
-            blocks = [(np.arange(len(alive)),
-                       *_solve_dense_jax(cache, ba, D[alive],
-                                         interpret=jax_interpret,
-                                         block=block))]
+            sl = np.arange(len(alive))
+            t_nm, conv = _solve_dense_jax(cache, ba, D[alive],
+                                          interpret=jax_interpret,
+                                          block=block)
+            blocks.append(_host_verdicts(sl, t_nm, conv))
         elif backend in ("numpy", "reference", "jax"):
-            if backend == "numpy":
-                solve = _solve_block_numpy
-            elif backend == "reference":
-                solve = _solve_block_reference
-            else:           # sparse chain-structured Pallas max-plus lane
-                solve = (lambda ba_, Db_: _solve_sparse_jax(
-                    cache, ba_, Db_, interpret=jax_interpret, block=block))
-            blocks = []
             for lo in range(0, len(alive), max(block, 1)):
                 sl = np.arange(lo, min(lo + max(block, 1), len(alive)))
-                t_nm, conv, rounds = solve(ba, D[alive[sl]])
+                if backend == "jax":    # sparse chain-structured Pallas lane
+                    *verdicts, rounds = _solve_sparse_jax(
+                        cache, ba, D[alive[sl]], interpret=jax_interpret,
+                        block=block)
+                    blocks.append((sl, *verdicts))
+                else:
+                    solve = (_solve_block_numpy if backend == "numpy"
+                             else _solve_block_reference)
+                    t_nm, conv, rounds = solve(ba, D[alive[sl]])
+                    blocks.append(_host_verdicts(sl, t_nm, conv))
                 total_rounds = max(total_rounds, rounds)
-                blocks.append((sl, t_nm, conv))
         else:
             raise ValueError(f"unknown backend {backend!r}")
+    host_rows = 0
+    if blocks:
         with span("solve.recheck", rounds=total_rounds) as sp:
-            for sl, t_nm, conv in blocks:
+            for sl, conv, viol, cyc, host, t_nm in blocks:
                 rows = alive[sl]
-                status[rows[~conv]] = CYCLE                   # ② event order
-                if conv.any():
+                if len(host):
                     # ③ constraint re-check, all configs at once
-                    viol = _check_constraints_stacked(cache, ba, t_nm,
-                                                      D[rows])
-                    violated[rows[conv]] = viol[conv]
-                    status[rows[conv & (viol > 0)]] = VIOLATED
-                    good = conv & (viol == 0)
-                    if good.any():
-                        cyc = (t_nm.max(axis=0) if t_nm.shape[0]
-                               else np.zeros(len(rows), np.int64))
-                        cycles[rows[good]] = cyc[good]
-            sp.set_metadata(violated_rows=int((violated > 0).sum()))
+                    viol[host] = _check_constraints_stacked(
+                        cache, ba, t_nm, D[rows[host]])
+                    cyc[host] = (t_nm.max(axis=0) if t_nm.shape[0]
+                                 else 0)
+                    host_rows += len(host)
+                status[rows[~conv]] = CYCLE                   # ② event order
+                violated[rows[conv]] = viol[conv]
+                status[rows[conv & (viol > 0)]] = VIOLATED
+                good = conv & (viol == 0)
+                cycles[rows[good]] = cyc[good]
+            sp.set_metadata(rows=host_rows,
+                            violated_rows=int((violated > 0).sum()))
+    if counts is not None:
+        counts["host_recheck_rows"] = host_rows
+        counts["device_verdict_rows"] = len(alive) - host_rows
     return status, cycles, violated, total_rounds
+
+
+def _host_verdicts(sl: np.ndarray, t_nm: np.ndarray, conv: np.ndarray):
+    """A host lane's block in :func:`solve_block_status`'s form: every
+    row's verdict is the host re-check's."""
+    k = len(sl)
+    return (sl, conv, np.zeros(k, np.int64), np.zeros(k, np.int64),
+            np.arange(k), t_nm)
 
 
 def status_reason(cache: CompiledGraph, status_k: int, violated_k: int,
@@ -636,6 +662,25 @@ def status_reason(cache: CompiledGraph, status_k: int, violated_k: int,
                 f"with depth {int(depths_row[fid])} (would deadlock)")
     return (f"{int(violated_k)} constraint(s) violated — "
             f"control/data flow diverges")
+
+
+class _ReusedShell(SimResult):
+    """A REUSED row's result.  Its ``constraints`` are a copy of the base
+    run's (``base_constraints``), made on first access: a shell of its
+    own to mutate, as any result is, without a copy a row for every
+    shell that is never read (2,632 constraints, 21 kB, a row of
+    ``multicore``, held for as long as the caller holds the result)."""
+
+    @property
+    def constraints(self) -> list:
+        own = self.__dict__.get("_constraints")
+        if own is None:
+            own = self.__dict__["_constraints"] = list(self.base_constraints)
+        return own
+
+    @constraints.setter
+    def constraints(self, value) -> None:
+        self.__dict__["_constraints"] = value
 
 
 def materialize_block(result: SimResult, Du: np.ndarray,
@@ -672,12 +717,13 @@ def materialize_block(result: SimResult, Du: np.ndarray,
             # constraints a mutable list — sharing them would let a caller
             # mutating one sweep result corrupt its siblings AND the cached
             # base run (the graph stays shared by design: it IS the cache)
-            results_u[u] = SimResult(
+            shell = _ReusedShell(
                 program=result.program, outputs=dict(result.outputs),
                 cycles=int(cycles_u[u]), engine=engine_label,
                 stats=copy.copy(result.stats), graph=engine,
-                constraints=list(result.constraints),
-                depths=tuple(int(d) for d in Du[u]))
+                constraints=None, depths=tuple(int(d) for d in Du[u]))
+            shell.base_constraints = result.constraints
+            results_u[u] = shell
         elif fallback_mask[u] and status_u[u] in FALLBACK_STATUSES:
             with (lock if lock is not None else nullcontext()), \
                     program_mutation_lock(engine.program):
@@ -784,13 +830,16 @@ def _int32_saturation_guard(ba: _BatchArrays, backend: str) -> None:
 
 def _sparse_arrays(cache: CompiledGraph, ba: _BatchArrays):
     """Lazily built (and cached on ``ba``) chain-flat device transfer
-    arrays for the sparse jax lane."""
+    arrays for the sparse jax lane, the constraint re-check's operands
+    included."""
     if ba.sparse is None:
         from ..kernels.maxplus.sparse import NEG
         ba.sparse = export_chain_flat(
             ba.slices, ba.cw, ba.c_inf, ba.raw_dst, ba.raw_src, ba.raw_w,
             ba.fifo_w_cols, ba.fifo_r_cols, ba.fifo_blocking,
-            bound=ba.bound, neg=int(NEG))
+            bound=ba.bound, neg=int(NEG),
+            checks=(cache.c_kind, cache.c_fifo, cache.c_seq, ba.c_src_p,
+                    cache.c_out))
     return ba.sparse
 
 
@@ -804,23 +853,29 @@ def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
     chain-pass/cross-pass to the same unique least fixpoint the numpy
     Gauss-Seidel reaches — times, and hence statuses/cycles/violations,
     are bit-identical for converged rows.  O(K·n + K·edges) memory.
-    Rows the device leaves undecided (at its straggler cap, or where a
-    block shorter than ``block`` stops in place of a cycle check) are
-    solved exactly on the host by :func:`_solve_rows_exact`.
+    The device decides each converged row's violations and cycle count.
+    Rows it leaves undecided (at its straggler cap, or where a block
+    shorter than ``block`` stops in place of a cycle check) are solved
+    exactly on the host by :func:`_solve_rows_exact`.
+
+    Returns ``(converged, violated, cycles, host, times, rounds)``:
+    ``host`` the positions of the rows solved on the host, whose
+    ``violated`` and ``cycles`` the caller re-checks from ``times`` (n,
+    len(host)).
     """
     from ..kernels.maxplus import sparse as sp
 
     _int32_saturation_guard(ba, "jax")
     arr = _sparse_arrays(cache, ba)
-    times, conv, rounds, unsettled = sp.solve_chains(
-        arr, Db, interpret=interpret, block=block)
-    if unsettled.any():
-        with span("solve.exact", rows=int(unsettled.sum())):
-            times, conv = np.array(times), np.array(conv)
-            t_ex, conv[unsettled] = _solve_rows_exact(ba, Db[unsettled],
-                                                      int(sp.NEG))
-            times[:, unsettled] = t_ex
-    return times, conv, rounds
+    v = sp.solve_chains(arr, Db, interpret=interpret, block=block)
+    conv = np.array(v.converged)
+    host = np.flatnonzero(v.unsettled)
+    times = np.zeros((len(ba.perm), 0), np.int64)
+    if len(host):
+        with span("solve.exact", rows=len(host)):
+            times, conv[host] = _solve_rows_exact(ba, Db[host], int(sp.NEG))
+    return (conv, v.violated.astype(np.int64), v.cycles.astype(np.int64),
+            host, times, v.rounds)
 
 
 def _solve_rows_exact(ba: _BatchArrays, Db: np.ndarray, neg: int):

@@ -409,6 +409,9 @@ def longest_path_chains_batched(chain_slices, cw, base, cross_dst, cross_src,
     return times, converged, rounds
 
 
+_EMPTY = np.zeros(0, np.int32)
+
+
 class ChainFlatArrays(NamedTuple):
     """Flat chain-major export of the batched solver's graph view.
 
@@ -445,6 +448,18 @@ class ChainFlatArrays(NamedTuple):
 
     RAW edges and WAR rows are sorted by destination column, padding
     included, so the device scatters may declare sorted indices.
+
+    The ``chk_`` fields carry the Table-2 constraint re-check
+    (``core.dse._check_constraints_stacked``) for the device, which
+    decides each row's verdict from its final times.  A can-read
+    constraint compares its source with a static write column.  A
+    can-write constraint ``seq`` of FIFO ``f`` holds outright where
+    ``seq <= S`` and otherwise compares with read ``seq - S - 1`` of the
+    FIFO: that read comes through a lane of the constrained FIFOs' reads
+    built as the WAR lane is (one segment of ``max(nr, max seq)`` slots a
+    FIFO, slot ``chk_wr_pos`` read after the row's shift ``S``), so it
+    needs no gather whose indices differ by row either.  A design without
+    constraints has empty ``chk_`` arrays.
     """
 
     n: int                    # real node count (columns 0..n are live)
@@ -465,13 +480,91 @@ class ChainFlatArrays(NamedTuple):
     bound: int                # upper bound on any acyclic path length
     max_seg: int = 1          # longest chain (caps the scan's doubling steps)
     war_seg: int = 0          # longest WAR lane segment (caps the shifts)
+    chk_rd_src: np.ndarray = _EMPTY   # (R,) can-read: source column
+    chk_rd_tgt: np.ndarray = _EMPTY   # (R,) its seq-th write's column
+    chk_rd_live: np.ndarray = _EMPTY  # (R,) 1 where that write exists
+    chk_rd_out: np.ndarray = _EMPTY   # (R,) base outcome (0/1)
+    chk_wr_src: np.ndarray = _EMPTY   # (W,) can-write: source column
+    chk_wr_seq: np.ndarray = _EMPTY   # (W,) 1-based write sequence
+    chk_wr_fid: np.ndarray = _EMPTY   # (W,) its FIFO
+    chk_wr_nr: np.ndarray = _EMPTY    # (W,) reads of that FIFO
+    chk_wr_pos: np.ndarray = _EMPTY   # (W,) check lane slot it reads
+    chk_wr_out: np.ndarray = _EMPTY   # (W,) base outcome (0/1)
+    chk_lane_src: np.ndarray = _EMPTY  # (L,) read column, -1 where empty
+    chk_lane_fid: np.ndarray = _EMPTY  # (L,) FIFO of the slot's segment
+    chk_seg: int = 0          # longest check lane segment (caps the shifts)
+
+
+def _bucket(k: int) -> int:
+    """A power of two >= ``k`` (floor 16): table lengths bucketed so
+    solves of different designs reuse the device solver's jit cache."""
+    m = 16
+    while m < k:
+        m *= 2
+    return m
+
+
+def _pad(a, fill):
+    """``a`` as int32, padded with ``fill`` to its bucket length."""
+    a = np.asarray(a)
+    if len(a) == 0:
+        return a.astype(np.int32)
+    out = np.full(_bucket(len(a)), fill, np.int32)
+    out[:len(a)] = a
+    return out
+
+
+def _export_checks(c_kind, c_fifo, c_seq, c_src, c_out, fifo_w_cols,
+                   fifo_r_cols) -> dict:
+    """The ``chk_`` fields of :class:`ChainFlatArrays` (``c_src`` in
+    chain-major columns, ``c_seq`` 1-based as the engine records it)."""
+    c_kind, c_fifo, c_seq, c_src, c_out = (
+        np.asarray(a, np.int64) for a in (c_kind, c_fifo, c_seq, c_src,
+                                          c_out))
+    rd = np.flatnonzero(c_kind == 0)
+    nw = np.asarray([len(w) for w in fifo_w_cols], np.int64)
+    live = c_seq[rd] <= nw[c_fifo[rd]]
+    tgt = np.zeros(len(rd), np.int64)
+    tgt[live] = [fifo_w_cols[f][s - 1]
+                 for f, s in zip(c_fifo[rd][live], c_seq[rd][live])]
+    wr = np.flatnonzero(c_kind == 1)
+    start, lsrc, lfid, lane, seg = {}, [], [], 0, 0
+    for f in np.unique(c_fifo[wr]):
+        rcols = fifo_r_cols[f]
+        seg_len = max(len(rcols), int(c_seq[wr][c_fifo[wr] == f].max()), 1)
+        src = np.full(seg_len, -1, np.int64)
+        src[:len(rcols)] = rcols
+        lsrc.append(src)
+        lfid.append(np.full(seg_len, f, np.int64))
+        start[int(f)] = lane
+        lane += seg_len
+        seg = max(seg, seg_len)
+    pos = [start[int(f)] + max(int(s) - 1, 0)
+           for f, s in zip(c_fifo[wr], c_seq[wr])]
+    nr = np.asarray([len(r) for r in fifo_r_cols], np.int64)
+    cat = (lambda parts: np.concatenate(parts) if parts
+           else np.zeros(0, np.int64))
+    # padding reads never hold and expect not to; padding writes
+    # (seq = 0) always hold and expect to: neither counts as a flip
+    return dict(
+        chk_rd_src=_pad(c_src[rd], 0), chk_rd_tgt=_pad(tgt, 0),
+        chk_rd_live=_pad(live, 0), chk_rd_out=_pad(c_out[rd], 0),
+        chk_wr_src=_pad(c_src[wr], 0), chk_wr_seq=_pad(c_seq[wr], 0),
+        chk_wr_fid=_pad(c_fifo[wr], 0), chk_wr_nr=_pad(nr[c_fifo[wr]], 1),
+        chk_wr_pos=_pad(pos, 0), chk_wr_out=_pad(c_out[wr], 1),
+        chk_lane_src=_pad(cat(lsrc), -1), chk_lane_fid=_pad(cat(lfid), 0),
+        chk_seg=seg)
 
 
 def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
                       fifo_w_cols, fifo_r_cols, fifo_blocking, bound: int,
-                      neg: int, lanes: int = 128) -> ChainFlatArrays:
+                      neg: int, lanes: int = 128,
+                      checks=None) -> ChainFlatArrays:
     """Build the :class:`ChainFlatArrays` transfer view of a chain-major
-    graph (``neg`` is the int32 -INF sentinel everything is clipped to)."""
+    graph (``neg`` is the int32 -INF sentinel everything is clipped to).
+    ``checks``, where given, is the graph's constraints as ``(c_kind,
+    c_fifo, c_seq, c_src, c_out)`` with ``c_src`` in chain-major
+    columns; without it the ``chk_`` arrays are empty."""
     n = len(cw)
     npad = max(((n + lanes - 1) // lanes) * lanes, lanes)
     seg = np.arange(npad, dtype=np.int32)      # padding: isolated segments
@@ -514,25 +607,6 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
     worder = np.argsort(war_dst_c, kind="stable")
     war = [cat(p)[worder] for p in (ws, wf, wnr, wpos)]
 
-    def pad(a, m, fill):
-        """Bucket array lengths to powers of two (floor 16) so solves of
-        different designs reuse the device solver's jit cache; padding
-        entries are inert (see the per-array fill values below)."""
-        if len(a) == 0 or len(a) == m:
-            return a.astype(np.int32)
-        out = np.full(m, fill, np.int32)
-        out[:len(a)] = a
-        return out
-
-    def bucket(k):
-        m = 16
-        while m < k:
-            m *= 2
-        return m
-
-    E = bucket(len(raw_dst)) if len(raw_dst) else 0
-    m = bucket(len(war_dst_c)) if len(war_dst_c) else 0
-    L = bucket(lane) if lane else 0
     # padding edges and WAR rows repeat the last destination, keeping the
     # destinations sorted; what they scatter is masked to -INF
     raw_last = int(raw_dst[-1]) if len(raw_dst) else 0
@@ -540,20 +614,22 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
     return ChainFlatArrays(
         n=n, npad=npad, cw=cwp, seg_start=seg, c_seed=cs,
         # padding edges: weight = -INF (a max-identity), src = 0
-        raw_dst=pad(raw_dst, E, raw_last),
-        raw_src=pad(raw_src, E, 0),
-        raw_w=pad(np.maximum(raw_w, neg), E, neg),
+        raw_dst=_pad(raw_dst, raw_last),
+        raw_src=_pad(raw_src, 0),
+        raw_w=_pad(np.maximum(raw_w, neg), neg),
         # padding WAR rows: wseq = 0 makes every target negative (masked);
         # pos = 0 keeps their lane read in bounds.  Padding lane slots are
         # empty, and no real write's shifted source reaches them
-        war_dst=pad(war_dst_c[worder], m, war_last),
-        war_wseq=pad(war[0], m, 0), war_fid=pad(war[1], m, 0),
-        war_nr=pad(war[2], m, 1), war_pos=pad(war[3], m, 0),
-        war_lane_src=pad(cat(lsrc), L, -1),
-        war_lane_fid=pad(cat(lfid), L, 0),
+        war_dst=_pad(war_dst_c[worder], war_last),
+        war_wseq=_pad(war[0], 0), war_fid=_pad(war[1], 0),
+        war_nr=_pad(war[2], 1), war_pos=_pad(war[3], 0),
+        war_lane_src=_pad(cat(lsrc), -1),
+        war_lane_fid=_pad(cat(lfid), 0),
         bound=int(bound),
         max_seg=max([hi - lo for (lo, hi) in chain_slices] or [1]),
-        war_seg=war_seg)
+        war_seg=war_seg,
+        **(_export_checks(*checks, fifo_w_cols, fifo_r_cols)
+           if checks is not None else {}))
 
 
 def to_dense_blocks(indptr: np.ndarray, src: np.ndarray, wgt: np.ndarray,

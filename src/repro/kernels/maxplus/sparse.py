@@ -95,6 +95,15 @@ block stops where the check would first run, while fewer than half its
 rows have settled, and leaves the rows still pending to the same exact
 pass.
 
+The program ends with each row's verdict, in the ``recheck`` scope
+(:func:`_recheck`): the number of Table-2 constraint outcomes its final
+times flip and its cycle count, the latest of its real node times.  The
+host copies back these (K,) vectors, never the (K, npad) time matrix.
+The constraints' operands are static columns as the RAW half's are, and
+a lane of the constrained FIFOs' reads moved by the WAR half's barrel
+shift (see :class:`~repro.core.graph.ChainFlatArrays`); a design without
+constraints compiles only the row max.
+
 Everything is int32 on device — callers must clip against :data:`NEG`
 and refuse graphs whose path-length bound nears int32 range (see
 ``core.dse``'s saturation guard); this mirrors the wrap-around hazard
@@ -108,7 +117,7 @@ tails hit the jit cache instead of recompiling per shape.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -266,19 +275,26 @@ def war_steps(war_seg: int) -> int:
     return max(int(war_seg) - 1, 0).bit_length()
 
 
+def _lane_read(t, lane_src, pos, shift, steps: int):
+    """(K, m) times at lane slots ``pos`` once each row's segments moved
+    right by their ``shift``: a static column gather into the lane (NEG in
+    empty slots); a barrel shift, where at step ``b`` a slot takes its
+    partner ``2**b`` to the left iff bit ``b`` of its ``shift`` is set
+    (exact, as the module docstring says); a static read of the slots."""
+    x = jnp.where(lane_src[None, :] >= 0, t[:, lane_src], jnp.int32(NEG))
+    for b in range(steps):
+        x = jnp.where(((shift >> b) & 1) == 1, _shift_right(x, 1 << b), x)
+    return x[:, pos]
+
+
 def _war_candidates(t, war_lane_src, war_pos, war_shift, war_valid,
                     steps: int):
     """(K, m) WAR candidates ``t[read wseq - S - 1] + 1``, NEG where that
-    read does not exist, through the WAR lane: a static column gather;
-    a barrel shift, where at step ``b`` a slot takes its partner ``2**b``
-    to the left iff bit ``b`` of its ``war_shift`` is set (exact, as the
-    module docstring says); a static read of each write's slot."""
-    x = jnp.where(war_lane_src[None, :] >= 0, t[:, war_lane_src],
-                  jnp.int32(NEG))
-    for b in range(steps):
-        x = jnp.where(((war_shift >> b) & 1) == 1, _shift_right(x, 1 << b),
-                      x)
-    return jnp.where(war_valid, x[:, war_pos] + 1, jnp.int32(NEG))
+    read does not exist, through the WAR lane (:func:`_lane_read`)."""
+    return jnp.where(war_valid,
+                     _lane_read(t, war_lane_src, war_pos, war_shift,
+                                steps) + 1,
+                     jnp.int32(NEG))
 
 
 def _war_operands(Db, war_wseq, war_fid, war_nr, war_lane_fid, war_seg):
@@ -290,6 +306,35 @@ def _war_operands(Db, war_wseq, war_fid, war_nr, war_lane_fid, war_seg):
     war_valid = (tgt >= 0) & (tgt < war_nr[None, :])
     war_shift = jnp.minimum(Db[:, war_lane_fid], war_seg - 1)
     return war_shift, war_valid
+
+
+def _recheck(t, Db, n, rd_src, rd_tgt, rd_live, rd_out, wr_src, wr_seq,
+             wr_fid, wr_nr, wr_pos, wr_out, lane_src, lane_fid, seg: int):
+    """Each row's verdict from its final times ``t`` (K, npad): the count
+    of Table-2 constraint outcomes that flip, as
+    ``core.dse._check_constraints_stacked`` counts them, and the cycle
+    count, the latest time over the graph's ``n`` real columns.  Every
+    gather reads whole columns, one index for all rows: a can-write
+    constraint ``seq`` that does not hold outright (``seq <= S``) reads
+    read ``seq - S - 1`` of its FIFO through the check lane, whose shifts
+    and mask are the WAR half's (:func:`_war_operands`)."""
+    K, npad = t.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, npad), 1)
+    cycles = jnp.where(col < n, t, jnp.int32(NEG)).max(axis=1)
+    violated = jnp.zeros(K, jnp.int32)
+    if rd_src.shape[0]:
+        ok = (rd_live[None, :] != 0) & (t[:, rd_tgt] < t[:, rd_src])
+        violated += (ok != (rd_out[None, :] != 0)).sum(axis=1,
+                                                        dtype=jnp.int32)
+    if wr_src.shape[0]:
+        shift, valid = _war_operands(Db, wr_seq, wr_fid, wr_nr, lane_fid,
+                                     seg)
+        x = _lane_read(t, lane_src, wr_pos, shift, war_steps(seg))
+        ok = ((wr_seq[None, :] <= Db[:, wr_fid])
+              | (valid & (x < t[:, wr_src])))
+        violated += (ok != (wr_out[None, :] != 0)).sum(axis=1,
+                                                        dtype=jnp.int32)
+    return violated, cycles
 
 
 def straggler_cap(half_at):
@@ -309,12 +354,15 @@ def straggler_cap(half_at):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("max_seg", "war_seg", "width",
-                                    "interpret", "check"))
+                   static_argnames=("max_seg", "war_seg", "chk_seg", "width",
+                                    "interpret", "check", "times"))
 def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
               war_dst, war_wseq, war_fid, war_nr, war_pos, war_lane_src,
-              war_lane_fid, Db, bound, iters, *, max_seg: int, war_seg: int,
-              width: int, interpret: bool, check: bool = True):
+              war_lane_fid, chk_rd_src, chk_rd_tgt, chk_rd_live, chk_rd_out,
+              chk_wr_src, chk_wr_seq, chk_wr_fid, chk_wr_nr, chk_wr_pos,
+              chk_wr_out, chk_lane_src, chk_lane_fid, Db, bound, iters, n, *,
+              max_seg: int, war_seg: int, chk_seg: int, width: int,
+              interpret: bool, check: bool = True, times: bool = False):
     K = Db.shape[0]
     npad = c_seed.shape[0]
     c0 = jnp.broadcast_to(c_seed[None, :], (K, npad))
@@ -460,7 +508,14 @@ def _fixpoint(c_seed, cw, seg_start, raw_dst, raw_src, raw_w,
     # as longest_path_chains_batched's iteration-cap leftover rows; rows
     # the straggler cap (or a halt) left pending are undecided
     unsettled = pending & (rounds < iters)
-    return t, ~(diverged | pending), rounds, frozen_at, unsettled
+    with jax.named_scope("recheck"):
+        violated, cycles = _recheck(
+            t, Db, n, chk_rd_src, chk_rd_tgt, chk_rd_live, chk_rd_out,
+            chk_wr_src, chk_wr_seq, chk_wr_fid, chk_wr_nr, chk_wr_pos,
+            chk_wr_out, chk_lane_src, chk_lane_fid, chk_seg)
+    out = (~(diverged | pending), violated, cycles, rounds, frozen_at,
+           unsettled)
+    return out + (t,) if times else out
 
 
 def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
@@ -485,23 +540,41 @@ def _fixpoint_args(arr: ChainFlatArrays, Db: np.ndarray):
                                              dtype=np.int32)])
     args = (c_seed, cw, seg, arr.raw_dst, arr.raw_src, arr.raw_w,
             arr.war_dst, arr.war_wseq, arr.war_fid, arr.war_nr,
-            arr.war_pos, arr.war_lane_src, arr.war_lane_fid, Dp,
-            np.int32(arr.bound), np.int32(arr.n + 2))
+            arr.war_pos, arr.war_lane_src, arr.war_lane_fid,
+            arr.chk_rd_src, arr.chk_rd_tgt, arr.chk_rd_live, arr.chk_rd_out,
+            arr.chk_wr_src, arr.chk_wr_seq, arr.chk_wr_fid, arr.chk_wr_nr,
+            arr.chk_wr_pos, arr.chk_wr_out, arr.chk_lane_src,
+            arr.chk_lane_fid, Dp, np.int32(arr.bound), np.int32(arr.n + 2),
+            np.int32(arr.n))
     return args, dict(max_seg=arr.max_seg, war_seg=arr.war_seg,
-                      width=_tile_width(full))
+                      chk_seg=arr.chk_seg, width=_tile_width(full))
+
+
+class Verdicts(NamedTuple):
+    """Per-row results of :func:`solve_chains`, on the host."""
+
+    converged: np.ndarray       # (K,) bool: a fixpoint, and not unsettled
+    violated: np.ndarray        # (K,) int32 flipped constraint outcomes
+    cycles: np.ndarray          # (K,) int32 latest node time
+    rounds: int                 # the fixpoint loop's rounds
+    unsettled: np.ndarray       # (K,) bool: undecided at the straggler cap
+    times: Optional[np.ndarray] = None  # (n, K), only where asked for
 
 
 def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
                  interpret: Optional[bool] = None,
-                 block: Optional[int] = None):
+                 block: Optional[int] = None, times: bool = False):
     """Solve K depth configs over one chain-flat graph.
 
-    ``Db``: (K, n_fifos) depth block.  Returns ``(times, converged,
-    rounds, unsettled)`` — ``times`` (n, K) int32 in chain-major node
-    order (the layout ``core.dse.solve_block_status`` consumes),
+    ``Db``: (K, n_fifos) depth block.  Returns :class:`Verdicts`:
     ``converged[k]`` False where config k's regenerated WAR edges form a
     cycle or where ``unsettled[k]``: the row was still pending at
-    :func:`straggler_cap`, undecided, its times not final.
+    :func:`straggler_cap`, undecided, its verdict not final.  A converged
+    row's ``violated`` and ``cycles`` are decided on the device from its
+    final times (:func:`_recheck`, in the ``recheck`` scope of the same
+    program), so only these (K,) vectors come back to the host.
+    ``times=True`` also copies back the node times, (n, K) int32 in
+    chain-major node order, for a comparison with the numpy lane's.
     ``interpret=None`` derives the Pallas mode from the platform.
 
     ``block`` is the caller's full block height.  A block that pads to
@@ -516,34 +589,36 @@ def solve_chains(arr: ChainFlatArrays, Db: np.ndarray, *,
     ``war_lane`` and its shift steps ``war_steps``), ``solve.fixpoint``
     (waiting for the device; metadata: the loop's ``rounds``, the rows
     the cycle exit froze, ``cyclic_rows``, ``freeze_round``, the round
-    the last of them froze, 0 for none, and ``unsettled_rows``),
-    ``solve.copy_back``
-    (results to the host) and ``solve.transpose``.
+    the last of them froze, 0 for none, and ``unsettled_rows``) and
+    ``solve.copy_back`` (the per-row vectors to the host).
     """
     interpret = pallas_interpret(interpret)
     K = len(Db)
     if K == 0 or arr.n == 0:
-        return (np.zeros((arr.n, K), np.int32), np.ones(K, bool), 0,
-                np.zeros(K, bool))
+        return Verdicts(np.ones(K, bool), np.zeros(K, np.int32),
+                        np.zeros(K, np.int32), 0, np.zeros(K, bool),
+                        np.zeros((arr.n, K), np.int32) if times else None)
     with span("solve.upload") as sp:
         args, static = _fixpoint_args(arr, Db)
         floor = max(ROWS, 1)
         check = block is None or _pow2(K, floor) >= _pow2(block, floor)
         out = _fixpoint(*map(jnp.asarray, args), **static,
-                        interpret=interpret, check=check)
+                        interpret=interpret, check=check, times=times)
         sp.set_metadata(K=int(out[0].shape[0]),       # padded batch
                         war_lane=len(arr.war_lane_src),
                         war_steps=war_steps(arr.war_seg))
     with span("solve.fixpoint") as sp:
-        t, conv, rounds, frozen_at, unsettled = jax.block_until_ready(out)
+        conv, violated, cycles, rounds, frozen_at, unsettled = \
+            jax.block_until_ready(out)[:6]
         rounds, frozen_at = int(rounds), np.asarray(frozen_at)[:K]
         unsettled = np.asarray(unsettled)[:K]
         sp.set_metadata(rounds=rounds,
                         cyclic_rows=int((frozen_at > 0).sum()),
                         freeze_round=int(frozen_at.max()),
                         unsettled_rows=int(unsettled.sum()))
-    with span("solve.copy_back", bytes=t.nbytes + conv.nbytes):
-        t, conv = np.asarray(t), np.asarray(conv)
-    with span("solve.transpose"):
-        times = np.ascontiguousarray(t[:K, :arr.n].T)
-    return times, conv[:K], rounds, unsettled
+    with span("solve.copy_back",
+              bytes=conv.nbytes + violated.nbytes + cycles.nbytes):
+        conv, violated, cycles = jax.device_get((conv, violated, cycles))
+    t = np.asarray(out[6])[:K, :arr.n].T if times else None
+    return Verdicts(conv[:K], violated[:K], cycles[:K], rounds, unsettled,
+                    t)

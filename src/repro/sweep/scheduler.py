@@ -202,7 +202,7 @@ def _apply_shard_faults(out, hang_s: float, boom: bool, corrupt: bool):
     if boom:
         raise InjectedFault(SHARD_FAULT, -1)
     if corrupt and len(out[0]):
-        return (out[0][:-1], out[1][:-1], out[2][:-1], out[3])
+        return (out[0][:-1], out[1][:-1], out[2][:-1], *out[3:])
     return out
 
 
@@ -217,9 +217,10 @@ def _shard_task(graph, Db: np.ndarray, backend: str, block: int,
         _time.sleep(hang_s)
     if boom:
         raise InjectedFault(SHARD_FAULT, -1)
+    counts = {}
     out = solve_block_status(graph, Db, backend=backend, block=block,
-                             jax_interpret=jax_interpret)
-    return _apply_shard_faults(out, 0.0, False, corrupt)
+                             jax_interpret=jax_interpret, counts=counts)
+    return _apply_shard_faults((*out, counts), 0.0, False, corrupt)
 
 
 def _process_shard_solve(key: str, blob: Optional[bytes], Db: np.ndarray,
@@ -240,9 +241,10 @@ def _process_shard_solve(key: str, blob: Optional[bytes], Db: np.ndarray,
         _time.sleep(hang_s)
     if boom:
         raise InjectedFault(SHARD_FAULT, -1)
+    counts = {}
     out = solve_block_status(graph, Db, backend=backend, block=block,
-                             jax_interpret=jax_interpret)
-    return _apply_shard_faults(out, 0.0, False, corrupt)
+                             jax_interpret=jax_interpret, counts=counts)
+    return _apply_shard_faults((*out, counts), 0.0, False, corrupt)
 
 
 class BlockScheduler:
@@ -321,6 +323,8 @@ class BlockScheduler:
         self.stats_solve_calls = 0       # shard solves that returned
         self.stats_fixpoint_rounds = 0   # their fixpoint rounds, summed
         self.stats_cyclic_rows = 0       # rows they froze as cyclic
+        self.stats_device_verdict_rows = 0   # rows the device re-checked
+        self.stats_host_recheck_rows = 0     # rows the host re-checked
         self.stats_forced_bulk = 0       # bulk blocks forced past waiting
         #                                  interactive rows
 
@@ -656,7 +660,7 @@ class BlockScheduler:
                         self.jax_interpret)
                     attempt = _Attempt(fut, None, self._pool_gen)
                     continue
-                status, cycles, violated, rounds = out
+                status, cycles, violated, rounds, counts = out
                 if (len(status) != K or len(cycles) != K
                         or len(violated) != K):
                     raise ShardCorruption(
@@ -667,6 +671,10 @@ class BlockScheduler:
                     self.stats_fixpoint_rounds += int(rounds)
                     self.stats_cyclic_rows += int(
                         (np.asarray(status) == CYCLE).sum())
+                    self.stats_device_verdict_rows += \
+                        counts["device_verdict_rows"]
+                    self.stats_host_recheck_rows += \
+                        counts["host_recheck_rows"]
                 return (np.asarray(status, np.int8),
                         np.asarray(cycles, np.int64),
                         np.asarray(violated, np.int64), "")
@@ -949,6 +957,8 @@ class BlockScheduler:
                 "solve_calls": self.stats_solve_calls,
                 "fixpoint_rounds": self.stats_fixpoint_rounds,
                 "cyclic_rows": self.stats_cyclic_rows,
+                "device_verdict_rows": self.stats_device_verdict_rows,
+                "host_recheck_rows": self.stats_host_recheck_rows,
                 "forced_bulk_blocks": self.stats_forced_bulk,
                 "shards": self.shards,
                 "mode": self.mode,

@@ -362,6 +362,8 @@ def test_reused_shells_do_not_alias_mutable_state():
     assert base.stats.queries != -123
     assert "sentinel" not in r1.constraints
     assert "sentinel" not in base.constraints
+    assert r1.constraints == base.constraints
+    assert r0.constraints is not r1.constraints is not base.constraints
 
 
 # ------------------------------------------------------------ cycle exit
@@ -440,7 +442,7 @@ def test_a_block_below_the_full_height_leaves_cycles_to_the_host():
     cyclic = want[0] == CYCLE
     assert cyclic.any()
     arr = _sparse_arrays(g, _batch_arrays(g))
-    assert sp.solve_chains(arr, D, block=64)[3][cyclic].all()
+    assert sp.solve_chains(arr, D, block=64).unsettled[cyclic].all()
     args, static = sp._fixpoint_args(arr, D)
     text = {check: str(jax.make_jaxpr(functools.partial(
         sp._fixpoint, **static, interpret=True, check=check))(*args))
@@ -554,3 +556,65 @@ def test_exact_row_solve_matches_the_numpy_lane(height, width, seed):
     np.testing.assert_array_equal(conv_ex, conv_np)
     assert 0 < conv_np.sum() < len(D)
     np.testing.assert_array_equal(t_ex[:, conv_np], t_np[:, conv_np])
+
+
+def _verdict_case(case):
+    """(compiled graph, depth rows, block) of a device-verdict case."""
+    from repro.core.incremental import compile_graph
+    from repro.designs.paper import fig2_timer, multicore
+    if case == "optical_flow":
+        _, g, D = _optical_flow_rows(2, 64, 0, k=16)
+        return g, D, 16
+    if case == "unsettled":       # a short block leaves rows to the host
+        _, g, D = _optical_flow_rows(2, 64, 3, k=8)
+        return g, D, 64
+    build, lo, hi, k = {
+        "multicore": (lambda: multicore(cores=2, prog_len=16, stride=4),
+                      0, 12, 64),
+        "fig2_timer": (lambda: fig2_timer(64), 0, 1, 16),
+        "skynet_like": (lambda: skynet_like(items=16, depth=4), 1, 8, 16),
+    }[case]
+    base = simulate(build(), trace="auto")
+    D = np.random.default_rng(7).integers(lo, hi + 1, (k, len(base.depths)))
+    return compile_graph(base.graph), D, k
+
+
+@pytest.mark.parametrize("case", ["multicore", "fig2_timer", "skynet_like",
+                                  "optical_flow", "unsettled"])
+def test_device_verdicts_match_the_numpy_lane(case):
+    """The jax lane decides each row's violations and cycle count on the
+    device and copies back only those: its statuses, cycles and
+    violations equal the numpy lane's host re-check row for row.  A Type
+    C design with can-read and can-write constraints and REUSED, VIOLATED
+    and CYCLE rows; can-read constraints only; designs without
+    constraints, CYCLE rows included; a block whose undecided rows the
+    host solves and re-checks."""
+    from repro.core.dse import (CYCLE, DEADLOCK, VIOLATED, _batch_arrays,
+                                _sparse_arrays, solve_block_status)
+    from repro.kernels.maxplus import sparse as sp
+    g, D, block = _verdict_case(case)
+    want = solve_block_status(g, D, backend="numpy", block=block)
+    counts = {}
+    got = solve_block_status(g, D, backend="jax", block=block,
+                             counts=counts)
+    for i in range(3):
+        np.testing.assert_array_equal(got[i], want[i])
+    alive = want[0] != DEADLOCK
+    assert (counts["device_verdict_rows"] + counts["host_recheck_rows"]
+            == alive.sum())
+    arr = _sparse_arrays(g, _batch_arrays(g))
+    kinds = (len(arr.chk_rd_src) > 0, len(arr.chk_wr_src) > 0)
+    v = sp.solve_chains(arr, D[alive], block=block)
+    on_device = np.zeros(len(D), bool)
+    on_device[alive] = ~v.unsettled
+    assert on_device.sum() == counts["device_verdict_rows"]
+    if case in ("multicore", "fig2_timer"):
+        assert kinds == (True, case == "multicore")
+        assert (want[0][on_device] == VIOLATED).any()
+        assert (want[0] == CYCLE).any() and (want[0] == 0).any()
+    else:
+        assert kinds == (False, False)
+    if case == "optical_flow":
+        assert (want[0][on_device] == CYCLE).any()
+    if case == "unsettled":
+        assert counts["host_recheck_rows"] > 0

@@ -270,9 +270,10 @@ def test_solve_chains_matches_numpy_seeded_solver():
     Gauss-Seidel production solver, WAR edges active."""
     from repro.core import simulate
     from repro.core.dse import (_batch_arrays, _solve_block_numpy,
-                                _solve_sparse_jax)
+                                _sparse_arrays)
     from repro.core.incremental import compile_graph
     from repro.designs.typea import skynet_like
+    from repro.kernels.maxplus import sparse as sp
 
     base = simulate(skynet_like(items=16, depth=4))
     g = compile_graph(base.graph)
@@ -280,7 +281,8 @@ def test_solve_chains_matches_numpy_seeded_solver():
     rng = np.random.default_rng(2)
     Db = rng.integers(1, 9, size=(8, len(base.depths))).astype(np.int64)
     t_np, conv_np, _ = _solve_block_numpy(ba, Db)
-    t_jx, conv_jx, _ = _solve_sparse_jax(g, ba, Db)
+    v = sp.solve_chains(_sparse_arrays(g, ba), Db, times=True)
+    t_jx, conv_jx = v.times, v.converged
     assert (conv_np == conv_jx).all()
     # converged configs: full (n, K) node-time agreement, not just cycles
     cols = np.flatnonzero(conv_np)
@@ -349,7 +351,8 @@ def test_solve_chains_node_tiles_match_numpy(monkeypatch):
     rng = np.random.default_rng(3)
     Db = rng.integers(1, 9, size=(8, len(base.depths))).astype(np.int64)
     t_np, conv_np, _ = _solve_block_numpy(ba, Db)
-    t_jx, conv_jx, _, _ = sp.solve_chains(arr, Db)
+    v = sp.solve_chains(arr, Db, times=True)
+    t_jx, conv_jx = v.times, v.converged
     assert (conv_np == conv_jx).all()
     cols = np.flatnonzero(conv_np)
     assert len(cols)
@@ -454,7 +457,8 @@ def test_solve_chains_multicore_matches_numpy():
     rng = np.random.default_rng(4)
     Db = rng.integers(0, 12, size=(16, len(base.depths))).astype(np.int64)
     t_np, conv_np, _ = _solve_block_numpy(ba, Db)
-    t_jx, conv_jx, _, _ = sp.solve_chains(arr, Db, interpret=True)
+    v = sp.solve_chains(arr, Db, interpret=True, times=True)
+    t_jx, conv_jx = v.times, v.converged
     assert (conv_np == conv_jx).all()
     cols = np.flatnonzero(conv_np)
     assert len(cols)
@@ -497,4 +501,34 @@ def test_fixpoint_loop_gathers_share_their_indices():
     # pulls its labels through the same three inside its own loop
     assert len(loop) == 6
     for e in loop:
+        assert e.params["slice_sizes"][0] == K, e
+
+
+def test_recheck_gathers_share_their_indices():
+    """The device epilogue that decides each row's verdict reads whole
+    columns (one index for all K rows): a can-write constraint's target
+    read comes through the check lane's barrel shift, with no per-row
+    gather of the times."""
+    from repro.core import simulate
+    from repro.core.dse import _batch_arrays, _sparse_arrays
+    from repro.core.incremental import compile_graph
+    from repro.designs.paper import multicore
+    from repro.kernels.maxplus import sparse as sp
+
+    base = simulate(multicore(cores=2, prog_len=16, stride=4), trace="auto")
+    g = compile_graph(base.graph)
+    arr = _sparse_arrays(g, _batch_arrays(g))
+    assert len(arr.chk_rd_src) and len(arr.chk_wr_src)
+    assert sp.war_steps(arr.chk_seg) > 1
+    K = 16
+    args, static = sp._fixpoint_args(
+        arr, np.ones((K, len(base.depths)), np.int64))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        sp._fixpoint, **static, interpret=True))(*args)
+    epilogue = [e for in_loop, e in _gathers(jaxpr.jaxpr)
+                if not in_loop and "recheck" in str(e.source_info.name_stack)]
+    # reads: source and target; writes: source, the lane and its slot,
+    # and the depth columns of the shifts, the mask and the outright test
+    assert len(epilogue) == 8
+    for e in epilogue:
         assert e.params["slice_sizes"][0] == K, e

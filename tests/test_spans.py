@@ -6,7 +6,8 @@ service, the solve phase and the cache open is there with its
 attributes; the scheduler thread's phase spans are flat and grouped by
 their ``block`` number; a request's submit, dequeue and done carry its
 ``rid``.  The scheduler's counters (``solve_calls``, ``fixpoint_rounds``,
-``forced_bulk_blocks``) are checked against the same run.
+``forced_bulk_blocks``, ``device_verdict_rows``, ``host_recheck_rows``)
+are checked against the same run.
 """
 import glob
 import threading
@@ -30,16 +31,15 @@ SPANS = {
     "solve.fixpoint": {"rounds", "cyclic_rows", "freeze_round",
                        "unsettled_rows"},
     "solve.copy_back": {"bytes"},
-    "solve.transpose": set(),
-    "solve.recheck": {"rounds", "violated_rows"},
+    "solve.recheck": {"rounds", "violated_rows", "rows"},
     "sweep.materialize": {"block", "fallbacks"},
     "sweep.deliver": {"block", "rows"},
     "sweep.request_done": {"rid", "lane", "latency_us"},
 }
 # the scheduler thread's phases, which never nest
 PHASES = ("sweep.assemble", "sweep.dedup", "solve.upload", "solve.fixpoint",
-          "solve.copy_back", "solve.transpose", "solve.recheck",
-          "sweep.materialize", "sweep.deliver")
+          "solve.copy_back", "solve.recheck", "sweep.materialize",
+          "sweep.deliver")
 
 
 def _build():
@@ -110,19 +110,40 @@ def _all(lines):
     return [e for ln in lines for e in ln]
 
 
+def _idle_poll(e):
+    """An assemble that found no rows (``solve.recheck``'s ``rows`` may
+    be 0 in a block that did work)."""
+    return e[0] == "sweep.assemble" and not e[3].get("rows")
+
+
 @pytest.mark.parametrize("name", sorted(SPANS))
 def test_every_span_appears_with_its_attributes(jax_run, name):
     evs = [e for e in _all(jax_run[0]) if e[0] == name]
     assert evs, name
     # an idle poll's assemble carries rows=0 and nothing else
-    evs = [e for e in evs if e[3].get("rows", 1)]
+    evs = [e for e in evs if not _idle_poll(e)]
     assert evs and all(SPANS[name] <= set(e[3]) for e in evs), \
         (name, [e[3] for e in evs])
 
 
+def test_jax_lane_copies_back_verdicts_not_times(jax_run):
+    """The device decides every row's verdict: the host copies back
+    per-row vectors, transposes no time matrix and re-checks no row."""
+    lines, st = jax_run
+    evs = _all(lines)
+    assert not [e for e in evs if e[0] == "solve.transpose"]
+    recheck = [e[3] for e in evs if e[0] == "solve.recheck"]
+    assert recheck and all(r["rows"] == 0 for r in recheck)
+    assert st["host_recheck_rows"] == 0
+    assert st["device_verdict_rows"] == st["rows_unique"] > 0
+    for e in evs:
+        if e[0] == "solve.copy_back":       # three (K,) vectors
+            assert e[3]["bytes"] <= 3 * 4 * 16, e
+
+
 def test_phase_spans_are_flat_and_share_their_block(jax_run):
     line = _scheduler_line(jax_run[0])
-    phases = [e for e in line if e[0] in PHASES and e[3].get("rows", 1)]
+    phases = [e for e in line if e[0] in PHASES and not _idle_poll(e)]
     for a, b in zip(phases, phases[1:]):
         assert a[2] <= b[1], (a, b)              # never nest or overlap
     block, seen = None, []
@@ -175,6 +196,8 @@ def test_numpy_lane_emits_the_sweep_spans(tmp_path):
     assert {n for n in SPANS if n.startswith("sweep.")} <= names
     assert "solve.recheck" in names and "solve.fixpoint" not in names
     assert st["solve_calls"] == st["blocks"]
+    assert st["device_verdict_rows"] == 0
+    assert st["host_recheck_rows"] == st["rows_unique"] > 0
 
 
 def test_cache_resolve_names_how_the_entry_was_found():

@@ -621,9 +621,7 @@ def table_sparse_maxplus() -> List[str]:
     compiled on a TPU, interpret mode on the CPU) on a 100-module corpus
     design: device-lane throughput at K = 1e3 / 1e4 / 1e5 depth configs,
     plus the ratio against the numpy Gauss-Seidel fixpoint at the largest
-    K.  The dense ``jax_dense`` lowering cannot run this design at all — its
-    (K, npad, npad) working set is O(n^2) per config.  ``--quick`` keeps
-    every key but solves K/100 configs per point."""
+    K.  ``--quick`` keeps every key but solves K/100 configs per point."""
     import numpy as np
 
     from repro.core.dse import solve_block_status
@@ -637,7 +635,7 @@ def table_sparse_maxplus() -> List[str]:
     # recorded next to the maxplus_sparse_* keys: interpret mode executes
     # the Pallas kernel body through XLA on CPU, so its numbers are not
     # comparable with a compiled-device trajectory
-    jax_interpret = pallas_interpret()
+    interpret = pallas_interpret()
     for seed in range(8):           # first live seed, deterministically
         c = generate(seed, scale=100, spec=BENCH_SPEC)
         base_run = simulate(c.builder(), trace="auto")
@@ -655,8 +653,7 @@ def table_sparse_maxplus() -> List[str]:
 
     # warm both solvers (jit compile + chain-flat export on the jax side)
     solve_block_status(g, depths(min(block, 1000 // shrink)),
-                       backend="jax", block=block,
-                       jax_interpret=jax_interpret)
+                       backend="jax", block=block)
     Kn = max(1000 // shrink, 1)
     s_np, t_np = _timeit(lambda: solve_block_status(g, depths(Kn),
                                                     backend="numpy",
@@ -669,7 +666,7 @@ def table_sparse_maxplus() -> List[str]:
         Keff = max(K // shrink, 1)
         D = depths(Keff)
         out, t_jx = _timeit(lambda: solve_block_status(
-            g, D, backend="jax", block=block, jax_interpret=jax_interpret))
+            g, D, backend="jax", block=block))
         us_jx = t_jx / Keff * 1e6
         reused = int((out[0] == 0).sum())
         print(f"{Keff:8d} {t_jx*1e3:10.1f} {us_jx:7.0f} "
@@ -680,7 +677,7 @@ def table_sparse_maxplus() -> List[str]:
     # interpret mode runs the TPU kernel through XLA on CPU, so this ratio
     # understates the device lane; it pins the trajectory either way
     BENCH_CORE["maxplus_sparse_vs_numpy_speedup"] = us_np / us_jx
-    BENCH_CORE["maxplus_sparse_jax_interpret"] = jax_interpret
+    BENCH_CORE["maxplus_sparse_jax_interpret"] = interpret
     print(f"numpy baseline: {us_np:.0f} us/cfg at K={Kn} "
           f"(ratio at largest K: {us_np/us_jx:.2f}x)")
     return rows

@@ -2,31 +2,21 @@
 
     PYTHONPATH=src python examples/pipeline_perfsim.py
 
-The paper's technique integrated into the training framework: a pipeline-
-parallel step is a dataflow design (stages = modules, activation queues =
-FIFOs).  OmniSim predicts step time for GPipe vs 1F1B across microbatch
-counts and buffer depths, using incremental re-simulation for the depth
-sweep.  If dry-run roofline records exist (reports/dryrun), tick costs come
-from the real compiled step of qwen2.5-14b.
+A pipeline-parallel training step is a dataflow design (stages = modules,
+activation queues = FIFOs).  OmniSim predicts step time for GPipe vs 1F1B
+across microbatch counts and buffer depths, using incremental
+re-simulation for the depth sweep.  Tick costs are synthetic: 40 ticks
+forward and 80 backward per stage and microbatch.
 """
 import dataclasses
 
 from repro.perfsim.pipeline import (PipelineSpec, buffer_depth_dse,
                                     simulate_pipeline)
-from repro.perfsim.stepmodel import load_record, spec_from_roofline
 
 
 def main():
-    rec = load_record("reports/dryrun", "qwen2.5-14b", "train_4k")
-    if rec is not None and "roofline" in rec:
-        spec = spec_from_roofline(rec, stages=8, microbatches=32)
-        print(f"tick costs from qwen2.5-14b train_4k dry-run: "
-              f"fwd={spec.fwd_ticks} bwd={spec.bwd_ticks} ticks/stage/mb\n")
-    else:
-        spec = PipelineSpec(stages=8, microbatches=32, fwd_ticks=40,
-                            bwd_ticks=80)
-        print("no dry-run records found; using synthetic tick costs\n")
-
+    spec = PipelineSpec(stages=8, microbatches=32, fwd_ticks=40,
+                        bwd_ticks=80)
     print(f"{'schedule':>9s} {'mb':>4s} {'depth':>6s} {'step(ticks)':>12s} "
           f"{'bubble':>8s}")
     for schedule in ("gpipe", "1f1b"):

@@ -15,7 +15,7 @@ from .engine import OmniSim, simulate
 from .events import (Constraint, DeadlockError, NodeKind, Query, RequestType,
                      SimStats, UnsupportedDesignError)
 from .graph import (SimGraph, level_schedule, longest_path_numpy,
-                    longest_path_python, to_dense_blocks)
+                    longest_path_python)
 from .dse import BatchOutcome, resimulate_batch
 from .incremental import (CompiledGraph, IncrementalOutcome, compile_graph,
                           resimulate)
@@ -36,7 +36,7 @@ __all__ = [
     "Program", "Fifo", "Module", "Op", "Read", "Write", "ReadNB", "WriteNB",
     "Empty", "Full", "Delay", "Emit", "SimResult", "SimGraph",
     "longest_path_numpy", "longest_path_python", "level_schedule",
-    "to_dense_blocks", "Constraint", "DeadlockError", "Query", "RequestType",
+    "Constraint", "DeadlockError", "Query", "RequestType",
     "NodeKind", "SimStats", "UnsupportedDesignError", "CSimCrash",
     "classify_dynamic",
     "TraceUnsupported", "RecordedTrace", "ModuleTrace", "CompiledTrace",

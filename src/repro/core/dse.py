@@ -33,13 +33,11 @@ lifted to a batch of solves):
 
 Backends: ``"numpy"`` (default, above), ``"reference"`` (the synchronous
 Jacobi :func:`~repro.core.graph.longest_path_chains_batched` — the oracle
-the production solver is tested against), ``"jax"`` — the sparse
+the production solver is tested against) and ``"jax"`` — the sparse
 chain-structured Pallas max-plus solver (``repro.kernels.maxplus.sparse``:
 segmented cummax over the chain-major flat arrays, on-device WAR
 regeneration, O(K·n + K·edges) memory) for device-resident sweeps of any
-graph size — and ``"jax_dense"``, the historical ``jax.vmap`` lowering of
-the dense O(n²)-per-config max-plus fixpoint, kept for tiny graphs and as
-a second device oracle.
+graph size.
 """
 from __future__ import annotations
 
@@ -52,7 +50,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from ..device import pallas_interpret, span
+from ..device import span
 from .engine import OmniSim, simulate
 from .graph import (CHECK_FLOOR, cycle_check_due, export_chain_flat,
                     longest_path_chains, longest_path_chains_batched,
@@ -543,7 +541,6 @@ def _pointer_cycles(ba: _BatchArrays, c: np.ndarray, cw: np.ndarray,
 
 def solve_block_status(cache: CompiledGraph, depth_block,
                        backend: str = "numpy", block: int = 128,
-                       jax_interpret: Optional[bool] = None,
                        counts: Optional[dict] = None):
     """Engine-free solve phase of :func:`resimulate_batch`.
 
@@ -566,10 +563,6 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     The other lanes re-check every row on the host.  ``counts``, where
     given, receives ``device_verdict_rows`` and ``host_recheck_rows``,
     the rows each side decided.
-
-    ``jax_interpret`` (jax backends only) defaults to the platform's
-    Pallas mode (:func:`repro.device.pallas_interpret`): compiled on a
-    TPU, interpreted on the CPU.
     """
     ba = _batch_arrays(cache)
     D = np.asarray(depth_block, dtype=np.int64)
@@ -589,31 +582,21 @@ def solve_block_status(cache: CompiledGraph, depth_block,
     # positions the host re-checks, their node times (n, len(positions)))
     blocks = []
 
-    if len(alive):
-        if backend in ("jax", "jax_dense"):
-            jax_interpret = pallas_interpret(jax_interpret)
-        if backend == "jax_dense":
-            sl = np.arange(len(alive))
-            t_nm, conv = _solve_dense_jax(cache, ba, D[alive],
-                                          interpret=jax_interpret,
-                                          block=block)
-            blocks.append(_host_verdicts(sl, t_nm, conv))
-        elif backend in ("numpy", "reference", "jax"):
-            for lo in range(0, len(alive), max(block, 1)):
-                sl = np.arange(lo, min(lo + max(block, 1), len(alive)))
-                if backend == "jax":    # sparse chain-structured Pallas lane
-                    *verdicts, rounds = _solve_sparse_jax(
-                        cache, ba, D[alive[sl]], interpret=jax_interpret,
-                        block=block)
-                    blocks.append((sl, *verdicts))
-                else:
-                    solve = (_solve_block_numpy if backend == "numpy"
-                             else _solve_block_reference)
-                    t_nm, conv, rounds = solve(ba, D[alive[sl]])
-                    blocks.append(_host_verdicts(sl, t_nm, conv))
-                total_rounds = max(total_rounds, rounds)
+    if backend not in ("numpy", "reference", "jax"):
+        raise ValueError(f"unknown backend {backend!r}")
+    step = max(block, 1)
+    for lo in range(0, len(alive), step):
+        sl = np.arange(lo, min(lo + step, len(alive)))
+        if backend == "jax":            # sparse chain-structured Pallas lane
+            *verdicts, rounds = _solve_sparse_jax(
+                cache, ba, D[alive[sl]], block=block)
+            blocks.append((sl, *verdicts))
         else:
-            raise ValueError(f"unknown backend {backend!r}")
+            solve = (_solve_block_numpy if backend == "numpy"
+                     else _solve_block_reference)
+            t_nm, conv, rounds = solve(ba, D[alive[sl]])
+            blocks.append(_host_verdicts(sl, t_nm, conv))
+        total_rounds = max(total_rounds, rounds)
     host_rows = 0
     if blocks:
         with span("solve.recheck", rounds=total_rounds) as sp:
@@ -721,7 +704,9 @@ def materialize_block(result: SimResult, Du: np.ndarray,
                 program=result.program, outputs=dict(result.outputs),
                 cycles=int(cycles_u[u]), engine=engine_label,
                 stats=copy.copy(result.stats), graph=engine,
-                constraints=None, depths=tuple(int(d) for d in Du[u]))
+                constraints=None, depths=tuple(int(d) for d in Du[u]),
+                deadlock=result.deadlock,
+                deadlock_cycle=result.deadlock_cycle)
             shell.base_constraints = result.constraints
             results_u[u] = shell
         elif fallback_mask[u] and status_u[u] in FALLBACK_STATUSES:
@@ -742,7 +727,6 @@ def materialize_block(result: SimResult, Du: np.ndarray,
 def resimulate_batch(result: SimResult, depth_matrix,
                      fallback: bool = True, backend: str = "numpy",
                      block: int = 128,
-                     jax_interpret: Optional[bool] = None,
                      dedup: bool = True) -> BatchOutcome:
     """Incrementally re-simulate ``result`` under K depth vectors at once.
 
@@ -763,10 +747,9 @@ def resimulate_batch(result: SimResult, depth_matrix,
 
     ``backend="jax"`` lowers the fixpoint onto the sparse chain-structured
     Pallas max-plus kernel (``repro.kernels.maxplus.sparse``) — O(K·n +
-    K·edges) memory, device-resident sweeps; ``backend="jax_dense"`` keeps
-    the legacy dense O(n^2)-per-config vmap lowering for tiny graphs;
-    ``backend="reference"`` runs the synchronous Jacobi oracle.  ``block``
-    bounds the per-slab working set for every backend.
+    K·edges) memory, device-resident sweeps; ``backend="reference"`` runs
+    the synchronous Jacobi oracle.  ``block`` bounds the per-slab working
+    set for every backend.
     """
     t0 = _time.perf_counter()
     engine: OmniSim = result.graph
@@ -787,7 +770,7 @@ def resimulate_batch(result: SimResult, depth_matrix,
         Du, inverse = D, np.arange(K)
     U = len(Du)
     status_u, cycles_u, violated_u, total_rounds = solve_block_status(
-        cache, Du, backend=backend, block=block, jax_interpret=jax_interpret)
+        cache, Du, backend=backend, block=block)
 
     # ④ fall back to full re-simulation for exactly the failed subset —
     # once per unique config; duplicate rows share the result object
@@ -805,25 +788,19 @@ def resimulate_batch(result: SimResult, depth_matrix,
 
 
 # ---------------------------------------------------------------------------
-# jax backends: sparse chain-structured kernel + legacy dense vmap
+# jax backend: sparse chain-structured kernel
 # ---------------------------------------------------------------------------
-# Working-set ceiling for the dense lowering: K * npad^2 int32 entries per
-# slab.  A module constant so regression tests can shrink it and exercise
-# the chunking/error paths without gigabyte batches.
-_DENSE_CAP = 1 << 27
-
-
-def _int32_saturation_guard(ba: _BatchArrays, backend: str) -> None:
+def _int32_saturation_guard(ba: _BatchArrays) -> None:
     """Refuse int32 device transfer when finite times could exceed int32.
 
     ``ba.bound`` bounds every finite (acyclic) node time and the numpy
-    path switches to int64 at ``2^28``; the jax lanes are int32-only, so
+    path switches to int64 at ``2^28``; the jax lane is int32-only, so
     past that point a silently wrapped time could flip a constraint
     comparison.  Raise instead of wrapping.
     """
     if ba.bound >= (1 << 28):
         raise ValueError(
-            f"backend={backend!r} solves in int32 but the graph's "
+            f"backend='jax' solves in int32 but the graph's "
             f"path-length bound {ba.bound} >= 2^28 risks overflow; "
             f"use backend='numpy' (int64) for this design")
 
@@ -844,8 +821,7 @@ def _sparse_arrays(cache: CompiledGraph, ba: _BatchArrays):
 
 
 def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
-                      Db: np.ndarray, interpret: Optional[bool] = None,
-                      block: Optional[int] = None):
+                      Db: np.ndarray, block: Optional[int] = None):
     """Sparse chain-structured Pallas solve for one block of configs.
 
     Seeds every config at the no-WAR fixpoint contribution (``c_inf``, a
@@ -865,9 +841,9 @@ def _solve_sparse_jax(cache: CompiledGraph, ba: _BatchArrays,
     """
     from ..kernels.maxplus import sparse as sp
 
-    _int32_saturation_guard(ba, "jax")
+    _int32_saturation_guard(ba)
     arr = _sparse_arrays(cache, ba)
-    v = sp.solve_chains(arr, Db, interpret=interpret, block=block)
+    v = sp.solve_chains(arr, Db, block=block)
     conv = np.array(v.converged)
     host = np.flatnonzero(v.unsettled)
     times = np.zeros((len(ba.perm), 0), np.int64)
@@ -946,74 +922,3 @@ def _solve_rows_exact(ba: _BatchArrays, Db: np.ndarray, neg: int):
         times[:, r] = t
     return times, conv
 
-
-def _solve_dense_jax(cache: CompiledGraph, ba: _BatchArrays, Db: np.ndarray,
-                     interpret: bool, block: int = 128):
-    """Batched node times via ``jax.vmap`` over the dense Pallas max-plus
-    kernel (``repro.kernels.maxplus``) — the legacy O(n^2)-per-config
-    lowering, kept as ``backend="jax_dense"`` for tiny graphs.
-
-    Builds dense ``(slab, npad, npad)`` max-plus adjacencies (shared
-    SEQ+RAW skeleton broadcast, per-config WAR entries scattered in) and
-    vmaps the jitted fixpoint, chunking the batch so one slab never
-    exceeds ``_DENSE_CAP`` int32 entries (a *single* config past the cap
-    is a hard error).  Convergence is certified by one extra sweep:
-    non-converged rows (WAR cycles) report False.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..kernels.maxplus.kernel import BLK, NEG as NEG32, maxplus_sweep
-    from ..kernels.maxplus.ops import longest_path
-
-    n = cache.n
-    npad = ((n + BLK - 1) // BLK) * BLK if n else BLK
-    K = len(Db)
-    if npad * npad > _DENSE_CAP:
-        raise ValueError(
-            f"dense jax backend needs npad^2 <= {_DENSE_CAP} per config "
-            f"(got {npad}^2); use backend='numpy' (or the sparse "
-            f"backend='jax') for large graphs")
-    _int32_saturation_guard(ba, "jax_dense")
-    slab = max(1, min(max(block, 1), _DENSE_CAP // (npad * npad)))
-    # clip int64 weights against the kernel's -INF before the int32 cast —
-    # a bare .astype would wrap NEGI into a huge positive phantom edge
-    # (the hazard ops.finalize_times documents for a/base)
-    b = np.full((npad,), int(NEG32), dtype=np.int32)
-    b[:n] = np.maximum(cache.base, int(NEG32)).astype(np.int32)
-    A = np.full((npad, npad), int(NEG32), dtype=np.int32)
-    for ch in cache.chains:                      # SEQ skeleton
-        if len(ch) > 1:
-            A[ch[1:], ch[:-1]] = np.maximum(
-                cache.seq_w[ch[1:]], int(NEG32)).astype(np.int32)
-    A[cache.raw_dst, cache.raw_src] = np.maximum(
-        cache.raw_w, int(NEG32)).astype(np.int32)
-    bK = jnp.asarray(b)
-    solve = jax.vmap(lambda a: longest_path(a, bK, use_pallas=True,
-                                            interpret=interpret))
-    sweep = jax.vmap(lambda a, t: maxplus_sweep(a, t, bK,
-                                                interpret=interpret))
-    times_parts, conv_parts = [], []
-    for lo in range(0, K, slab):
-        Ds = Db[lo:lo + slab]
-        AK = np.broadcast_to(A, (len(Ds), npad, npad)).copy()
-        for fid, (w_nodes, r_nodes, blk) in enumerate(cache.fifos):
-            nw, nr = len(w_nodes), len(r_nodes)
-            if nw == 0 or int(Ds[:, fid].min()) >= nw:
-                continue
-            w_seq = np.arange(1, nw + 1, dtype=np.int64)
-            tgt = w_seq[None, :] - Ds[:, fid][:, None] - 1
-            valid = blk[None, :] & (tgt >= 0) & (tgt < nr)
-            kk, jj = np.nonzero(valid)
-            AK[kk, w_nodes[jj], r_nodes[tgt[kk, jj]]] = 1
-        aK = jnp.asarray(AK)
-        tK = solve(aK)
-        # certify fixpoint: one more sweep must be a no-op (cycles diverge)
-        conv_parts.append(np.asarray((sweep(aK, tK) == tK).all(axis=1)))
-        times_parts.append(np.asarray(tK)[:, :n].astype(np.int64))
-    times = np.concatenate(times_parts) if times_parts else \
-        np.zeros((0, n), np.int64)
-    conv = np.concatenate(conv_parts) if conv_parts else np.zeros(0, bool)
-    times_nm = (np.ascontiguousarray(times[:, ba.perm].T) if n
-                else times.T)
-    return times_nm, conv

@@ -12,9 +12,10 @@ Three longest-path backends:
 
   * ``longest_path_numpy`` — vectorized CSR forward pass over levels
     (production path on CPU; reference for the others).
-  * ``repro.kernels.maxplus`` — Pallas TPU kernel: blocked dense max-plus
-    relaxation with VMEM tiling (the TPU analogue of LightningSimV2's
-    compiled CSR graph).  Used for device-resident incremental re-sim.
+  * ``repro.kernels.maxplus.sparse`` — Pallas TPU kernel: segmented cummax
+    over the chain-major flat arrays :func:`export_chain_flat` builds (the
+    TPU analogue of LightningSimV2's compiled CSR graph).  Used for
+    device-resident batched re-sim.
   * ``longest_path_python`` — straight-line oracle used in tests.
 """
 from __future__ import annotations
@@ -631,22 +632,3 @@ def export_chain_flat(chain_slices, cw, c_seed, raw_dst, raw_src, raw_w,
         **(_export_checks(*checks, fifo_w_cols, fifo_r_cols)
            if checks is not None else {}))
 
-
-def to_dense_blocks(indptr: np.ndarray, src: np.ndarray, wgt: np.ndarray,
-                    base: np.ndarray, pad_to: int = 128):
-    """Dense max-plus adjacency for the Pallas kernel (small graphs).
-
-    Returns (A, b) with A[i, j] = weight of edge j->i or -INF, padded to a
-    multiple of ``pad_to`` so MXU/VPU tiles are hardware-aligned.
-    """
-    n = len(base)
-    npad = ((n + pad_to - 1) // pad_to) * pad_to if n else pad_to
-    NEG = np.int64(-(1 << 40))
-    A = np.full((npad, npad), NEG, dtype=np.int64)
-    b = np.full((npad,), NEG, dtype=np.int64)
-    b[:n] = base
-    for i in range(n):
-        lo, hi = indptr[i], indptr[i + 1]
-        for k in range(lo, hi):
-            A[i, src[k]] = max(A[i, src[k]], wgt[k])
-    return A, b

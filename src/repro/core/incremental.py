@@ -326,7 +326,8 @@ def resimulate(result: SimResult, new_depths: Sequence[int],
                                 outputs=dict(result.outputs), cycles=cycles,
                                 engine="omnisim-incr", stats=result.stats,
                                 graph=engine, constraints=result.constraints,
-                                depths=new_depths)
+                                depths=new_depths, deadlock=result.deadlock,
+                                deadlock_cycle=result.deadlock_cycle)
             return IncrementalOutcome(True, "constraints satisfied", elapsed,
                                       new_res)
         err = f"{violated} constraint(s) violated — control/data flow diverges"

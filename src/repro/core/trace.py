@@ -1166,11 +1166,10 @@ def build_traced_result(program: Program, rec: RecordedTrace,
 
     Shared by :func:`simulate_traced` (cold record) and
     ``repro.delta.patch`` (spliced re-record): given a solved trace, build
-    an engine shell so downstream consumers (incremental, DSE, taxonomy,
-    ``kernels.finalize_times``) see exactly the generator engine's end
-    state.  ``graph`` optionally supplies an already-built
-    ``to_compiled_graph(ct)`` (the patch path builds one for verification
-    anyway) so it isn't rebuilt here.
+    an engine shell so downstream consumers (incremental, DSE, taxonomy)
+    see exactly the generator engine's end state.  ``graph`` optionally
+    supplies an already-built ``to_compiled_graph(ct)`` (the patch path
+    builds one for verification anyway) so it isn't rebuilt here.
     """
     depths = program.depths()
     cycles = int(times.max()) if ct.n else 0

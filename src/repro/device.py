@@ -34,9 +34,9 @@ def pallas_interpret(requested: Optional[bool] = None) -> bool:
     derived = INTERPRET_BY_PLATFORM[platform]
     if requested is not None and bool(requested) != derived:
         raise ValueError(
-            f"jax_interpret={requested!r} on platform {platform!r}: Pallas "
+            f"interpret={requested!r} on platform {platform!r}: Pallas "
             f"kernels run {'interpreted' if derived else 'compiled'} here; "
-            f"leave jax_interpret unset (None) to derive it")
+            f"leave interpret unset (None) to derive it")
     return derived
 
 

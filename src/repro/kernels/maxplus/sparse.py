@@ -1,9 +1,7 @@
 """Sparse chain-structured batched max-plus solver (Pallas TPU kernel).
 
-The dense kernel in ``kernel.py`` materializes the whole max-plus
-adjacency — O(n^2) per depth config — which caps the ``backend="jax"``
-DSE lane at tiny graphs.  This module is its sparse replacement: it runs
-the chain-decomposed fixpoint of ``core.dse._solve_block_numpy`` /
+The ``backend="jax"`` DSE lane.  It runs the chain-decomposed fixpoint
+of ``core.dse._solve_block_numpy`` /
 ``core.graph.longest_path_chains_batched`` directly over the chain-major
 flat arrays (:class:`repro.core.graph.ChainFlatArrays`), so a block of K
 depth configs costs O(K·n + K·edges) memory and sweeps of 10^5–10^6
@@ -106,8 +104,8 @@ constraints compiles only the row max.
 
 Everything is int32 on device — callers must clip against :data:`NEG`
 and refuse graphs whose path-length bound nears int32 range (see
-``core.dse``'s saturation guard); this mirrors the wrap-around hazard
-``ops.finalize_times`` documents for the dense path.
+``core.dse``'s saturation guard): a bare int32 cast of the host's int64
+-INF would wrap it into a phantom edge.
 
 Shape bucketing: batch, edge and WAR-table lengths are padded up to
 powers of two (padding rows replicate row 0; padding edges carry the

@@ -13,8 +13,8 @@ The OmniSim engine then gives, *for free*:
     DSE sweeps depths in microseconds instead of re-simulating each point,
   * bubble-fraction accounting from the simulation graph.
 
-Tick costs come from the dry-run roofline terms (launch/roofline.py):
-per-stage forward/backward compute ticks and inter-stage P2P ticks.
+Tick costs are the caller's (:class:`PipelineSpec`): per-stage
+forward/backward compute ticks and inter-stage P2P ticks.
 """
 from __future__ import annotations
 

@@ -1,14 +1,14 @@
 """Continuous batching of depth-vector requests over warm compiled graphs.
 
-The serving loop of ``serve/engine.py::ContinuousBatchingEngine`` — admit
-work into the next batch as slots free up, keep the expensive kernel hot —
-transplanted onto the DSE solver.  The unit of execution here is a *block*:
-up to ``block`` depth rows against ONE design, assembled fresh each step
-from however many client requests are queued (heterogeneous requests
-against the same design coalesce into shared blocks), deduplicated down to
-unique rows, solved by :func:`repro.core.dse.solve_block_status`, and
-streamed back **per config** — a client starts receiving results for its
-first rows while its later rows are still queued behind other tenants.
+A continuous-batching serving loop over the DSE solver: admit work into
+the next batch as slots free up, keep the expensive kernel hot.  The unit
+of execution is a *block*: up to ``block`` depth rows against ONE design,
+assembled fresh each step from however many client requests are queued
+(heterogeneous requests against the same design coalesce into shared
+blocks), deduplicated down to unique rows, solved by
+:func:`repro.core.dse.solve_block_status`, and streamed back **per
+config** — a client starts receiving results for its first rows while
+its later rows are still queued behind other tenants.
 
 Scheduling policy:
 
@@ -30,17 +30,16 @@ Sharding: a block's unique rows are split across ``shards`` workers —
 cummax sweeps; all workers share the warm ``_BatchArrays`` view) or
 ``mode="process"`` (workers hold their own unpickled
 :class:`~repro.core.incremental.CompiledGraph` per design key, the
-multi-host/device stand-in — blocks-over-workers is the same
-data-parallel decomposition ``distrib/sharding.py`` applies to batches
-over mesh axes).  Chunks are concatenated in submission order, so results
+multi-host stand-in: blocks over workers, a data-parallel split of the
+block's rows).  Chunks are concatenated in submission order, so results
 are bit-identical for every ``shards``/``mode`` setting.  Process pools
 are seeded through a *pool initializer*: the host keeps a bounded LRU of
 pickled graphs per design key, every (re)spawned worker unpickles them
 once at startup, and a task ships only the design key — a worker that has
 never seen the key answers with a need-blob sentinel and the host resends
 that one chunk with the blob attached, so steady state, retries and
-respawns never re-pay graph serialization per task.  The jax lanes
-refuse ``mode="process"``: a chip belongs to one process, so their
+respawns never re-pay graph serialization per task.  The jax lane
+refuses ``mode="process"``: a chip belongs to one process, so its
 shards are threads on the one device.
 
 Exactness: a block's verdicts and cycle counts are exactly
@@ -93,7 +92,6 @@ from .faults import (POOL_BROKEN, SHARD_CORRUPT, SHARD_FAULT, SHARD_HANG,
                      RetryPolicy, _PoolBrokenFault)
 
 INTERACTIVE, BULK = "interactive", "bulk"
-JAX_BACKENDS = ("jax", "jax_dense")     # solve lanes that hold the device
 
 _DONE = object()                     # per-request stream terminator
 
@@ -208,8 +206,7 @@ def _apply_shard_faults(out, hang_s: float, boom: bool, corrupt: bool):
 
 def _shard_task(graph, Db: np.ndarray, backend: str, block: int,
                 hang_s: float = 0.0, boom: bool = False,
-                corrupt: bool = False,
-                jax_interpret: Optional[bool] = None):
+                corrupt: bool = False):
     """Thread/serial shard unit: solve one chunk (plus injected faults —
     the injector draws on the scheduler thread, deterministically, and
     ships only the outcome flags here)."""
@@ -219,14 +216,13 @@ def _shard_task(graph, Db: np.ndarray, backend: str, block: int,
         raise InjectedFault(SHARD_FAULT, -1)
     counts = {}
     out = solve_block_status(graph, Db, backend=backend, block=block,
-                             jax_interpret=jax_interpret, counts=counts)
+                             counts=counts)
     return _apply_shard_faults((*out, counts), 0.0, False, corrupt)
 
 
 def _process_shard_solve(key: str, blob: Optional[bytes], Db: np.ndarray,
                          backend: str, block: int, hang_s: float = 0.0,
-                         boom: bool = False, corrupt: bool = False,
-                         jax_interpret: Optional[bool] = None):
+                         boom: bool = False, corrupt: bool = False):
     graph = _WORKER_GRAPHS.get(key)
     if graph is None:
         if blob is None:
@@ -243,7 +239,7 @@ def _process_shard_solve(key: str, blob: Optional[bytes], Db: np.ndarray,
         raise InjectedFault(SHARD_FAULT, -1)
     counts = {}
     out = solve_block_status(graph, Db, backend=backend, block=block,
-                             jax_interpret=jax_interpret, counts=counts)
+                             counts=counts)
     return _apply_shard_faults((*out, counts), 0.0, False, corrupt)
 
 
@@ -258,25 +254,23 @@ class BlockScheduler:
                  shard_timeout_s: Optional[float] = 30.0,
                  quarantine: Optional[DesignQuarantine] = None,
                  max_pool_respawns: int = 2,
-                 jax_interpret: Optional[bool] = None,
                  memo_capacity: int = 4096):
         assert mode in ("serial", "thread", "process"), mode
-        if backend in JAX_BACKENDS:
+        if backend == "jax":
             if mode == "process":
                 raise ValueError(
                     f"mode='process' cannot serve backend={backend!r}: a "
                     f"chip belongs to one process, so worker processes "
                     f"cannot reach the device the parent holds; use "
                     f"mode='thread' (shards share the one device)")
-            # resolved here, not in a shard: a refused mode must fail the
-            # constructor, never surface later as FAULTED rows
-            jax_interpret = pallas_interpret(jax_interpret)
+            # checked here, not in a shard: an unsupported platform must
+            # fail the constructor, never surface later as FAULTED rows
+            pallas_interpret()
         self.block = max(int(block), 1)
         self.shards = max(int(shards), 1)
         self.mode = mode if self.shards > 1 else "serial"
         self.starvation_limit = max(int(starvation_limit), 1)
         self.backend = backend
-        self.jax_interpret = jax_interpret
         self.min_shard_rows = min_shard_rows
         self.retry = retry if retry is not None else RetryPolicy()
         self.injector = injector
@@ -588,18 +582,17 @@ class BlockScheduler:
                                                     key=entry.key))
         if not pooled:
             call = (lambda: _shard_task(entry.graph, Db, self.backend,
-                                        self.block, hang_s, boom, corrupt,
-                                        self.jax_interpret))
+                                        self.block, hang_s, boom, corrupt))
             return _Attempt(None, call, self._pool_gen)
         if self.mode == "process":
             self._register_blob(entry)
             fut = self._submit(_process_shard_solve, entry.key, None,
                                Db, self.backend, self.block,
-                               hang_s, boom, corrupt, self.jax_interpret)
+                               hang_s, boom, corrupt)
         else:
             fut = self._submit(_shard_task, entry.graph, Db,
                                self.backend, self.block,
-                               hang_s, boom, corrupt, self.jax_interpret)
+                               hang_s, boom, corrupt)
         return _Attempt(fut, None, self._pool_gen)
 
     def _collect(self, entry: CacheEntry, Db: np.ndarray,
@@ -656,8 +649,7 @@ class BlockScheduler:
                     fut = self._submit(
                         _process_shard_solve, entry.key,
                         self._register_blob(entry), Db, self.backend,
-                        self.block, 0.0, False, False,
-                        self.jax_interpret)
+                        self.block, 0.0, False, False)
                     attempt = _Attempt(fut, None, self._pool_gen)
                     continue
                 status, cycles, violated, rounds, counts = out

@@ -192,7 +192,6 @@ class SweepService:
                  max_inflight_rows_per_tenant: Optional[int] = None,
                  max_queued_rows: Optional[int] = None,
                  default_deadline_s: Optional[float] = None,
-                 jax_interpret: Optional[bool] = None,
                  memo_capacity: int = 4096):
         self.cache = GraphCache(capacity=cache_capacity)
         quarantine = DesignQuarantine(threshold=quarantine_after,
@@ -206,7 +205,6 @@ class SweepService:
                                         shard_timeout_s=shard_timeout_s,
                                         quarantine=quarantine,
                                         max_pool_respawns=max_pool_respawns,
-                                        jax_interpret=jax_interpret,
                                         memo_capacity=memo_capacity)
         self.scheduler.hybrid = self.cache.hybrid
         self.admission = AdmissionController(

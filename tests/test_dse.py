@@ -9,24 +9,11 @@ back (deadlock / WAR cycle / constraint flip).
 import numpy as np
 import pytest
 
+from batch_oracle import assert_batch_exact
 from repro.core import resimulate, resimulate_batch, simulate
 from repro.core.program import Emit, Program, Read, Write
 from repro.designs.paper import fig4_ex4a, fig4_ex5
 from repro.designs.typea import producer_consumer, skynet_like
-
-
-def _assert_batch_exact(out, builder, D, base):
-    """Every config: verdict matches looped resimulate, numbers match a
-    from-scratch simulation."""
-    for k in range(len(D)):
-        depths = tuple(int(d) for d in D[k])
-        inc = resimulate(base, depths)
-        full = simulate(builder(), depths=depths)
-        assert bool(out.ok[k]) == inc.ok, \
-            (k, depths, out.reasons[k], inc.reason)
-        assert out.cycles[k] == full.cycles, (k, depths)
-        assert out.results[k].outputs == full.outputs, (k, depths)
-        assert out.results[k].deadlock == full.deadlock, (k, depths)
 
 
 # ------------------------------------------------------------------- Type A
@@ -37,7 +24,7 @@ def test_batch_matches_loop_and_full_typea():
     rng = np.random.default_rng(7)
     D = rng.integers(1, 13, size=(24, len(base.depths)))
     out = resimulate_batch(base, D)
-    _assert_batch_exact(out, builder, D, base)
+    assert_batch_exact(out, builder, D, base)
     assert out.n_reused > 0          # slack configs must actually reuse
 
 
@@ -57,7 +44,7 @@ def test_batch_typec_constraint_flips():
     base = simulate(fig4_ex5())
     D = np.array([(2, 100), (100, 2), (2, 2), (1, 1), (64, 64)])
     out = resimulate_batch(base, D)
-    _assert_batch_exact(out, fig4_ex5, D, simulate(fig4_ex5()))
+    assert_batch_exact(out, fig4_ex5, D, simulate(fig4_ex5()))
     assert bool(out.ok[0]) and not bool(out.ok[1])
     assert "constraint" in out.reasons[1]
     # the two ends genuinely diverge functionally
@@ -70,7 +57,7 @@ def test_batch_typec_nb_drop_design():
     base = simulate(fig4_ex4a(n=96))
     D = np.array([[1], [2], [3], [8], [96]])
     out = resimulate_batch(base, D)
-    _assert_batch_exact(out, lambda: fig4_ex4a(n=96), D, simulate(fig4_ex4a(n=96)))
+    assert_batch_exact(out, lambda: fig4_ex4a(n=96), D, simulate(fig4_ex4a(n=96)))
 
 
 def test_batch_detects_new_deadlock():
@@ -98,7 +85,7 @@ def test_batch_detects_new_deadlock():
     assert not base.deadlock
     D = np.array([[8], [4], [3], [1]])
     out = resimulate_batch(base, D)
-    _assert_batch_exact(out, leftover, D, simulate(leftover()))
+    assert_batch_exact(out, leftover, D, simulate(leftover()))
     assert bool(out.ok[0]) and bool(out.ok[1])
     assert not bool(out.ok[2]) and not bool(out.ok[3])
     assert "deadlock" in out.reasons[2]
@@ -134,7 +121,7 @@ def test_batch_detects_war_cycle():
     base = simulate(burst_pingpong())
     D = np.array([(1, 1), (2, 2), (1, 8), (8, 1), (4, 4), (8, 8)])
     out = resimulate_batch(base, D)
-    _assert_batch_exact(out, burst_pingpong, D, simulate(burst_pingpong()))
+    assert_batch_exact(out, burst_pingpong, D, simulate(burst_pingpong()))
     assert "cycle" in out.reasons[0] and "cycle" in out.reasons[1]
     assert out.results[0].deadlock            # fallback finds the deadlock
     assert out.ok[2:].all()                   # one roomy channel suffices
@@ -220,17 +207,6 @@ def test_batch_speedup_256_configs():
         f"({t_loop*1e3:.0f} ms vs {t_batch*1e3:.0f} ms for {K} configs)")
 
 
-def test_batch_jax_dense_backend_agrees():
-    """Legacy dense lowering (backend="jax_dense") still matches numpy."""
-    pytest.importorskip("jax")
-    base = simulate(producer_consumer(n=24, depth=3))
-    D = np.array([[1], [2], [4], [8]])
-    out = resimulate_batch(base, D, backend="numpy")
-    jd = resimulate_batch(base, D, backend="jax_dense")
-    assert (out.ok == jd.ok).all()
-    assert (out.cycles == jd.cycles).all()
-
-
 def test_batch_jax_sparse_deadlock_and_war_cycle():
     """The sparse jax lane must classify starved writes (DEADLOCK) and
     inverted event orders (WAR CYCLE) bit-identically to numpy — the
@@ -292,40 +268,10 @@ def test_batch_jax_sparse_deadlock_and_war_cycle():
                              backend="jax", fallback=False).status == 1).all()
 
 
-# ------------------------------------------- dense-path regression fixes
-def test_dense_jax_chunks_by_block(monkeypatch):
-    """Regression: a batch larger than the dense capacity must be slab-
-    chunked (honoring ``block``), not rejected outright."""
-    pytest.importorskip("jax")
-    import repro.core.dse as dse
-
-    base = simulate(producer_consumer(n=24, depth=3))
-    D = np.array([[1], [2], [3], [4], [6], [8]])
-    # npad = 128 -> one config occupies exactly the capacity: the old
-    # code raised for any K > 1, the fixed path chunks into slabs of 1
-    monkeypatch.setattr(dse, "_DENSE_CAP", 128 * 128)
-    out = resimulate_batch(base, D, backend="numpy")
-    jd = resimulate_batch(base, D, backend="jax_dense")
-    assert (out.ok == jd.ok).all()
-    assert (out.cycles == jd.cycles).all()
-    assert (out.violated == jd.violated).all()
-
-
-def test_dense_jax_single_config_capacity_error(monkeypatch):
-    """Only a SINGLE config exceeding dense capacity is an error — and the
-    message must point at a usable backend."""
-    pytest.importorskip("jax")
-    import repro.core.dse as dse
-
-    base = simulate(producer_consumer(n=24, depth=3))
-    monkeypatch.setattr(dse, "_DENSE_CAP", 128 * 128 - 1)
-    with pytest.raises(ValueError, match="numpy"):
-        resimulate_batch(base, np.array([[4]]), backend="jax_dense")
-
-
 def test_jax_backends_refuse_int32_overflow():
-    """Regression: both jax lanes must refuse (not silently wrap) a graph
-    whose path-length bound exceeds int32 headroom."""
+    """Regression: the jax lane must refuse (not silently wrap) a graph
+    whose path-length bound exceeds int32 headroom; ``"jax_dense"`` is
+    an unknown backend."""
     pytest.importorskip("jax")
     from repro.core.dse import _batch_arrays
     from repro.core.incremental import compile_graph
@@ -336,12 +282,14 @@ def test_jax_backends_refuse_int32_overflow():
     old = ba.bound
     try:
         ba.bound = 1 << 28              # numpy's int64 switchover point
-        for b in ("jax", "jax_dense"):
-            with pytest.raises(ValueError, match="numpy"):
-                resimulate_batch(base, np.array([[4]]), backend=b,
-                                 fallback=False)
+        with pytest.raises(ValueError, match="numpy"):
+            resimulate_batch(base, np.array([[4]]), backend="jax",
+                             fallback=False)
     finally:
         ba.bound = old
+    with pytest.raises(ValueError, match="unknown backend 'jax_dense'"):
+        resimulate_batch(base, np.array([[4]]), backend="jax_dense",
+                         fallback=False)
 
 
 def test_reused_shells_do_not_alias_mutable_state():

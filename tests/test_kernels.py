@@ -1,8 +1,10 @@
-"""Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracles.
+"""Kernel validation: the sparse max-plus Pallas kernel (interpret mode)
+vs pure-jnp and numpy oracles.
 
-Each kernel is swept over shapes/dtypes (hypothesis + parametrize) and
-asserted allclose against its ref.py.  interpret=True executes the kernel
-body in Python on CPU; the BlockSpecs/grids are identical to the TPU build.
+The kernel is swept over shapes (parametrize) and asserted equal to
+``segmented_cummax_ref`` and to the numpy DSE lane.  interpret=True
+executes the kernel body in Python on CPU; the BlockSpecs/grids are
+identical to the TPU build.
 """
 import functools
 
@@ -12,205 +14,6 @@ import pytest
 jax = pytest.importorskip("jax")
 pytest.importorskip("jax.experimental.pallas")
 import jax.numpy as jnp
-
-# hypothesis drives only the property tests below; the plain Pallas
-# regression tests must keep running where it is not installed
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                                   # pragma: no cover
-    HAVE_HYPOTHESIS = False
-
-    def given(*_a, **_k):          # stand-ins so decorators still apply
-        return lambda fn: pytest.mark.skip(reason="hypothesis missing")(fn)
-
-    def settings(*_a, **_k):
-        return lambda fn: fn
-
-    class st:                      # noqa: N801 — mirrors hypothesis alias
-        integers = sampled_from = staticmethod(lambda *a, **k: None)
-
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.maxplus.kernel import BLK, NEG, maxplus_sweep
-from repro.kernels.maxplus.ops import finalize_times, longest_path
-from repro.kernels.maxplus.ref import longest_path_ref, maxplus_sweep_ref
-from repro.kernels.mlstm_chunk.ops import mlstm_chunk
-from repro.kernels.mlstm_chunk.ref import mlstm_ref
-
-
-# ------------------------------------------------------------------ maxplus
-def _random_dag_dense(rng, n_real, npad):
-    a = np.full((npad, npad), int(NEG), dtype=np.int64)
-    base = np.full((npad,), int(NEG), dtype=np.int64)
-    base[:n_real] = rng.integers(0, 4, size=n_real)
-    for i in range(1, n_real):
-        for p in rng.choice(i, size=min(i, int(rng.integers(0, 3))),
-                            replace=False):
-            a[i, p] = int(rng.integers(0, 8))
-    return (jnp.asarray(a, jnp.int32), jnp.asarray(base, jnp.int32))
-
-
-@pytest.mark.parametrize("n_real", [5, 60, 128, 250])
-def test_maxplus_kernel_matches_ref(n_real):
-    rng = np.random.default_rng(n_real)
-    npad = ((n_real + BLK - 1) // BLK) * BLK
-    a, base = _random_dag_dense(rng, n_real, npad)
-    t_k = longest_path(a, base, use_pallas=True, interpret=True)
-    t_r = longest_path_ref(a, base, iters=npad)
-    np.testing.assert_array_equal(np.asarray(t_k), np.asarray(t_r))
-
-
-@pytest.mark.property
-@settings(max_examples=15, deadline=None)
-@given(st.integers(2, 100), st.integers(0, 2**31 - 1))
-def test_maxplus_sweep_property(n_real, seed):
-    rng = np.random.default_rng(seed)
-    npad = ((n_real + BLK - 1) // BLK) * BLK
-    a, base = _random_dag_dense(rng, n_real, npad)
-    t = base
-    s_k = maxplus_sweep(a, t, base, interpret=True)
-    s_r = maxplus_sweep_ref(a, t, base)
-    np.testing.assert_array_equal(np.asarray(s_k), np.asarray(s_r))
-
-
-def test_maxplus_finalizes_simulation_graph():
-    """End-to-end: kernel longest path == the engine's eager node times."""
-    from repro.core import simulate
-    from repro.designs.typea import producer_consumer
-    res = simulate(producer_consumer(n=40, depth=2))
-    times = finalize_times(res.graph.graph, use_pallas=True, interpret=True)
-    eager = res.graph.graph.times()
-    np.testing.assert_array_equal(np.asarray(times), eager)
-
-
-# ---------------------------------------------------------- flash attention
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,S,H,Hkv,hd", [
-    (1, 128, 2, 2, 64),
-    (2, 256, 4, 2, 64),
-    (1, 256, 8, 2, 128),
-    (2, 128, 3, 1, 64),        # odd head count (GQA 3:1)
-])
-def test_flash_attention_matches_ref(B, S, H, Hkv, hd, dtype):
-    keys = jax.random.split(jax.random.PRNGKey(S + H), 3)
-    q = jax.random.normal(keys[0], (B, S, H, hd), dtype)
-    k = jax.random.normal(keys[1], (B, S, Hkv, hd), dtype)
-    v = jax.random.normal(keys[2], (B, S, Hkv, hd), dtype)
-    out = flash_attention(q, k, v, interpret=True)
-    G = H // Hkv
-    qb = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kb = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    ref = attention_ref(qb, kb, vb, group_size=G)
-    ref = ref.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("window", [0, 64, 200])
-@pytest.mark.parametrize("softcap", [0.0, 50.0])
-def test_flash_attention_window_softcap(window, softcap):
-    B, S, H, Hkv, hd = 1, 256, 2, 1, 64
-    keys = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(keys[0], (B, S, H, hd))
-    k = jax.random.normal(keys[1], (B, S, Hkv, hd))
-    v = jax.random.normal(keys[2], (B, S, Hkv, hd))
-    out = flash_attention(q, k, v, window=window, softcap=softcap,
-                          interpret=True)
-    qb = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kb = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    ref = attention_ref(qb, kb, vb, window=window, softcap=softcap,
-                        group_size=2)
-    ref = ref.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.property
-@settings(max_examples=10, deadline=None)
-@given(st.sampled_from([128, 256]), st.sampled_from([1, 2, 4]),
-       st.sampled_from([64, 128]), st.integers(0, 2**31 - 1))
-def test_flash_attention_property(S, G, hd, seed):
-    B, Hkv = 1, 2
-    H = Hkv * G
-    keys = jax.random.split(jax.random.PRNGKey(seed % (2**31 - 1)), 3)
-    q = jax.random.normal(keys[0], (B, S, H, hd))
-    k = jax.random.normal(keys[1], (B, S, Hkv, hd))
-    v = jax.random.normal(keys[2], (B, S, Hkv, hd))
-    out = flash_attention(q, k, v, interpret=True)
-    qb = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kb = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, hd)
-    ref = attention_ref(qb, kb, vb, group_size=G)
-    ref = ref.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=3e-5, atol=3e-5)
-
-
-def test_flash_attention_matches_model_sdpa():
-    """Kernel vs the model's XLA attention path (the dry-run path)."""
-    from repro.configs import get_arch
-    from repro.models.attention import _project_qkv, _sdpa
-    from repro.models.common import causal_mask
-    cfg = get_arch("smollm-135m").smoke()
-    B, S = 1, 128
-    keys = jax.random.split(jax.random.PRNGKey(3), 3)
-    hd = cfg.resolved_head_dim
-    q = jax.random.normal(keys[0], (B, S, cfg.num_heads, hd))
-    k = jax.random.normal(keys[1], (B, S, cfg.num_kv_heads, hd))
-    v = jax.random.normal(keys[2], (B, S, cfg.num_kv_heads, hd))
-    pos = jnp.arange(S)[None]
-    mask = causal_mask(pos, pos)
-    xla_out = _sdpa(q, k, v, mask, cfg)
-    pl_out = flash_attention(q, k, v, interpret=True)
-    np.testing.assert_allclose(np.asarray(xla_out),
-                               np.asarray(pl_out.reshape(B, S, -1)),
-                               rtol=3e-5, atol=3e-5)
-
-
-# -------------------------------------------------------------- mlstm chunk
-@pytest.mark.parametrize("S,chunk", [(128, 32), (128, 128), (256, 64)])
-@pytest.mark.parametrize("P,Pv", [(32, 32), (64, 65)])
-def test_mlstm_chunk_matches_ref(S, chunk, P, Pv):
-    B, H = 2, 3
-    keys = jax.random.split(jax.random.PRNGKey(S + P), 5)
-    q = jax.random.normal(keys[0], (B, S, H, P)) * 0.3
-    k = jax.random.normal(keys[1], (B, S, H, P)) * 0.3
-    v = jax.random.normal(keys[2], (B, S, H, Pv))
-    ig = jax.nn.sigmoid(jax.random.normal(keys[3], (B, S, H)))
-    la = jax.nn.log_sigmoid(jax.random.normal(keys[4], (B, S, H)) + 1.0)
-    out = mlstm_chunk(q, k, v, ig, la, chunk=chunk, interpret=True)
-    qb = q.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    kb = k.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    vb = v.transpose(0, 2, 1, 3).reshape(B * H, S, Pv)
-    igb = ig.transpose(0, 2, 1).reshape(B * H, S)
-    lab = la.transpose(0, 2, 1).reshape(B * H, S)
-    ref = mlstm_ref(qb, kb, vb, igb, lab)
-    ref = ref.reshape(B, H, S, Pv).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_mlstm_chunk_matches_model_scan():
-    """Kernel vs the model's _ssd_scan_perhead (the XLA dry-run path)."""
-    from repro.models.xlstm import _ssd_scan_perhead
-    B, S, H, P = 1, 128, 2, 32
-    keys = jax.random.split(jax.random.PRNGKey(11), 5)
-    q = jax.random.normal(keys[0], (B, S, H, P)) * 0.3
-    k = jax.random.normal(keys[1], (B, S, H, P)) * 0.3
-    v = jax.random.normal(keys[2], (B, S, H, P + 1))
-    ig = jax.nn.sigmoid(jax.random.normal(keys[3], (B, S, H)))
-    la = jax.nn.log_sigmoid(jax.random.normal(keys[4], (B, S, H)) + 1.0)
-    scan_out = _ssd_scan_perhead(
-        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
-        ig, la, chunk=32)
-    pl_out = mlstm_chunk(q, k, v, ig, la, chunk=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(scan_out), np.asarray(pl_out),
-                               rtol=2e-4, atol=2e-4)
 
 
 # ----------------------------------------------------------- sparse maxplus

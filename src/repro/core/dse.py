@@ -45,7 +45,7 @@ import copy
 import threading
 import time as _time
 from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -647,23 +647,58 @@ def status_reason(cache: CompiledGraph, status_k: int, violated_k: int,
             f"control/data flow diverges")
 
 
+class _CopyOnAccess:
+    """A mutable field of :class:`_ReusedShell`: the base run's value,
+    copied (``copy``) into the shell the first time it is read or set."""
+
+    def __init__(self, copy):
+        self.copy = copy
+
+    def __set_name__(self, owner, name):
+        self.name, self.own = name, "_" + name
+
+    def __get__(self, shell, owner=None):
+        if shell is None:
+            return self
+        d = shell.__dict__
+        if self.own not in d:
+            d[self.own] = self.copy(getattr(d["base"], self.name))
+        return d[self.own]
+
+    def __set__(self, shell, value) -> None:
+        shell.__dict__[self.own] = value
+
+
 class _ReusedShell(SimResult):
-    """A REUSED row's result.  Its ``constraints`` are a copy of the base
-    run's (``base_constraints``), made on first access: a shell of its
-    own to mutate, as any result is, without a copy a row for every
-    shell that is never read (2,632 constraints, 21 kB, a row of
-    ``multicore``, held for as long as the caller holds the result)."""
+    """A REUSED row's result: the base run's under the row's own cycles
+    and depths.  It holds the base run (``base``, the cache entry's
+    result, which is never mutated) and copies each mutable field of it,
+    ``outputs``, ``stats`` and ``constraints``, on first access: once
+    touched, the field is the shell's own, equal to the base run's, and
+    mutating it reaches no sibling and not the base.  A shell nobody
+    reads copies nothing (a row of ``multicore`` would copy 2,632
+    constraints, 21 kB, held for as long as the caller holds the result).
+    It compares equal to any :class:`SimResult` with the same fields."""
 
-    @property
-    def constraints(self) -> list:
-        own = self.__dict__.get("_constraints")
-        if own is None:
-            own = self.__dict__["_constraints"] = list(self.base_constraints)
-        return own
+    outputs = _CopyOnAccess(dict)
+    stats = _CopyOnAccess(copy.copy)
+    constraints = _CopyOnAccess(list)
 
-    @constraints.setter
-    def constraints(self, value) -> None:
-        self.__dict__["_constraints"] = value
+    @classmethod
+    def of(cls, base: SimResult, cycles: int, depths: tuple,
+           engine: str) -> "_ReusedShell":
+        shell = cls.__new__(cls)
+        shell.__dict__.update(
+            program=base.program, cycles=cycles, engine=engine,
+            graph=base.graph, depths=depths, deadlock=base.deadlock,
+            deadlock_cycle=base.deadlock_cycle, base=base)
+        return shell
+
+    def __eq__(self, other):
+        if not isinstance(other, SimResult):
+            return NotImplemented
+        return all(getattr(self, f.name) == getattr(other, f.name)
+                   for f in fields(SimResult))
 
 
 def materialize_block(result: SimResult, Du: np.ndarray,
@@ -684,44 +719,38 @@ def materialize_block(result: SimResult, Du: np.ndarray,
     :class:`~repro.core.trace.HybridCache` into the fallback simulations,
     so a dynamic design's repeat fallbacks (same depths, any tenant)
     replay the verified whole-run entry instead of re-interpreting.
-    Returns ``(results_u, reasons_u)``.
+    Returns ``(results_u, reasons_u, rows_u)``; ``rows_u[u]`` is ``Du[u]``
+    as a tuple of ints, converted once a block for every result of the
+    row.
     """
     engine: OmniSim = result.graph
     cache = compile_graph(engine)
     fifo_names = [f.name for f in engine.fifos]
-    U = len(Du)
-    results_u: List[Optional[SimResult]] = [None] * U
-    reasons_u: List[str] = [""] * U
-    for u in range(U):
-        reasons_u[u] = status_reason(cache, int(status_u[u]),
-                                     int(violated_u[u]), Du[u], fifo_names)
-        if status_u[u] == REUSED:
-            # per-shell copies: SimStats is a mutable dataclass and
-            # constraints a mutable list — sharing them would let a caller
-            # mutating one sweep result corrupt its siblings AND the cached
-            # base run (the graph stays shared by design: it IS the cache)
-            shell = _ReusedShell(
-                program=result.program, outputs=dict(result.outputs),
-                cycles=int(cycles_u[u]), engine=engine_label,
-                stats=copy.copy(result.stats), graph=engine,
-                constraints=None, depths=tuple(int(d) for d in Du[u]),
-                deadlock=result.deadlock,
-                deadlock_cycle=result.deadlock_cycle)
-            shell.base_constraints = result.constraints
-            results_u[u] = shell
-        elif fallback_mask[u] and status_u[u] in FALLBACK_STATUSES:
+    rows_u = [tuple(r) for r in Du.tolist()]
+    statuses = status_u.tolist()
+    cycles = cycles_u.tolist()
+    results_u: List[Optional[SimResult]] = [None] * len(rows_u)
+    reasons_u: List[str] = [_STATUS_REASON.get(s) for s in statuses]
+    for u, s in enumerate(statuses):
+        if s == REUSED:
+            results_u[u] = _ReusedShell.of(result, cycles[u], rows_u[u],
+                                           engine_label)
+            continue
+        if reasons_u[u] is None:                 # DEADLOCK, VIOLATED
+            reasons_u[u] = status_reason(cache, s, int(violated_u[u]),
+                                         Du[u], fifo_names)
+        if fallback_mask[u] and s in FALLBACK_STATUSES:
             with (lock if lock is not None else nullcontext()), \
                     program_mutation_lock(engine.program):
                 saved = engine.program.depths()
                 try:
-                    full = simulate(engine.program,
-                                    depths=tuple(int(d) for d in Du[u]),
+                    full = simulate(engine.program, depths=rows_u[u],
                                     hybrid_cache=hybrid_cache)
                 finally:
                     engine.program.with_depths(saved)
             results_u[u] = full
             cycles_u[u] = full.cycles
-    return results_u, reasons_u
+    return results_u, reasons_u, rows_u
 
 
 def resimulate_batch(result: SimResult, depth_matrix,
@@ -774,7 +803,7 @@ def resimulate_batch(result: SimResult, depth_matrix,
 
     # ④ fall back to full re-simulation for exactly the failed subset —
     # once per unique config; duplicate rows share the result object
-    results_u, reasons_u = materialize_block(
+    results_u, reasons_u, _rows = materialize_block(
         result, Du, status_u, cycles_u, violated_u,
         np.full(U, bool(fallback)))
 

@@ -306,6 +306,7 @@ class BlockScheduler:
         self.stats_rows = 0              # rows placed into blocks
         self.stats_rows_unique = 0       # rows actually solved
         self.stats_fallbacks = 0         # full re-simulations run
+        self.stats_result_shells = 0     # REUSED result shells built
         self.stats_cancelled_rows = 0
         self.stats_requests = 0
         self.stats_retries = 0           # shard attempts beyond the first
@@ -803,38 +804,40 @@ class BlockScheduler:
                             self._memo.popitem(last=False)
 
         with span("sweep.materialize", block=n) as sp:
-            results_u, reasons_u, n_fb = self._materialize(
+            results_u, reasons_u, rows_u, n_fb, n_shells = self._materialize(
                 blk, Du, inverse, status_u, cycles_u, violated_u)
             for u, note in notes.items():
                 reasons_u[u] = note
-            sp.set_metadata(fallbacks=n_fb)
+            sp.set_metadata(fallbacks=n_fb, shells=n_shells)
 
         with span("sweep.deliver", block=n, rows=len(blk.items)):
             now = _time.perf_counter()
-            for pos, (req, i) in enumerate(blk.items):
+            # plain-int lists, converted once a block (cycles after the
+            # fallbacks wrote theirs)
+            status = status_u.tolist()
+            cycles = cycles_u.tolist()
+            violated = violated_u.tolist()
+            for (req, i), u in zip(blk.items, inverse.tolist()):
                 if req.cancelled.is_set():
                     continue
                 if req.expired(now):
                     # end-to-end deadline: a result that arrives late is a
                     # timeout, not a delivery
                     req.out_q.put(ConfigResult(
-                        request_id=req.rid, index=i,
-                        depths=tuple(int(d) for d in req.D[i]),
+                        request_id=req.rid, index=i, depths=rows_u[u],
                         ok=False, status=TIMED_OUT, cycles=-1, violated=0,
                         reason="deadline exceeded before this config was "
                                "delivered", result=None))
                     with self._cv:
                         self.stats_timed_out_rows += 1
                 else:
-                    u = int(inverse[pos])
-                    use_fb = req.fallback or status_u[u] == REUSED
+                    ok = status[u] == REUSED
+                    use_fb = req.fallback or ok
                     req.out_q.put(ConfigResult(
-                        request_id=req.rid, index=i,
-                        depths=tuple(int(d) for d in req.D[i]),
-                        ok=bool(status_u[u] == REUSED),
-                        status=int(status_u[u]),
-                        cycles=int(cycles_u[u]) if use_fb else -1,
-                        violated=int(violated_u[u]), reason=reasons_u[u],
+                        request_id=req.rid, index=i, depths=rows_u[u],
+                        ok=ok, status=status[u],
+                        cycles=cycles[u] if use_fb else -1,
+                        violated=violated[u], reason=reasons_u[u],
                         result=results_u[u] if use_fb else None))
                 req.delivered += 1
                 if req.delivered >= req.K:
@@ -846,7 +849,8 @@ class BlockScheduler:
     def _materialize(self, blk: _Block, Du, inverse, status_u, cycles_u,
                      violated_u):
         """Reasons, result shells and exact fallbacks of a block's unique
-        rows; returns ``(results_u, reasons_u, fallbacks run)``."""
+        rows; returns ``(results_u, reasons_u, rows_u, fallbacks run,
+        shells built)`` (``rows_u``: :func:`materialize_block`'s)."""
         entry = blk.entry
         # a failed unique row pays for its exact fallback only if a LIVE
         # request owning it asked for fallback (a cancelled or expired
@@ -865,14 +869,14 @@ class BlockScheduler:
         # solver verdicts stand, only the engine-exact results are
         # withheld, and the design takes a quarantine strike.
         try:
-            results_u, reasons_u = materialize_block(
+            results_u, reasons_u, rows_u = materialize_block(
                 entry.result, Du, status_u, cycles_u, violated_u, fb_mask,
                 engine_label="omnisim-sweep", lock=entry.lock,
                 hybrid_cache=self.hybrid)
         except Exception as exc:
             note = f"fallback re-simulation faulted: {exc!r}"
             self.quarantine.strike(entry.key, note)
-            results_u, reasons_u = materialize_block(
+            results_u, reasons_u, rows_u = materialize_block(
                 entry.result, Du, status_u, cycles_u, violated_u,
                 np.zeros(len(Du), dtype=bool),
                 engine_label="omnisim-sweep", lock=entry.lock,
@@ -882,10 +886,11 @@ class BlockScheduler:
                     reasons_u[u] += f" [{note}]"
             fb_mask[:] = False
         n_fb = int((fb_mask & (status_u != REUSED)).sum())
-        if n_fb:
-            with self._cv:
-                self.stats_fallbacks += n_fb
-        return results_u, reasons_u, n_fb
+        n_shells = int((status_u == REUSED).sum())
+        with self._cv:
+            self.stats_fallbacks += n_fb
+            self.stats_result_shells += n_shells
+        return results_u, reasons_u, rows_u, n_fb, n_shells
 
     # --------------------------------------------------------------- step
     def step(self) -> bool:
@@ -938,6 +943,7 @@ class BlockScheduler:
                 "dedup_ratio": (self.stats_rows / solved
                                 if self.stats_rows else 1.0),
                 "fallbacks": self.stats_fallbacks,
+                "result_shells": self.stats_result_shells,
                 "cancelled_rows": self.stats_cancelled_rows,
                 "retries": self.stats_retries,
                 "faulted_rows": self.stats_faulted_rows,

@@ -292,26 +292,84 @@ def test_jax_backends_refuse_int32_overflow():
                          fallback=False)
 
 
-def test_reused_shells_do_not_alias_mutable_state():
-    """Regression: REUSED result shells shared the base run's mutable
-    ``stats`` object and ``constraints`` list — mutating one sweep result
-    corrupted its siblings and the cached base run."""
-    base = simulate(producer_consumer(n=32, depth=4))
-    D = np.array([[4], [8], [16]])
-    out = resimulate_batch(base, D)
+def _reused_shells():
+    """fig4_ex5's base run (4 outputs, 2,025 constraints) and three REUSED
+    rows' results."""
+    base = simulate(fig4_ex5())
+    out = resimulate_batch(base, np.array([(2, 100), (2, 3), (2, 50)]))
     assert out.ok.all()
-    r0, r1 = out.results[0], out.results[1]
-    assert r0.stats is not base.stats
-    assert r0.stats is not r1.stats
-    before = r1.stats.queries
-    r0.stats.queries = -123
-    r0.constraints.append("sentinel")
-    assert r1.stats.queries == before
-    assert base.stats.queries != -123
-    assert "sentinel" not in r1.constraints
-    assert "sentinel" not in base.constraints
-    assert r1.constraints == base.constraints
-    assert r0.constraints is not r1.constraints is not base.constraints
+    return base, out.results
+
+
+def _set_stats(stats):
+    stats.queries = -123
+
+
+@pytest.mark.parametrize("name,mutate", [
+    ("outputs", lambda v: v.__setitem__("sentinel", -1)),
+    ("stats", _set_stats),
+    ("constraints", lambda v: v.append("sentinel")),
+])
+def test_reused_shells_do_not_alias_mutable_state(name, mutate):
+    """Regression: REUSED result shells shared the base run's mutable
+    ``outputs``, ``stats`` and ``constraints`` — mutating one sweep result
+    corrupted its siblings and the cached base run.  Each shell copies a
+    field on first access: equal to the base run's, an object of its own,
+    and a mutation reaches neither a sibling read before it, nor one read
+    after it, nor the base."""
+    import copy
+    base, (r0, r1, r2) = _reused_shells()
+    snapshot = {"outputs": dict, "stats": copy.copy, "constraints": list}
+    pristine = snapshot[name](getattr(base, name))
+    seen = [id(getattr(base, name))]
+    for r in (r1, r0):                     # r1 copies before the mutation
+        assert getattr(r, name) == pristine
+        assert getattr(r, name) is getattr(r, name)      # copied once
+        seen.append(id(getattr(r, name)))
+    mutate(getattr(r0, name))
+    assert getattr(r0, name) != pristine
+    for r in (r1, r2, base):               # r2 copies after it
+        assert getattr(r, name) == pristine, r.engine
+    seen.append(id(getattr(r2, name)))
+    assert len(set(seen)) == 4             # base and three shells
+    # a shell's own value may be set outright, as any result's
+    setattr(r2, name, None)
+    assert getattr(r2, name) is None and getattr(base, name) == pristine
+
+
+def test_reused_shells_pickle_replace_and_compare_like_eager_results():
+    """A shell is a :class:`SimResult`: ``==`` an eagerly built result
+    with the same fields (either way round), survives
+    ``dataclasses.replace`` and a pickle round trip, and carries its
+    row's depths as plain ints."""
+    import dataclasses
+    import pickle
+    base, (r0, r1, _r2) = _reused_shells()
+    assert r0.depths == (2, 100)
+    assert all(type(d) is int for d in r0.depths + (r0.cycles,))
+    eager = dataclasses.replace(base, cycles=r0.cycles, depths=r0.depths,
+                                engine=r0.engine)
+    assert type(eager) is not type(r0)
+    assert r0 == eager and eager == r0 and r0 != r1
+    assert dataclasses.replace(r0) == eager
+    moved = dataclasses.replace(r0, cycles=r0.cycles + 1)
+    assert moved != eager and moved.outputs == base.outputs
+    r0.outputs["sentinel"] = -1
+    assert r0 != eager and eager != r0
+    # pickle round trip: the design's generator bodies are local
+    # functions, so the shell is rebuilt over a base without its graph
+    # (and with its constraints a plain list, not the trace's lazy view)
+    from repro.core.dse import _ReusedShell
+    flat = dataclasses.replace(base, graph=None,
+                               constraints=list(base.constraints))
+    shell = _ReusedShell.of(flat, r1.cycles, r1.depths, r1.engine)
+    back = pickle.loads(pickle.dumps(shell))
+    assert type(back) is _ReusedShell
+    assert back == shell == dataclasses.replace(eager, graph=None,
+                                                cycles=r1.cycles,
+                                                depths=r1.depths)
+    shell.constraints.append("sentinel")
+    assert "sentinel" not in back.constraints
 
 
 # ------------------------------------------------------------ cycle exit

@@ -6,8 +6,8 @@ service, the solve phase and the cache open is there with its
 attributes; the scheduler thread's phase spans are flat and grouped by
 their ``block`` number; a request's submit, dequeue and done carry its
 ``rid``.  The scheduler's counters (``solve_calls``, ``fixpoint_rounds``,
-``forced_bulk_blocks``, ``device_verdict_rows``, ``host_recheck_rows``)
-are checked against the same run.
+``forced_bulk_blocks``, ``device_verdict_rows``, ``host_recheck_rows``,
+``result_shells``) are checked against the same run.
 """
 import glob
 import threading
@@ -32,7 +32,7 @@ SPANS = {
                        "unsettled_rows"},
     "solve.copy_back": {"bytes"},
     "solve.recheck": {"rounds", "violated_rows", "rows"},
-    "sweep.materialize": {"block", "fallbacks"},
+    "sweep.materialize": {"block", "fallbacks", "shells"},
     "sweep.deliver": {"block", "rows"},
     "sweep.request_done": {"rid", "lane", "latency_us"},
 }
@@ -188,6 +188,9 @@ def test_counters_after_a_jax_lane_sweep(jax_run):
     forced = [e for e in assemble if e[3]["forced"]]
     assert st["forced_bulk_blocks"] == len(forced) >= 1
     assert all(e[3]["lane"] == BULK for e in forced)
+    shells = [e[3]["shells"] for e in _scheduler_line(lines)
+              if e[0] == "sweep.materialize"]
+    assert st["result_shells"] == sum(shells) > 0
 
 
 def test_numpy_lane_emits_the_sweep_spans(tmp_path):

@@ -244,6 +244,57 @@ def test_coalescing_and_block_dedup_across_requests():
             assert out.cycles[k] == full.cycles
 
 
+def test_block_results_carry_row_tuples_and_share_shells(monkeypatch):
+    """One block of four requests: duplicate rows within and across them,
+    one request cancelled and one whose deadline passes while the block
+    solves.  Every delivered ``depths`` is its row as plain ints, the
+    results of duplicate rows are one shell, and ``result_shells`` counts
+    the block's REUSED unique rows."""
+    base = simulate(fig4_ex5())
+    Da = np.array([(2, 100), (100, 2), (2, 2), (2, 100), (2, 2)])
+    Db = np.array([(2, 2), (8, 8)])
+    Dc = np.array([(2, 100), (16, 16)])        # cancelled during the solve
+    Dd = np.array([(2, 2), (1, 1)])            # expires during the solve
+    with _manual_service(block=16) as svc:
+        ha, hb, hc = (svc.submit(fig4_ex5(), D, priority=BULK)
+                      for D in (Da, Db, Dc))
+        hd = svc.submit(fig4_ex5(), Dd, priority=BULK, deadline_s=60.0)
+        sched = svc.scheduler
+        solve = sched._solve_unique
+
+        def solve_while_clients_leave(*a, **kw):
+            hc.cancel()
+            hd._req.t_deadline = time.perf_counter() - 1.0
+            return solve(*a, **kw)
+
+        monkeypatch.setattr(sched, "_solve_unique",
+                            solve_while_clients_leave)
+        assert sched.step() and not sched.step()
+        got = {h: list(h.stream(timeout=10)) for h in (ha, hb, hc, hd)}
+        st = sched.stats()
+    assert st["blocks"] == 1 and st["rows"] == 11
+    assert [len(got[h]) for h in (ha, hb, hc, hd)] == [5, 2, 0, 2]
+    for h, D in ((ha, Da), (hb, Db), (hd, Dd)):
+        for cfg in got[h]:
+            assert cfg.depths == tuple(D[cfg.index].tolist())
+            assert all(type(d) is int for d in cfg.depths)
+            assert cfg.result is None or cfg.result.depths == cfg.depths
+    assert all(cfg.status == TIMED_OUT for cfg in got[hd])
+    ref = resimulate_batch(base, np.concatenate([Da, Db]))
+    for cfg in got[ha] + got[hb]:
+        k = cfg.index + (len(Da) if cfg.request_id == hb.request_id else 0)
+        assert (cfg.status, cfg.cycles) == (ref.status[k], ref.cycles[k])
+    res = {cfg.depths: [] for cfg in got[ha] + got[hb]}
+    for cfg in got[ha] + got[hb]:
+        res[cfg.depths].append(cfg.result)
+    assert all(r is rs[0] for rs in res.values() for r in rs)
+    for row in ((2, 100), (2, 2)):
+        assert type(res[row][0]).__name__ == "_ReusedShell"
+    # unique rows (2,100) (100,2) (2,2) (8,8) (16,16) (1,1): two REUSED;
+    # only the live requests' failed rows (100,2) and (8,8) fall back
+    assert st["result_shells"] == 2 and st["fallbacks"] == 2
+
+
 def test_cancel_mid_queue_finalizes_promptly():
     """A cancelled request buried behind a long bulk queue must get its
     terminal sentinel at the next scheduling point, not after the queue
